@@ -21,9 +21,9 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 import jax
-import jax.core as jcore
+from jax.extend import core as jcore
 
-from .findings import Finding, Report, Severity
+from .findings import Report
 from .registry import Rule, resolve_rules
 
 _UNSET = object()
@@ -38,7 +38,7 @@ _MAX_TRACE_RETRIES = 16
 class ProgramInfo:
     """One traced program plus the metadata rules need."""
 
-    closed_jaxpr: Any                      # jax.core.ClosedJaxpr
+    closed_jaxpr: Any                      # jax.extend.core.ClosedJaxpr
     mesh: Any = None                       # jax.sharding.Mesh or None
     axis_env: Dict[str, int] = field(default_factory=dict)
     unbound_axes: List[str] = field(default_factory=list)
@@ -160,7 +160,8 @@ def trace_program(
             )(*conv_args, **conv_kwargs)
             break
         except NameError as e:
-            m = re.search(r"unbound axis name:?\s*([\w.]+)", str(e))
+            # "Found an unbound axis name: dp. To fix this, ..."
+            m = re.search(r"unbound axis name:?\s*([\w.]*\w)", str(e))
             if not m or m.group(1) in env:
                 raise
             ax = m.group(1)
@@ -209,15 +210,10 @@ def trace_program(
 def analyze_program(program: ProgramInfo, rules=None) -> Report:
     """Run registered rules over an already-traced program."""
     report = Report(target=program.target)
+    # a rule that raises is a broken analyzer, not a clean program: let it
+    # propagate (the CLI exits nonzero; TrainStep's lint gate warns)
     for rule in resolve_rules(rules):
-        try:
-            report.extend(rule.check(program) or ())
-        except Exception as e:  # a rule must never kill the lint pass
-            report.findings.append(Finding(
-                rule=rule.id, severity=Severity.INFO,
-                message=f"rule crashed and was skipped: {type(e).__name__}: {e}",
-                fix_hint="report this — likely jax version drift in the "
-                         "analyzer, not a problem in your program"))
+        report.extend(rule.check(program) or ())
     return report.sort()
 
 

@@ -22,7 +22,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional
 
-import jax.core as jcore
+from jax.core import DropVar
+from jax.extend.core import Literal
 
 from .analyzer import eqn_subjaxprs
 
@@ -46,7 +47,7 @@ def producer_indices(jaxpr) -> Dict[Any, int]:
     out: Dict[Any, int] = {}
     for i, eqn in enumerate(jaxpr.eqns):
         for v in eqn.outvars:
-            if not isinstance(v, jcore.DropVar):
+            if not isinstance(v, DropVar):
                 out[v] = i
     return out
 
@@ -56,7 +57,7 @@ def output_ready_indices(closed) -> List[int]:
     jaxpr = getattr(closed, "jaxpr", closed)
     prod = producer_indices(jaxpr)
     return [
-        -1 if isinstance(v, jcore.Literal) else prod.get(v, -1)
+        -1 if isinstance(v, Literal) else prod.get(v, -1)
         for v in jaxpr.outvars
     ]
 

@@ -12,7 +12,8 @@ from __future__ import annotations
 
 from typing import Any, Iterable, List, Tuple
 
-import jax.core as jcore
+from jax.core import DropVar
+from jax.extend.core import Var
 
 from ..analyzer import ProgramInfo, eqn_source, eqn_subjaxprs
 from ..findings import Finding, Severity
@@ -34,7 +35,7 @@ _HEAVY = {"dot_general", "conv_general_dilated", "sort", "top_k",
 
 
 def _is_var(v) -> bool:
-    return isinstance(v, jcore.Var) and not isinstance(v, jcore.DropVar)
+    return isinstance(v, Var) and not isinstance(v, DropVar)
 
 
 def _dead_eqns(jaxpr) -> List[Tuple[int, Any]]:

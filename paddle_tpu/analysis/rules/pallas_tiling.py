@@ -85,13 +85,13 @@ _LANE = 128
 
 
 def _int_dims(block_shape) -> List[Optional[int]]:
-    """Block dims as ints; None for squeezed/mapped markers."""
+    """Block dims as ints; None for squeezed dims. A traced pallas_call
+    carries pl.Blocked(block_size=n) / pl.Squeezed() entries; the direct
+    lint_block_shape entry takes the ints / None of a BlockSpec."""
     out = []
     for b in tuple(block_shape):
-        if isinstance(b, (int, np.integer)):
-            out.append(int(b))
-        else:  # pallas Mapped/Squeezed marker (None in the BlockSpec)
-            out.append(None)
+        b = getattr(b, "block_size", b)
+        out.append(int(b) if isinstance(b, (int, np.integer)) else None)
     return out
 
 
@@ -168,10 +168,9 @@ def check(program: ProgramInfo):
         src = eqn_source(eqn)
         total = 0
         for bm in bms:
-            sd = getattr(bm, "array_shape_dtype", None)
-            ashape = tuple(sd.shape) if sd is not None else None
-            adtype = sd.dtype if sd is not None else np.float32
-            dims = _int_dims(getattr(bm, "block_shape", ()))
+            ashape = tuple(bm.array_aval.shape)
+            adtype = bm.array_aval.dtype
+            dims = _int_dims(bm.block_shape)
             total += _block_bytes(dims, adtype)
             for code, msg in lint_block_shape(dims, adtype, ashape):
                 yield Finding(

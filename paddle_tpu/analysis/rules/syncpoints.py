@@ -14,20 +14,19 @@ from ..analyzer import ProgramInfo, eqn_source, iter_eqns
 from ..findings import Finding, Severity
 from ..registry import register_rule
 
-_SYNC_EXACT = ("infeed", "outfeed")
+_SYNC_EXACT = ("infeed", "outfeed", "debug_print")
 
 
 @register_rule(
     "host-sync", "Host callback / sync point inside the compiled program",
     Severity.WARNING,
-    doc="Flags *_callback primitives and infeed/outfeed inside the traced "
+    doc="Flags *_callback, debug_print and infeed/outfeed inside the traced "
         "program: each one is a device->host round trip per step.")
 def check(program: ProgramInfo):
     for idx, eqn in iter_eqns(program.closed_jaxpr):
         name = eqn.primitive.name
         if "callback" in name or name in _SYNC_EXACT:
-            what = ("jax.debug.print" if name == "debug_callback"
-                    else name)
+            what = "jax.debug.print" if name == "debug_print" else name
             yield Finding(
                 rule="host-sync", severity=Severity.WARNING,
                 message=f"{what} compiled into the program — a "

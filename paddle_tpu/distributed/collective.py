@@ -134,10 +134,8 @@ def _axis_size(axis_name: str, group: Optional[Group]) -> int:
     """Size of a bound mesh axis, resolved INSIDE the trace (the binding mesh
     may differ from the global one, and groups may predate the mesh)."""
     try:
-        from ._compat import axis_size as _compat_axis_size
-
-        return int(_compat_axis_size(axis_name))
-    except Exception:
+        return int(jax.lax.axis_size(axis_name))
+    except NameError:   # axis not bound by the enclosing trace
         pass
     from .mesh import get_mesh
 

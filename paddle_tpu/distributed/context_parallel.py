@@ -29,12 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ._compat import (
-    NEW_SHARD_MAP_API as _NEW_SHARD_MAP_API,
-    axis_size as _axis_size,
-    pvary as _pvary,
-)
-
 NEG_INF = -1e30
 
 
@@ -112,10 +106,7 @@ def ring_attention(q, k, v, axis_name, causal=False, scale=None, rank=None):
     lax.switch, so only ONE branch executes per step — future chunks cost a
     cheap skip instead of a fully-masked dense attention.
     """
-    n = _axis_size(axis_name)
-    # rank may be fed in as data: old jax cannot lower axis_index inside a
-    # partial-auto shard_map (PartitionId is rejected by the SPMD
-    # partitioner) — see _sp_attention_fn
+    n = lax.axis_size(axis_name)
     r = lax.axis_index(axis_name) if rank is None else rank
     b, sq, h, d = q.shape
     if scale is None:
@@ -124,11 +115,12 @@ def ring_attention(q, k, v, axis_name, causal=False, scale=None, rank=None):
     use_flash = _flash_chunk_supported(sq, d)
 
     def chunk_skip(kc, vc):
-        # pvary: constants must carry the same varying-manual-axes type as
-        # the real chunk branches or lax.switch rejects the branch set
-        return (_pvary(jnp.zeros((b, sq, h, d), jnp.float32), axis_name),
-                _pvary(jnp.full((b, h, sq), NEG_INF, jnp.float32),
-                       axis_name))
+        # constants must carry the same varying-manual-axes type as the
+        # real chunk branches or lax.switch rejects the branch set
+        return (lax.pcast(jnp.zeros((b, sq, h, d), jnp.float32), axis_name,
+                          to="varying"),
+                lax.pcast(jnp.full((b, h, sq), NEG_INF, jnp.float32),
+                          axis_name, to="varying"))
 
     if use_flash:
         from ..ops import pallas as _pallas
@@ -175,22 +167,8 @@ def ring_attention(q, k, v, axis_name, causal=False, scale=None, rank=None):
             # 0: future chunk (skip), 1: diagonal (causal), 2: past (full);
             # lax.switch executes only the selected branch
             mode = jnp.where(src > r, 0, jnp.where(src == r, 1, 2))
-            if _NEW_SHARD_MAP_API or use_flash:
-                o_i, lse_i = lax.switch(
-                    mode, (chunk_skip, chunk_diag, chunk_full), kc, vc)
-            else:
-                # old-jax rep-checker cannot type the TRANSPOSE of a switch
-                # whose branches mix replicated constants with data-derived
-                # values (the forward is fixed by pvary, the cotangents are
-                # not) — encode the three modes as one additive mask instead:
-                # a fully -inf mask makes the chunk's lse ~ NEG_INF, which
-                # the online-softmax combine weights to zero, reproducing
-                # the skip branch
-                step_mask = (
-                    jnp.where(mode == 0, NEG_INF, 0.0)
-                    + jnp.where(mode == 1, causal_mask,
-                                jnp.zeros_like(causal_mask)))
-                o_i, lse_i = _chunk_attention(q, kc, vc, scale, step_mask)
+            o_i, lse_i = lax.switch(
+                mode, (chunk_skip, chunk_diag, chunk_full), kc, vc)
         else:
             o_i, lse_i = chunk_full(kc, vc)
         o, lse = _combine(o, lse, o_i, lse_i)
@@ -206,7 +184,7 @@ def ulysses_attention(q, k, v, axis_name, causal=False, scale=None,
     Swaps the sharded dimension seq<->heads with two all-to-alls, runs dense
     attention on the full sequence for h/n heads. Requires h % axis_size == 0.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     b, sq, h, d = q.shape
     if h % n != 0:
         raise ValueError(f"ulysses needs heads ({h}) divisible by axis size ({n})")
@@ -256,7 +234,7 @@ def _full_seq_attention(qf, kf, vf, causal, scale):
 # around TP blocks. Same semantics as local-shard functions.
 def scatter_seq(x, axis_name):
     """Keep this rank's 1/n slice of the sequence dim (ScatterOp)."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     r = lax.axis_index(axis_name)
     chunk = x.shape[1] // n if x.ndim > 2 else x.shape[0] // n
     dim = 1 if x.ndim > 2 else 0
@@ -309,20 +287,12 @@ def _sp_attention_fn(mesh, axis_name, mode, causal, _flag_state=None):
 
     from jax.sharding import PartitionSpec as P
 
-    from .sharded import shard_map
-
     inner = ring_attention if mode == "ring" else ulysses_attention
     spec = P(None, axis_name, None, None)
-    # Old jax cannot lower axis_index under a partial-auto shard_map
-    # (PartitionId is rejected by the SPMD partitioner) — fall back to a
-    # FULLY manual mapping there: dp/mp axes carry replicated data and
-    # redundant compute inside the region (correct, if wasteful), while the
-    # ring/all-to-all collectives still bind to `axis_name` only.
-    manual = (frozenset({axis_name}) if _NEW_SHARD_MAP_API else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(inner, axis_name=axis_name, causal=causal),
         mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
-        axis_names=manual, check_vma=False)
+        axis_names=frozenset({axis_name}), check_vma=False)
     # partial-manual shard_map (manual 'sep', auto dp/mp) requires a jit
     # scope in jax 0.9; nested jit inlines when already traced
     return jax.jit(fn)

@@ -39,15 +39,15 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
-import jax.core as jcore
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.core import DropVar
+from jax.extend.core import Literal
 
 from ..core.flags import define_flag, get_flag
 from ..observability.registry import counter as _obs_counter
 from ..observability.registry import gauge as _obs_gauge
-from ._compat import axis_size as _axis_size
 from .grad_buckets import coalesce as _coalesce
 from .grad_buckets import partition_buckets
 from .grad_buckets import uncoalesce as _uncoalesce
@@ -190,7 +190,7 @@ def ring_all_reduce(x, axis_name: str, world: Optional[int] = None,
     all 2*(world-1) ring steps back to back). Call inside a shard_map that
     binds the axis. Allclose to psum/pmean at dtype tolerance."""
     if world is None:
-        world = _axis_size(axis_name)
+        world = lax.axis_size(axis_name)
     if world <= 1:
         return x
     shape = x.shape
@@ -214,7 +214,7 @@ def reduce_flush(g_vals, axis_name: str, bucket_bytes: Optional[int] = None,
         return bucket_reduce(g_vals, axis_name, bucket_bytes, mean=mean)
     if bucket_bytes is None:
         bucket_bytes = default_bucket_bytes()
-    world = _axis_size(axis_name)
+    world = lax.axis_size(axis_name)
     shapes = [tuple(g.shape) for g in g_vals]
     dtypes = [g.dtype for g in g_vals]
     out: List[Any] = [None] * len(g_vals)
@@ -238,7 +238,7 @@ def _replay_eqn(eqn, env: Dict[Any, Any]) -> None:
     """Re-emit one traced equation into the enclosing trace (the
     jax.core.eval_jaxpr idiom: get_bind_params + primitive.bind)."""
     def read(v):
-        return v.val if isinstance(v, jcore.Literal) else env[v]
+        return v.val if isinstance(v, Literal) else env[v]
 
     subfuns, bind_params = eqn.primitive.get_bind_params(eqn.params)
     out = eqn.primitive.bind(*subfuns, *[read(v) for v in eqn.invars],
@@ -246,7 +246,7 @@ def _replay_eqn(eqn, env: Dict[Any, Any]) -> None:
     if not eqn.primitive.multiple_results:
         out = [out]
     for v, o in zip(eqn.outvars, out):
-        if not isinstance(v, jcore.DropVar):
+        if not isinstance(v, DropVar):
             env[v] = o
 
 
@@ -273,7 +273,7 @@ def overlap_grad_reduce(fwd_bwd, args: tuple, axis_name: str,
 
     if bucket_bytes is None:
         bucket_bytes = default_bucket_bytes()
-    world = _axis_size(axis_name)
+    world = lax.axis_size(axis_name)
 
     closed, out_shape = jax.make_jaxpr(fwd_bwd, return_shape=True)(*args)
     out_leaves, out_tree = jax.tree_util.tree_flatten(out_shape)
@@ -313,7 +313,7 @@ def overlap_grad_reduce(fwd_bwd, args: tuple, axis_name: str,
         env[v] = a
 
     def read_out(v):
-        return v.val if isinstance(v, jcore.Literal) else env[v]
+        return v.val if isinstance(v, Literal) else env[v]
 
     # schedule state: buckets waiting on their trigger point, rings in
     # flight with their emission stride

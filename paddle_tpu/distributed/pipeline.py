@@ -64,7 +64,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ._compat import NEW_SHARD_MAP_API, shard_map
 
 
 def _zeros_like_tree(tree):
@@ -88,22 +87,13 @@ def _expand0(tree):
 
 
 def _rank_shard_map(body, mesh, n, axis, in_specs, out_specs):
-    """shard_map over `axis` handing `body` its stage id as the FIRST arg.
-
-    New jax: partial-manual over `axis` (other mesh axes stay under GSPMD)
-    with lax.axis_index for the id. Old jax cannot lower axis_index inside
-    a partial-auto shard_map — it becomes a PartitionId instruction the
-    SPMD partitioner rejects (and XLA check-fails outright when sharded
-    operands feed the manual subgroup) — so there the WHOLE mesh goes
-    manual: axes other than `axis` carry replicated data and redundant
-    compute, which is correct if wasteful, and axis_index lowers cleanly
-    inside a fully-manual region.
-    """
+    """shard_map over `axis` handing `body` its stage id as the FIRST arg:
+    partial-manual over `axis` (other mesh axes stay under GSPMD) with
+    lax.axis_index for the id."""
     wrapped = lambda *a: body(lax.axis_index(axis), *a)
-    axis_names = frozenset({axis}) if NEW_SHARD_MAP_API else None
-    return shard_map(
+    return jax.shard_map(
         wrapped, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        axis_names=axis_names, check_vma=False)
+        axis_names=frozenset({axis}), check_vma=False)
 
 
 def pipeline_1f1b(

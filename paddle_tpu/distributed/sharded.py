@@ -17,7 +17,7 @@ from ..core.tensor import Tensor
 from .collective import axis_context
 from .mesh import get_mesh
 
-from ._compat import shard_map  # noqa: F401 — re-exported; see _compat.py
+shard_map = jax.shard_map  # re-exported: distributed.shard_map
 
 
 def _to_vals(x):

@@ -7,10 +7,11 @@ compile options + backend); wiring it up turns the second process launch of
 an identical train step into a disk read instead of a multi-second compile.
 
 Two pieces:
-  - enable_persistent_cache(): point jax at an on-disk cache directory and
-    drop the "only cache things that took >1s / >64KB" thresholds so even
-    bench-sized programs hit it. Idempotent; safe to call before or after
-    the first compile (earlier is better — entries written after enabling).
+  - enable_persistent_cache(): point jax at an on-disk cache directory
+    ($JAX_COMPILATION_CACHE_DIR when set, else a fixed directory inside the
+    checkout) and drop the "only cache things that took >1s / >64KB"
+    thresholds so even bench-sized programs hit it. Idempotent; call it
+    before the first compile (entries are written only after enabling).
   - TrainStep AOT fast dispatch (FLAGS_jit_fast_dispatch, jit/trainer.py):
     `jitted.lower(...).compile()` once, then call the compiled executable
     directly — skipping jax.jit's per-call python dispatch (signature
@@ -23,15 +24,17 @@ import os
 from typing import Dict, Optional
 
 import jax
+from jax.experimental.compilation_cache import compilation_cache as _jax_cc
 
 from ..core import flags
 from ..observability.registry import counter as _obs_counter
 
 flags.define_flag(
     "jit_compile_cache_dir", "",
-    "Directory for the persistent XLA compilation cache. Empty = disabled. "
-    "Set (or call jit.enable_persistent_cache) to make warm process starts "
-    "skip recompilation of unchanged train steps.")
+    "Directory jit.enable_persistent_cache() uses when called without one "
+    "(and JAX_COMPILATION_CACHE_DIR is not set); empty = the fixed "
+    ".jax_cache directory inside the checkout. After the call it holds the "
+    "directory in use.")
 flags.define_flag(
     "jit_fast_dispatch", False,
     "AOT-compile TrainStep on first call and dispatch the compiled "
@@ -39,54 +42,47 @@ flags.define_flag(
 
 _enabled_dir: Optional[str] = None
 
+# The path is part of a cache entry's key, so a directory that moves never
+# hits: the default is one fixed place inside the checkout (.gitignore'd),
+# never one made from tempfile, a pid or the time.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
 
 def enable_persistent_cache(cache_dir: Optional[str] = None) -> str:
-    """Enable jax's on-disk compilation cache at `cache_dir`.
+    """Enable jax's on-disk compilation cache; returns the directory in use.
 
-    Defaults to FLAGS_jit_compile_cache_dir, else ~/.cache/paddle_tpu/xla.
-    Returns the directory in use. Subsequent calls with the same dir are
-    no-ops; a different dir re-points the cache.
+    Where it lives: if the environment sets JAX_COMPILATION_CACHE_DIR, jax
+    has already read it and that directory is used — this function then sets
+    no other, whatever `cache_dir` or the flag say (whoever launches the
+    program places the cache). Otherwise `cache_dir`, else
+    FLAGS_jit_compile_cache_dir, else DEFAULT_CACHE_DIR. Call it before the
+    first compile. Subsequent calls that resolve to the same directory are
+    no-ops.
     """
     global _enabled_dir
-    if cache_dir is None:
-        cache_dir = str(flags.get_flag("jit_compile_cache_dir") or "")
-    if not cache_dir:
-        cache_dir = os.path.join(
-            os.path.expanduser("~"), ".cache", "paddle_tpu", "xla")
-    cache_dir = os.path.abspath(cache_dir)
+    from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    cache_dir = os.path.abspath(
+        from_env or cache_dir
+        or str(flags.get_flag("jit_compile_cache_dir") or "")
+        or DEFAULT_CACHE_DIR)
     if _enabled_dir == cache_dir:
         return cache_dir
     os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not from_env:
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     # default thresholds skip sub-second / small programs — exactly the ones
     # CI and benches compile over and over
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except AttributeError:  # knob added in later jax; older caches everything
-        pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # jax probes cache eligibility ONCE per process at the first compile; if
-    # anything compiled before this call, re-arm the probe so the new dir is
-    # actually used (no-op when nothing compiled yet)
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:
-        pass
+    # anything compiled before this call, re-arm the probe so the directory
+    # is actually used (no-op when nothing compiled yet)
+    _jax_cc.reset_cache()
     _enabled_dir = cache_dir
     flags.set_flags({"jit_compile_cache_dir": cache_dir})
     return cache_dir
-
-
-def maybe_enable_from_flags() -> Optional[str]:
-    """Enable the persistent cache iff FLAGS_jit_compile_cache_dir is set
-    (e.g. via the FLAGS_jit_compile_cache_dir env var). Called by bench
-    entrypoints so a single env var turns on warm starts."""
-    d = str(flags.get_flag("jit_compile_cache_dir") or "")
-    if d:
-        return enable_persistent_cache(d)
-    return None
 
 
 def cache_dir() -> Optional[str]:

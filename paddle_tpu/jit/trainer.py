@@ -279,7 +279,6 @@ class TrainStep:
         if dp_axis is not None:
             from jax.sharding import PartitionSpec as _P
 
-            from ..distributed._compat import shard_map as _shard_map
             from ..distributed.mesh import get_mesh as _get_mesh
 
             dp_mesh = mesh if mesh is not None else _get_mesh()
@@ -309,7 +308,7 @@ class TrainStep:
             self._mesh = dp_mesh  # resolved mesh, for lint + introspection
             # state replicated over dp, batch split on its leading dim;
             # outputs replicated (grads/loss are pmean'ed inside)
-            smapped = _shard_map(
+            smapped = jax.shard_map(
                 step, mesh=dp_mesh,
                 in_specs=(_P(), _P(), _P(), _P(), _P(), _P(dp_axis)),
                 out_specs=_P(),
@@ -573,7 +572,6 @@ class TrainStep:
                 from jax.sharding import PartitionSpec as _P
 
                 from ..distributed import overlap as _overlap
-                from ..distributed._compat import shard_map as _shard_map
 
                 axis, mode = self._dp_axis, self._overlap_mode()
                 bucket_bytes = self._bucket_bytes
@@ -583,7 +581,7 @@ class TrainStep:
                         list(g_vals), axis, bucket_bytes, mode=mode))
 
                 n = len(self.params)
-                self._reduce_probe = jax.jit(_shard_map(
+                self._reduce_probe = jax.jit(jax.shard_map(
                     reduce_only, mesh=self._mesh,
                     in_specs=(_P(),) * n, out_specs=(_P(),) * n,
                     axis_names=frozenset({axis}), check_vma=False))

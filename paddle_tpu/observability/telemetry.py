@@ -75,22 +75,28 @@ def enabled() -> bool:
     return metrics_enabled()
 
 
-def _peak_flops() -> float:
-    """Peak FLOP/s for the MFU estimate: BENCH_PEAK_FLOPS env override, else
-    the same defaults bench.py uses (v5e bf16 peak on an accelerator, a
-    nominal 1e12 on CPU so smoke MFUs stay visibly tiny, not meaningless)."""
-    env = os.environ.get("BENCH_PEAK_FLOPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    try:
+# Published per-chip peaks, keyed by jax's `device_kind`. One table: bench.py
+# reads it too. A device that is not here has no MFU, not a default one.
+#   "TPU v5 lite": Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+#   16 GB HBM at 819 GB/s.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+}
+
+
+def peak_flops(device_kind: Optional[str] = None) -> float:
+    """Peak bf16 FLOP/s of `device_kind` (default: the first device jax
+    reports). Raises for a device that is not in DEVICE_PEAKS."""
+    if device_kind is None:
         import jax
 
-        return 1e12 if jax.default_backend() == "cpu" else 197e12
-    except Exception:
-        return 1e12
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return DEVICE_PEAKS[device_kind]["flops_per_s"]
+    except KeyError:
+        raise ValueError(
+            f"no published peak for device kind {device_kind!r}: MFU is "
+            f"defined only for {sorted(DEVICE_PEAKS)}") from None
 
 
 class StepTelemetry:
@@ -206,7 +212,10 @@ class StepTelemetry:
             rec["tokens_per_s"] = round(tokens / step_wall, 3)
         flops = core.get("flops")
         if flops:
-            rec["mfu"] = round(float(flops) / step_wall / _peak_flops(), 6)
+            try:
+                rec["mfu"] = round(float(flops) / step_wall / peak_flops(), 6)
+            except ValueError:
+                pass    # e.g. the CPU: the record carries no MFU
         for extra in ("autotune", "compile_cache", "prefetch"):
             if extra in core:
                 rec[extra] = core[extra]

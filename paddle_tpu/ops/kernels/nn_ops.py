@@ -760,15 +760,18 @@ def scaled_dot_product_attention(
         # Block-size autotuning only on a real TPU backend: timing the
         # dense fallback (where blocks are no-ops) would cache a noise
         # winner that later steers the TPU export.
-        if jax.default_backend() == "tpu":
-            from ..pallas.flash_attention import (
-                flash_attention_platform_tuned as _flash_pd)
-
-            return _flash_pd(query, key, value, scale, is_causal)
         from ..pallas.flash_attention import (
-            flash_attention_platform as _flash_pd)
+            flash_attention_platform,
+            flash_attention_platform_tuned,
+            on_mesh,
+        )
 
-        return _flash_pd(query, key, value, scale, is_causal)
+        _flash_pd = (flash_attention_platform_tuned
+                     if jax.default_backend() == "tpu"
+                     else flash_attention_platform)
+        # under a device mesh the kernel has to sit inside a shard_map
+        return on_mesh(lambda q, k, v: _flash_pd(q, k, v, scale, is_causal),
+                       query, key, value)
     return _sdpa_xla(query, key, value, attn_mask, dropout_p, is_causal,
                      training, scale)
 
@@ -911,7 +914,7 @@ def paged_cached_attention(q, k, v, k_pages, v_pages, block_table, seq_lens,
     Pallas kernel on TPU / interpret mode, XLA gather composition otherwise.
 
     q: [slots, sq, q_heads, d]; k, v: [slots, sq, kv_heads, d];
-    k_pages, v_pages: [num_blocks, block_size, kv_heads, d];
+    k_pages, v_pages: [num_blocks, kv_heads, block_size, d];
     block_table: [slots, max_blocks] int32; seq_lens: [slots] int32.
     Returns (out [slots, sq, q_heads, d], k_pages, v_pages). Idle slots
     (block tables full of the null page 0) write and read garbage there
@@ -926,7 +929,7 @@ def paged_cached_attention(q, k, v, k_pages, v_pages, block_table, seq_lens,
     never read.
     """
     slots, sq, hq, d = q.shape
-    bs = k_pages.shape[1]
+    bs = k_pages.shape[2]
     seq_lens = jnp.asarray(seq_lens, jnp.int32).reshape(slots)
 
     from .. import pallas as _pallas
@@ -944,8 +947,10 @@ def paged_cached_attention(q, k, v, k_pages, v_pages, block_table, seq_lens,
             block_table.astype(jnp.int32),
             (seq_lens // bs)[:, None], axis=1)[:, 0]
         off = seq_lens % bs
-        k_pages = k_pages.at[page, off].set(k[:, 0].astype(k_pages.dtype))
-        v_pages = v_pages.at[page, off].set(v[:, 0].astype(v_pages.dtype))
+        k_pages = k_pages.at[page, :, off].set(
+            k[:, 0].astype(k_pages.dtype))
+        v_pages = v_pages.at[page, :, off].set(
+            v[:, 0].astype(v_pages.dtype))
         ctx = seq_lens + 1  # the token just written attends to itself
 
         q2 = q[:, 0]
@@ -969,8 +974,8 @@ def paged_cached_attention(q, k, v, k_pages, v_pages, block_table, seq_lens,
         bt, jnp.minimum(page_idx, bt.shape[1] - 1), axis=1)
     page = jnp.where(in_table, gathered, 0)    # overflow -> null page
     off = pos % bs
-    k_pages = k_pages.at[page, off].set(k.astype(k_pages.dtype))
-    v_pages = v_pages.at[page, off].set(v.astype(v_pages.dtype))
+    k_pages = k_pages.at[page, :, off].set(k.astype(k_pages.dtype))
+    v_pages = v_pages.at[page, :, off].set(v.astype(v_pages.dtype))
 
     kernel_ok = _paged_supports((slots, hq, d), k_pages.shape)
     if kernel_ok and _pallas.interpret_mode():

@@ -30,9 +30,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
-
-from ...distributed._compat import platform_dependent as _platform_dependent
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -42,11 +41,11 @@ NEG_INF = -1e30
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying `like`'s varying-manual-axes type, so the
     kernels compose with shard_map(check_vma=True) — e.g. as ring-attention
-    chunks over the 'sep' axis. (Version skew — jax.typeof absent on old
-    jax — is absorbed by distributed/_compat.py.)"""
-    from ...distributed._compat import shape_dtype_struct
-
-    return shape_dtype_struct(shape, dtype, like)
+    chunks over the 'sep' axis."""
+    vma = jax.typeof(like).vma
+    if vma:
+        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+    return jax.ShapeDtypeStruct(shape, dtype)
 
 
 # ------------------------------------------------------------------- forward
@@ -378,7 +377,7 @@ def flash_attention_platform(q, k, v, scale=None, causal=False,
 
 
 def _platform_fwd(q, k, v, scale, causal, block_q, block_k):
-    return _platform_dependent(
+    return lax.platform_dependent(
         q, k, v,
         tpu=lambda q, k, v: _fwd(q, k, v, scale, causal, block_q, block_k,
                                  False),
@@ -390,7 +389,7 @@ def _platform_fwd_rule(q, k, v, scale, causal, block_q, block_k):
 
 
 def _platform_bwd_rule(scale, causal, block_q, block_k, res, g):
-    return _platform_dependent(
+    return lax.platform_dependent(
         *res, g,
         tpu=lambda *a: _bwd(scale, causal, block_q, block_k, False,
                             a[:5], a[5]),
@@ -398,6 +397,39 @@ def _platform_bwd_rule(scale, causal, block_q, block_k, res, g):
 
 
 flash_attention_platform.defvjp(_platform_fwd_rule, _platform_bwd_rule)
+
+
+def on_mesh(fn, q, k, v):
+    """fn(q, k, v) — a flash-attention entry on [b, s, h, d] — under the
+    active device mesh. GSPMD cannot partition a Mosaic kernel ("Mosaic
+    kernels cannot be automatically partitioned. Please wrap the call in a
+    shard_map"), and attention is independent per batch row and head: so the
+    call goes inside a shard_map over every mesh axis that is not manual
+    already, batch split over the data axes and heads over 'mp' where they
+    divide, replicated over the rest."""
+    from jax.sharding import PartitionSpec
+
+    from ...distributed.mesh import get_mesh
+
+    mesh = get_mesh()
+    if mesh is None:
+        return fn(q, k, v)
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    free = [a for a in mesh.axis_names if a not in manual]
+    if all(mesh.shape[a] == 1 for a in free):
+        return fn(q, k, v)
+    batch_axes, n = [], 1
+    for a in ("dp", "sharding"):
+        if a in free and q.shape[0] % (n * mesh.shape[a]) == 0:
+            batch_axes.append(a)
+            n *= mesh.shape[a]
+    head_axis = "mp" if "mp" in free and all(
+        x.shape[2] % mesh.shape["mp"] == 0 for x in (q, k)) else None
+    spec = PartitionSpec(tuple(batch_axes) or None, None, head_axis, None)
+    # inside another shard_map the context mesh is the one to map over
+    return jax.shard_map(
+        fn, mesh=None if manual else mesh, in_specs=(spec,) * 3,
+        out_specs=spec, axis_names=frozenset(free), check_vma=False)(q, k, v)
 
 
 # ----------------------------------------------- varlen (segmented) flash

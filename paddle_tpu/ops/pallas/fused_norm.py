@@ -18,7 +18,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_ROWS = 256
+# Half of the 16 MiB of scoped VMEM a TPU v5e kernel gets by default; the
+# rest is headroom for what the compiler adds.
+_VMEM_BUDGET = 8 << 20
+
+
+def _block_rows(n, d, dtype):
+    """Rows per grid step, sized from the hidden width and dtype: the
+    backward kernel holds x, g and dx blocks (double-buffered by the
+    pipeline) plus about four fp32 temporaries of the same extent, and that
+    has to fit _VMEM_BUDGET at any width. A multiple of the dtype's sublane
+    tile (8 rows fp32, 16 bf16) unless the whole array is smaller."""
+    itemsize = jnp.dtype(dtype).itemsize
+    per_row = d * (3 * 2 * itemsize + 4 * 4)
+    sub = 32 // itemsize
+    rows = max(sub, min(1024, _VMEM_BUDGET // per_row) // sub * sub)
+    return min(rows, n)
 
 
 def _fwd_kernel(x_ref, w_ref, y_ref, rstd_ref, *, eps):
@@ -50,13 +65,13 @@ def _bwd_kernel(x_ref, w_ref, rstd_ref, g_ref, dx_ref, dw_ref):
     dw_ref[:] += part
 
 
-def _run_fwd(x, w, eps, block_rows, interpret):
+def _run_fwd(x, w, eps, interpret):
     orig_shape = x.shape
     d = x.shape[-1]
     n = x.size // d
     xr = x.reshape(n, d)
     wr = w.reshape(1, d)
-    rows = min(block_rows, n)
+    rows = _block_rows(n, d, x.dtype)
     # Pad the row dim to a block multiple (padded rows compute rsqrt(eps),
     # sliced away below) rather than shrinking the block to a divisor.
     pad = (-n) % rows
@@ -84,22 +99,21 @@ def _run_fwd(x, w, eps, block_rows, interpret):
     return y.reshape(orig_shape), (xr, w, rstd, orig_shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def fused_rms_norm(x, weight, epsilon=1e-6, block_rows=DEFAULT_BLOCK_ROWS,
-                   interpret=False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def fused_rms_norm(x, weight, epsilon=1e-6, interpret=False):
     """RMSNorm over the last axis; weight shape [d]."""
-    y, _ = _run_fwd(x, weight, epsilon, block_rows, interpret)
+    y, _ = _run_fwd(x, weight, epsilon, interpret)
     return y
 
 
-def _fwd_rule(x, weight, epsilon, block_rows, interpret):
-    return _run_fwd(x, weight, epsilon, block_rows, interpret)
+def _fwd_rule(x, weight, epsilon, interpret):
+    return _run_fwd(x, weight, epsilon, interpret)
 
 
-def _bwd_rule(epsilon, block_rows, interpret, res, g):
+def _bwd_rule(epsilon, interpret, res, g):
     xr, w, rstd, orig_shape = res
     n, d = xr.shape
-    rows = min(block_rows, n)
+    rows = _block_rows(n, d, xr.dtype)
     pad = (-n) % rows
     gr = g.reshape(n, d)
     if pad:

@@ -8,7 +8,10 @@ fixed-size token blocks scattered across one preallocated pool; this kernel
 computes one decode step of attention STRAIGHT from
 
     q            [slots, q_heads, d]        one query token per slot
-    k/v_pages    [num_blocks, block_size, kv_heads, d]
+    k/v_pages    [num_blocks, kv_heads, block_size, d]   (head-major: one
+                 (page, kv head) is a contiguous [block_size, d] tile, the
+                 shape the TPU lowering needs for the K/V block's last two
+                 dims)
     block_tables [slots, max_blocks]  int32 page ids per slot (0 = null)
     context_lens [slots]              int32 valid tokens incl. current
 
@@ -101,8 +104,8 @@ def _decode_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref,
 def _paged_pallas(q, k_pages, v_pages, block_tables, context_lens, scale,
                   kv_splits, interpret):
     slots, hq, d = q.shape
-    bs = k_pages.shape[1]
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
+    bs = k_pages.shape[2]
     g = hq // hkv
     max_bps = block_tables.shape[1]
     pad = (-max_bps) % kv_splits
@@ -120,12 +123,12 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, context_lens, scale,
         in_specs=[
             pl.BlockSpec((None, None, g, d),
                          lambda i, h, s, j, bt, cl: (i, h, 0, 0)),
-            pl.BlockSpec((None, bs, None, d),
+            pl.BlockSpec((None, None, bs, d),
                          lambda i, h, s, j, bt, cl, nps=nps:
-                         (bt[i, s * nps + j], 0, h, 0)),
-            pl.BlockSpec((None, bs, None, d),
+                         (bt[i, s * nps + j], h, 0, 0)),
+            pl.BlockSpec((None, None, bs, d),
                          lambda i, h, s, j, bt, cl, nps=nps:
-                         (bt[i, s * nps + j], 0, h, 0)),
+                         (bt[i, s * nps + j], h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, None, None, g, d),
@@ -220,8 +223,8 @@ def _verify_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref,
 def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
                         scale, kv_splits, interpret):
     slots, sq, hq, d = q.shape
-    bs = k_pages.shape[1]
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
+    bs = k_pages.shape[2]
     g = hq // hkv
     max_bps = block_tables.shape[1]
     pad = (-max_bps) % kv_splits
@@ -241,12 +244,12 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
         in_specs=[
             pl.BlockSpec((None, None, rows, d),
                          lambda i, h, s, j, bt, cl: (i, h, 0, 0)),
-            pl.BlockSpec((None, bs, None, d),
+            pl.BlockSpec((None, None, bs, d),
                          lambda i, h, s, j, bt, cl, nps=nps:
-                         (bt[i, s * nps + j], 0, h, 0)),
-            pl.BlockSpec((None, bs, None, d),
+                         (bt[i, s * nps + j], h, 0, 0)),
+            pl.BlockSpec((None, None, bs, d),
                          lambda i, h, s, j, bt, cl, nps=nps:
-                         (bt[i, s * nps + j], 0, h, 0)),
+                         (bt[i, s * nps + j], h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, None, None, rows, d),
@@ -286,20 +289,33 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
             .transpose(0, 2, 1, 3, 4).reshape(slots, sq, hq, d))
 
 
+def to_pages(x, block_size):
+    """Token-major KV [..., n_tokens, kv_heads, d] (n_tokens a multiple of
+    block_size) -> page layout [..., n_blocks, kv_heads, block_size, d]."""
+    *lead, n, hkv, d = x.shape
+    return jnp.swapaxes(
+        x.reshape(*lead, n // block_size, block_size, hkv, d), -3, -2)
+
+
+def from_pages(pages):
+    """Inverse of to_pages: [..., n_blocks, kv_heads, block_size, d] ->
+    [..., n_blocks * block_size, kv_heads, d]."""
+    *lead, nb, hkv, bs, d = pages.shape
+    return jnp.swapaxes(pages, -3, -2).reshape(*lead, nb * bs, hkv, d)
+
+
 def paged_attention_xla_multi(q, k_pages, v_pages, block_tables,
                               context_lens, scale=None):
     """Dense-gather reference for the multi-query verify window.
     q: [slots, sq, q_heads, d]; context_lens is the BASE context (tokens
     cached before the window) — query i sees pos < context_lens + i + 1."""
     slots, sq, hq, d = q.shape
-    bs = k_pages.shape[1]
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     g = hq // hkv
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    max_ctx = block_tables.shape[1] * bs
-    k = k_pages[block_tables].reshape(slots, max_ctx, hkv, d)
-    v = v_pages[block_tables].reshape(slots, max_ctx, hkv, d)
+    k, v = (from_pages(p[block_tables]) for p in (k_pages, v_pages))
+    max_ctx = k.shape[1]
     qg = (q.reshape(slots, sq, hkv, g, d)
           .transpose(0, 2, 1, 3, 4).astype(jnp.float32))  # [b,h,sq,g,d]
     sc = jnp.einsum("bhsgd,bkhd->bhsgk", qg,
@@ -333,14 +349,12 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
     [max_ctx] view, mask past context_lens, fp32 softmax. The default CPU
     path and the numerics oracle for the kernel tests."""
     slots, hq, d = q.shape
-    bs = k_pages.shape[1]
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     g = hq // hkv
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    max_ctx = block_tables.shape[1] * bs
-    k = k_pages[block_tables].reshape(slots, max_ctx, hkv, d)
-    v = v_pages[block_tables].reshape(slots, max_ctx, hkv, d)
+    k, v = (from_pages(p[block_tables]) for p in (k_pages, v_pages))
+    max_ctx = k.shape[1]
     qg = q.reshape(slots, hkv, g, d).astype(jnp.float32)
     sc = jnp.einsum("bhgd,bkhd->bhgk", qg,
                     k.astype(jnp.float32)) * scale
@@ -366,7 +380,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 def supports(q_shape, k_pages_shape) -> bool:
     """Shape gate for the kernel path (XLA fallback otherwise)."""
     slots, hq, d = q_shape
-    hkv = k_pages_shape[2]
+    hkv = k_pages_shape[1]
     return d <= 256 and hkv >= 1 and hq % hkv == 0
 
 

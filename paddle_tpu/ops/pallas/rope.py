@@ -14,9 +14,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
-
-from ...distributed._compat import platform_dependent as _platform_dependent
 
 
 def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, sign):
@@ -80,7 +79,7 @@ def _apply_platform(x, cos, sin, sign, interpret):
     pallas_call inside a cond branch)."""
     if interpret:
         return _apply(x, cos, sin, sign, True)
-    return _platform_dependent(
+    return lax.platform_dependent(
         x, cos, sin,
         tpu=lambda x, c, s: _apply(x, c, s, sign, False),
         default=lambda x, c, s: _apply_xla(x, c, s, sign))
@@ -196,7 +195,7 @@ def _apply_packed_platform(x, pos2d, cos_tab, sin_tab, sign, interpret):
         return _apply_packed(x, pos2d, cos_tab, sin_tab, sign, True)
     if not _packed_supported(x, cos_tab):
         return _xla_packed(x, pos2d, cos_tab, sin_tab, sign)
-    return _platform_dependent(
+    return lax.platform_dependent(
         x, pos2d, cos_tab, sin_tab,
         tpu=lambda x, p, c, s: _apply_packed(x, p, c, s, sign, False),
         default=lambda x, p, c, s: _xla_packed(x, p, c, s, sign))

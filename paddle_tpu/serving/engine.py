@@ -50,6 +50,7 @@ from .observability import (
     ServingObservability,
     new_engine_id,
 )
+from ..ops.pallas.paged_attention import from_pages, to_pages
 from .paged import PagedKVPool, PagedLayerCache, write_prefix
 from .scheduler import Request, Scheduler
 from .speculative import NgramDrafter, SpecState
@@ -509,11 +510,11 @@ class ServingEngine:
             def g(pages, table):
                 out = []
                 for kp, vp in pages:
-                    hkv, d = kp.shape[2], kp.shape[3]
+                    hkv, d = kp.shape[1], kp.shape[3]
                     k = jnp.zeros((1, padded, hkv, d), kp.dtype)
                     v = jnp.zeros((1, padded, hkv, d), vp.dtype)
-                    k = k.at[0, :n].set(kp[table].reshape(n, hkv, d))
-                    v = v.at[0, :n].set(vp[table].reshape(n, hkv, d))
+                    k = k.at[0, :n].set(from_pages(kp[table]))
+                    v = v.at[0, :n].set(from_pages(vp[table]))
                     out.append((k, v))
                 return out
 
@@ -562,25 +563,23 @@ class ServingEngine:
         if key not in self._jit:
             static_fn = self._functional()[1]
             bs = self.block_size
-            nb = P // bs
 
             def bp(pv, bv, pages, ids, pos, tP, last, slots, bt_rows,
                    plens, temps, d_toks, d_bt, d_sl, d_temps):
-                caches = []
-                for kp, vp in pages:
-                    hkv, d = kp.shape[2], kp.shape[3]
-                    caches.append((kp[tP].reshape(n, P, hkv, d),
-                                   vp[tP].reshape(n, P, hkv, d)))
+                caches = [(from_pages(kp[tP]), from_pages(vp[tP]))
+                          for kp, vp in pages]
                 logits, ncs = static_fn(pv, bv, ids, caches, pos)
                 lg = logits[jnp.arange(n), last].astype(jnp.float32)
                 first = jnp.argmax(lg, axis=-1).astype(jnp.int32)
                 flat = tP.reshape(-1)
                 new_pages = []
                 for (kp, vp), (k, v) in zip(pages, ncs):
-                    hkv, d = kp.shape[2], kp.shape[3]
+                    # rows back to back: [n * P, hkv, d] -> n * P/bs pages
                     new_pages.append(
-                        (kp.at[flat].set(k.reshape(n * nb, bs, hkv, d)),
-                         vp.at[flat].set(v.reshape(n * nb, bs, hkv, d))))
+                        (kp.at[flat].set(
+                            to_pages(k.reshape(-1, *k.shape[2:]), bs)),
+                         vp.at[flat].set(
+                            to_pages(v.reshape(-1, *v.shape[2:]), bs))))
                 return (first, new_pages,
                         d_toks.at[slots].set(first),
                         d_bt.at[slots].set(bt_rows),
@@ -708,7 +707,7 @@ class ServingEngine:
         n_layers = len(self.pool.layers)
         kp0 = self.pool.layers[0][0]
         np_dtype = np.dtype(kp0.dtype)
-        blk_shape = (self.block_size, kp0.shape[2], kp0.shape[3])
+        blk_shape = kp0.shape[1:]
         blk_bytes = int(np.prod(blk_shape)) * np_dtype.itemsize
         imported = dedup = rejected = skipped = nbytes = 0
         with self._lock:

@@ -425,7 +425,13 @@ class ProcessReplicaSpec:
     router passes registry/breaker/clock; the spec carries everything
     process-specific). ``child_store_addr`` lets chaos tests route the
     CHILD's store client through a StorePartitionProxy while the
-    supervisor keeps its direct connection."""
+    supervisor keeps its direct connection.
+
+    One process owns a chip: the child inherits the supervisor's
+    environment plus ``extra_env`` and uses whatever platform jax finds
+    there, so on a TPU host it claims the chip — and fails or hangs if the
+    supervisor (or another replica) already holds it. Pass
+    ``extra_env={"JAX_PLATFORMS": "cpu"}`` for CPU replicas."""
 
     def __init__(self, store_addr: Tuple[str, int], *,
                  factory: str = "paddle_tpu.serving.fleet_proc:demo_model",
@@ -750,7 +756,6 @@ class ProcessReplica(Replica):
                 "--parent-pid", str(os.getpid()),
             ]
             env = dict(os.environ)
-            env.setdefault("JAX_PLATFORMS", "cpu")
             env.update(self.spec.extra_env)
             proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
                                     stderr=subprocess.DEVNULL, env=env)

@@ -2,8 +2,12 @@
 
 The pool is ONE preallocated array pair per layer:
 
-    k_pages, v_pages : [num_blocks, block_size, kv_heads, head_dim]
+    k_pages, v_pages : [num_blocks, kv_heads, block_size, head_dim]
 
+Head-major inside a page: one (page, kv head) is a contiguous
+[block_size, head_dim] tile, which is what the TPU lowering of the paged
+attention kernel needs for the last two dims of its K/V block
+(ops/pallas/paged_attention.py owns the layout: to_pages / from_pages).
 Block ids from blocks.BlockAllocator index the leading dim directly. A
 sequence's KV lives in the (non-contiguous) blocks its table names; the
 ragged paged attention op (ops/pallas/paged_attention.py) computes straight
@@ -22,6 +26,8 @@ from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ..ops.pallas.paged_attention import to_pages
 
 
 class PagedLayerCache:
@@ -52,7 +58,7 @@ class PagedKVPool:
         self.num_kv_heads = int(num_kv_heads)
         self.head_dim = int(head_dim)
         self.dtype = dtype
-        shape = (self.num_blocks, self.block_size, self.num_kv_heads,
+        shape = (self.num_blocks, self.num_kv_heads, self.block_size,
                  self.head_dim)
         self.layers: List[Tuple[jax.Array, jax.Array]] = [
             (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
@@ -78,24 +84,6 @@ def write_prefix(k_pages, v_pages, k, v, table, *, block_size):
     block — they are masked by context_lens until the decode steps that
     overwrite them. Used by the engine after chunked prefill (which runs in
     a contiguous workspace); jit-compiled per padded length."""
-    nb = table.shape[0]
-    kb = k.reshape(nb, block_size, k.shape[1], k.shape[2])
-    vb = v.reshape(nb, block_size, v.shape[1], v.shape[2])
-    return (k_pages.at[table].set(kb.astype(k_pages.dtype)),
-            v_pages.at[table].set(vb.astype(v_pages.dtype)))
-
-
-def append_token_kv(k_pages, v_pages, k_new, v_new, block_table, seq_lens,
-                    *, block_size):
-    """Write one new token's K/V per slot at its current position.
-
-    k_new, v_new: [slots, kv_heads, d]; block_table: [slots, max_blocks];
-    seq_lens: [slots] tokens already present (write position). Idle slots
-    point at the null block and write garbage there harmlessly."""
-    slots = seq_lens.shape[0]
-    page = jnp.take_along_axis(
-        block_table, (seq_lens // block_size)[:, None], axis=1)[:, 0]
-    off = seq_lens % block_size
-    k_pages = k_pages.at[page, off].set(k_new.astype(k_pages.dtype))
-    v_pages = v_pages.at[page, off].set(v_new.astype(v_pages.dtype))
-    return k_pages, v_pages
+    return (
+        k_pages.at[table].set(to_pages(k, block_size).astype(k_pages.dtype)),
+        v_pages.at[table].set(to_pages(v, block_size).astype(v_pages.dtype)))

@@ -78,8 +78,7 @@ def test_object_collectives_cross_process():
     """Two real processes exchange objects over the native TCPStore."""
     code = r"""
 import os, sys
-sys.path.insert(0, "/root/repo")
-import tools.cpu_force
+sys.path.insert(0, os.environ["REPO_ROOT"])
 from paddle_tpu.distributed import objects as O
 rank = int(os.environ["PADDLE_TRAINER_ID"])
 O.gloo_init_parallel_env(rank, 2, os.environ["STORE_EP"])
@@ -107,7 +106,9 @@ print("RANK_OK", rank)
     for r in range(2):
         env = dict(os.environ, PADDLE_TRAINER_ID=str(r),
                    PADDLE_TRAINERS_NUM="2",
-                   STORE_EP=f"127.0.0.1:{port}", JAX_PLATFORMS="cpu")
+                   STORE_EP=f"127.0.0.1:{port}", JAX_PLATFORMS="cpu",
+                   REPO_ROOT=os.path.dirname(os.path.dirname(
+                       os.path.abspath(__file__))))
         procs.append(subprocess.Popen([sys.executable, "-c", code], env=env,
                                       stdout=subprocess.PIPE,
                                       stderr=subprocess.STDOUT, text=True))

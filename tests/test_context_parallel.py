@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from paddle_tpu.distributed.context_parallel import (
     all_gather_seq,
@@ -162,21 +162,21 @@ def test_sp_utils_roundtrip():
     # all_gather(shard) == identity on the full array
     gat = shard_map(
         lambda x: all_gather_seq(x, "sep"),
-        mesh=mesh, in_specs=(shard,), out_specs=rep, check_rep=False,
+        mesh=mesh, in_specs=(shard,), out_specs=rep, check_vma=False,
     )
     np.testing.assert_allclose(gat(x), x, atol=1e-6)
 
     # scatter(full) == shard
     sc = shard_map(
         lambda x: scatter_seq(x, "sep"),
-        mesh=mesh, in_specs=(rep,), out_specs=shard, check_rep=False,
+        mesh=mesh, in_specs=(rep,), out_specs=shard, check_vma=False,
     )
     np.testing.assert_allclose(sc(x), x, atol=1e-6)
 
     # reduce_scatter(replicated) == N * shard
     rs = shard_map(
         lambda x: reduce_scatter_seq(x, "sep"),
-        mesh=mesh, in_specs=(rep,), out_specs=shard, check_rep=False,
+        mesh=mesh, in_specs=(rep,), out_specs=shard, check_vma=False,
     )
     np.testing.assert_allclose(rs(x), N * x, atol=1e-5)
 

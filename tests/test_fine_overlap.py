@@ -15,7 +15,7 @@ import paddle_tpu as paddle
 from paddle_tpu import analysis, nn, optimizer
 from paddle_tpu.core import flags
 from paddle_tpu.distributed import overlap
-from paddle_tpu.distributed._compat import shard_map
+from jax import shard_map
 from paddle_tpu.jit.trainer import TrainStep
 
 

@@ -162,16 +162,19 @@ def test_flash_via_sdpa_op():
         paddle.set_flags({"pallas_interpret": False})
 
 
-def test_fused_rms_norm():
+# (3, 100, 2048): the row block (sized from the width) is 96 of 300 rows, so
+# the grid has several steps and a padded tail; (8, 33, 128) is one block
+@pytest.mark.parametrize("shape", [(8, 33, 128), (3, 100, 2048)])
+def test_fused_rms_norm(shape):
     rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.standard_normal((8, 33, 128)), jnp.float32)
-    w = jnp.asarray(rng.standard_normal((128,)), jnp.float32)
-    y = fused_rms_norm(x, w, 1e-6, 256, True)
+    x = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    w = jnp.asarray(rng.standard_normal(shape[-1:]), jnp.float32)
+    y = fused_rms_norm(x, w, 1e-6, True)
     ref = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * w
     np.testing.assert_allclose(y, ref, atol=1e-5)
 
     g1 = jax.grad(
-        lambda x, w: (fused_rms_norm(x, w, 1e-6, 256, True) ** 2).sum(),
+        lambda x, w: (fused_rms_norm(x, w, 1e-6, True) ** 2).sum(),
         argnums=(0, 1),
     )(x, w)
     g2 = jax.grad(

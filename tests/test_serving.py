@@ -183,13 +183,15 @@ class TestBlockAllocator:
 def _dense_oracle(q, k_pages, v_pages, tables, lens, scale):
     """Hand-built numpy reference: per-slot gather + masked softmax."""
     slots, hq, d = q.shape
-    bs, hkv = k_pages.shape[1], k_pages.shape[2]
+    hkv = k_pages.shape[1]          # pages: [blocks, hkv, bs, d]
     g = hq // hkv
     out = np.zeros_like(q, dtype=np.float32)
     for s in range(slots):
         ctx = int(lens[s])
-        k = k_pages[tables[s]].reshape(-1, hkv, d)[:ctx]   # [ctx, hkv, d]
-        v = v_pages[tables[s]].reshape(-1, hkv, d)[:ctx]
+        k = (k_pages[tables[s]].transpose(0, 2, 1, 3)
+             .reshape(-1, hkv, d)[:ctx])                   # [ctx, hkv, d]
+        v = (v_pages[tables[s]].transpose(0, 2, 1, 3)
+             .reshape(-1, hkv, d)[:ctx])
         for h in range(hq):
             kv_h = h // g
             sc = (k[:, kv_h] @ q[s, h]).astype(np.float64) * scale
@@ -204,8 +206,8 @@ def _make_case(slots=3, hq=4, hkv=2, d=8, bs=4, blocks_per_seq=3, seed=0):
     rng = np.random.default_rng(seed)
     num_blocks = 1 + slots * blocks_per_seq
     q = rng.standard_normal((slots, hq, d)).astype(np.float32)
-    k_pages = rng.standard_normal((num_blocks, bs, hkv, d)).astype(np.float32)
-    v_pages = rng.standard_normal((num_blocks, bs, hkv, d)).astype(np.float32)
+    k_pages = rng.standard_normal((num_blocks, hkv, bs, d)).astype(np.float32)
+    v_pages = rng.standard_normal((num_blocks, hkv, bs, d)).astype(np.float32)
     tables = np.arange(1, num_blocks, dtype=np.int32)
     tables = tables.reshape(slots, blocks_per_seq)
     max_ctx = blocks_per_seq * bs
@@ -258,12 +260,12 @@ class TestPagedAttentionNumerics:
         from paddle_tpu.ops import api
 
         q, kp, vp, bt, cl = _make_case(seed=11)
-        bs = kp.shape[1]
+        bs = kp.shape[2]
         # every slot needs a free next position inside its table
         cl = np.minimum(cl, bt.shape[1] * bs - 1).astype(np.int32)
         rng = np.random.default_rng(11)
         slots, hq, d = q.shape
-        hkv = kp.shape[2]
+        hkv = kp.shape[1]
         k_new = rng.standard_normal((slots, 1, hkv, d)).astype(np.float32)
         v_new = rng.standard_normal((slots, 1, hkv, d)).astype(np.float32)
         out, kp2, vp2 = api.paged_cached_attention(
@@ -274,8 +276,8 @@ class TestPagedAttentionNumerics:
         kp_ref, vp_ref = kp.copy(), vp.copy()
         for s in range(slots):
             pg = bt[s, cl[s] // bs]
-            kp_ref[pg, cl[s] % bs] = k_new[s, 0]
-            vp_ref[pg, cl[s] % bs] = v_new[s, 0]
+            kp_ref[pg, :, cl[s] % bs] = k_new[s, 0]
+            vp_ref[pg, :, cl[s] % bs] = v_new[s, 0]
         want = _dense_oracle(q, kp_ref, vp_ref, bt, cl + 1,
                              1.0 / np.sqrt(d))
         np.testing.assert_allclose(np.asarray(out)[:, 0], want,

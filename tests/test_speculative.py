@@ -123,13 +123,13 @@ class TestSpecState:
 def _dense_multi_oracle(q, k_pages, v_pages, tables, lens):
     """numpy reference: query i of slot s attends pos < lens[s] + i + 1."""
     slots, sq, hq, d = q.shape
-    bs, hkv = k_pages.shape[1], k_pages.shape[2]
+    hkv = k_pages.shape[1]          # pages: [blocks, hkv, bs, d]
     g = hq // hkv
     scale = 1.0 / np.sqrt(d)
     out = np.zeros_like(q, dtype=np.float32)
     for s in range(slots):
-        k = k_pages[tables[s]].reshape(-1, hkv, d)
-        v = v_pages[tables[s]].reshape(-1, hkv, d)
+        k = k_pages[tables[s]].transpose(0, 2, 1, 3).reshape(-1, hkv, d)
+        v = v_pages[tables[s]].transpose(0, 2, 1, 3).reshape(-1, hkv, d)
         for i in range(sq):
             ctx = int(lens[s]) + i + 1
             for h in range(hq):
@@ -146,8 +146,8 @@ def _multi_case(slots=3, sq=4, hq=4, hkv=2, d=8, bs=4, bps=4, seed=0):
     rng = np.random.default_rng(seed)
     num_blocks = 1 + slots * bps
     q = rng.standard_normal((slots, sq, hq, d)).astype(np.float32)
-    k_pages = rng.standard_normal((num_blocks, bs, hkv, d)).astype(np.float32)
-    v_pages = rng.standard_normal((num_blocks, bs, hkv, d)).astype(np.float32)
+    k_pages = rng.standard_normal((num_blocks, hkv, bs, d)).astype(np.float32)
+    v_pages = rng.standard_normal((num_blocks, hkv, bs, d)).astype(np.float32)
     tables = np.arange(1, num_blocks, dtype=np.int32).reshape(slots, bps)
     # base contexts leave room for the sq window inside the table
     lens = np.array([bps * bs - sq, 1, bs + 2], np.int32)[:slots]
@@ -210,8 +210,8 @@ class TestPagedCachedAttentionWindow:
         q = rng.standard_normal((slots, sq, hq, d)).astype(np.float32)
         k = rng.standard_normal((slots, sq, hkv, d)).astype(np.float32)
         v = rng.standard_normal((slots, sq, hkv, d)).astype(np.float32)
-        kp = rng.standard_normal((nb, bs, hkv, d)).astype(np.float32)
-        vp = rng.standard_normal((nb, bs, hkv, d)).astype(np.float32)
+        kp = rng.standard_normal((nb, hkv, bs, d)).astype(np.float32)
+        vp = rng.standard_normal((nb, hkv, bs, d)).astype(np.float32)
         bt = np.arange(1, nb, dtype=np.int32).reshape(slots, bps)
         lens = np.array([3, 7], np.int32)   # crosses a block boundary
 
@@ -245,8 +245,8 @@ class TestPagedCachedAttentionWindow:
         q = rng.standard_normal((slots, sq, hq, d)).astype(np.float32)
         k = np.ones((slots, sq, hkv, d), np.float32)
         v = np.ones((slots, sq, hkv, d), np.float32)
-        kp = np.zeros((nb, bs, hkv, d), np.float32)
-        vp = np.zeros((nb, bs, hkv, d), np.float32)
+        kp = np.zeros((nb, hkv, bs, d), np.float32)
+        vp = np.zeros((nb, hkv, bs, d), np.float32)
         bt = np.array([[2, 1]], np.int32)          # 2 blocks = 8 positions
         lens = np.array([6], np.int32)             # window 6..9 overflows
         import jax.numpy as jnp
@@ -256,7 +256,7 @@ class TestPagedCachedAttentionWindow:
         kp2 = np.asarray(kp2)
         # positions 6, 7 land in block 1 (offsets 2, 3); 8, 9 overflow to
         # the null page — block 2 (the table head) must be untouched
-        assert kp2[1, 2:].max() == 1.0
+        assert kp2[1, :, 2:].min() == 1.0 and kp2[1, :, :2].max() == 0.0
         assert kp2[2].max() == 0.0
         assert kp2[0].max() == 1.0                 # null page took the spill
 

@@ -54,7 +54,7 @@ def test_dtype_promotion_positive_f64_host_arg():
 
 def test_dtype_promotion_positive_bf16_accumulation():
     a = np.ones((16, 16), np.float32)
-    with jax.experimental.enable_x64(False):
+    with jax.enable_x64(False):
         r = analyze(lambda x: x.astype(jnp.bfloat16) @ x.astype(jnp.bfloat16),
                     a)
     hits = _hits(r, "dtype-promotion")

@@ -70,7 +70,9 @@ def main() -> int:
                     help="skip the TrainStep compile probe (device/host only)")
     args = ap.parse_args()
 
-    import tools.cpu_force  # noqa: F401
+    # a CPU tool: pin the platform (and the 8-device host mesh) before jax loads
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
     from paddle_tpu.core import flags
     from paddle_tpu.observability import memory as obs_memory

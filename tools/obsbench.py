@@ -48,6 +48,10 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+# a CPU tool: pin the platform (and the 8-device host mesh) before jax loads
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 OVERHEAD_TOLERANCE = 0.03  # metrics ON must keep >= 97% of OFF steps/s
@@ -63,8 +67,6 @@ def log(*a):
 
 def child_overhead(metrics_on: bool, steps: int) -> int:
     """Subprocess body: time a warm TrainStep loop; print steps/s JSON."""
-    import tools.cpu_force  # noqa: F401
-
     import numpy as np
 
     import paddle_tpu as paddle
@@ -106,6 +108,7 @@ def bench_overhead(steps: int, repeats: int = 3) -> dict:
     best = {"off": 0.0, "on": 0.0}
     for _ in range(repeats):
         for mode in ("off", "on"):
+            # a CPU measurement: the child is pinned to the CPU
             env = dict(os.environ, JAX_PLATFORMS="cpu")
             env.pop("FLAGS_metrics", None)
             env.pop("FLAGS_metrics_dir", None)
@@ -138,8 +141,6 @@ def bench_overhead(steps: int, repeats: int = 3) -> dict:
 
 def bench_flight_and_sinks(steps: int) -> dict:
     import glob
-
-    import tools.cpu_force  # noqa: F401
 
     import numpy as np
 
@@ -240,8 +241,6 @@ def bench_straggler(world: int = 4, steps: int = 12, inject_at: int = 5,
                     victim: int = 2) -> dict:
     import threading
 
-    import tools.cpu_force  # noqa: F401
-
     from paddle_tpu.core import flags
     from paddle_tpu.distributed.env import InProcStore
     from paddle_tpu.observability import reset_all
@@ -304,8 +303,6 @@ def bench_straggler(world: int = 4, steps: int = 12, inject_at: int = 5,
 def bench_anomaly_dump() -> dict:
     import glob
 
-    import tools.cpu_force  # noqa: F401
-
     from paddle_tpu.core import flags
     from paddle_tpu.observability import reset_all
     from paddle_tpu.observability.anomaly import AnomalyEngine
@@ -366,8 +363,6 @@ FLEET_OVERHEAD_RATIO = 0.97    # tracing ON keeps >= 97% of OFF throughput
 
 def bench_fleet_trace() -> dict:
     import glob
-
-    import tools.cpu_force  # noqa: F401
 
     import numpy as np
 

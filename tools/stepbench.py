@@ -305,6 +305,9 @@ def bench_compile_cache():
     cache_dir = tempfile.mkdtemp(prefix="sb_xla_")
     times = []
     for label in ("cold", "warm"):
+        # a CPU measurement: each child is pinned to the CPU (this parent
+        # has imported jax, and one process owns a chip) and gets a
+        # throwaway cache directory so that "cold" really is cold
         env = dict(os.environ, JAX_PLATFORMS="cpu",
                    FLAGS_jit_compile_cache_dir=cache_dir)
         env.pop("XLA_FLAGS", None)  # single device is enough for this probe

@@ -1,0 +1,12 @@
+"""Tests of the benchmark's own yardstick. They run on the CPU:
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
+
+and are not part of the repo's tier-1 run (tests/)."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)

@@ -27,6 +27,7 @@ from ..nn.clip import ClipGradByGlobalNorm
 from ..nn.layer import Layer
 from ..observability import flight_recorder as _flight
 from ..observability import telemetry as _telemetry
+from ..observability.spans import NOOP as _NOOP_SPAN
 from ..observability.spans import span as _span
 
 define_flag(
@@ -35,6 +36,14 @@ define_flag(
     "'warn' (lint on first call, emit findings as warnings), or 'raise' "
     "(additionally fail fast on ERROR-severity findings). Trace-only — "
     "adds one make_jaxpr trace before the first compile, nothing per-step.")
+
+
+def _step_annotation(step: int):
+    """While a jax profiler session is live, the step marker its tools
+    group device work by; otherwise nothing."""
+    if jax.profiler.TraceAnnotation.is_enabled():
+        return jax.profiler.StepTraceAnnotation("train", step_num=step)
+    return _NOOP_SPAN
 
 
 def _tensor_leaves(x):
@@ -182,7 +191,9 @@ class TrainStep:
 
         self._fwd_bwd_fn = fwd_bwd  # overlap tests trace this directly
 
-        def step(param_vals, buffer_vals, opt_state, lr, seed, batch):
+        # the function's name is the XLA module's in a device trace
+        # (jit_train_step), on every path that builds self._jitted
+        def train_step(param_vals, buffer_vals, opt_state, lr, seed, batch):
             saved = [(p._value,) for p in self.params]
             prev_seed = _random.default_generator.push_trace_seed(seed)
             try:
@@ -229,22 +240,25 @@ class TrainStep:
                     for g in g_vals:
                         gsq = gsq + jnp.sum(jnp.square(
                             g.astype(jnp.float32)))
-                clip = optimizer._grad_clip
-                if isinstance(clip, ClipGradByGlobalNorm):
-                    import inspect as _inspect
+                with jax.named_scope("optimizer"):  # clip + update
+                    clip = optimizer._grad_clip
+                    if isinstance(clip, ClipGradByGlobalNorm):
+                        import inspect as _inspect
 
-                    if "params" in _inspect.signature(
-                            clip.functional_clip).parameters:
-                        # hybrid clip: param identities distinguish
-                        # tensor-parallel from replicated norms
-                        g_vals = clip.functional_clip(g_vals,
-                                                      params=self.params)
-                    else:
-                        g_vals = clip.functional_clip(g_vals)
-                elif clip is not None:
-                    pairs = clip([(p, Tensor(g)) for p, g in zip(self.params, g_vals)])
-                    g_vals = [g._value for _, g in pairs]
-                new_p, new_s = optimizer.functional_update(param_vals, g_vals, opt_state, lr)
+                        if "params" in _inspect.signature(
+                                clip.functional_clip).parameters:
+                            # hybrid clip: param identities distinguish
+                            # tensor-parallel from replicated norms
+                            g_vals = clip.functional_clip(g_vals,
+                                                          params=self.params)
+                        else:
+                            g_vals = clip.functional_clip(g_vals)
+                    elif clip is not None:
+                        pairs = clip([(p, Tensor(g)) for p, g
+                                      in zip(self.params, g_vals)])
+                        g_vals = [g._value for _, g in pairs]
+                    new_p, new_s = optimizer.functional_update(
+                        param_vals, g_vals, opt_state, lr)
                 if self._param_shardings is not None:
                     new_p = [
                         jax.lax.with_sharding_constraint(v, sh)
@@ -271,7 +285,7 @@ class TrainStep:
                 for p, (v,) in zip(self.params, saved):
                     p._value = v
 
-        self._step_fn = step  # analysis.lint_train_step traces this
+        self._step_fn = train_step  # analysis.lint_train_step traces this
         self._donate = bool(donate)
         self._linted = False
         donate_argnums = (0, 1, 2) if donate else ()
@@ -309,7 +323,7 @@ class TrainStep:
             # state replicated over dp, batch split on its leading dim;
             # outputs replicated (grads/loss are pmean'ed inside)
             smapped = jax.shard_map(
-                step, mesh=dp_mesh,
+                train_step, mesh=dp_mesh,
                 in_specs=(_P(), _P(), _P(), _P(), _P(), _P(dp_axis)),
                 out_specs=_P(),
                 axis_names=frozenset({dp_axis}), check_vma=False)
@@ -317,10 +331,10 @@ class TrainStep:
             self._io_shardings = (None, None)
             self._jitted = jax.jit(smapped, donate_argnums=donate_argnums)
         else:
-            self._base_callable = step
+            self._base_callable = train_step
             self._io_shardings = (in_shardings, out_shardings)
             self._jitted = jax.jit(
-                step,
+                train_step,
                 donate_argnums=donate_argnums,
                 in_shardings=in_shardings,
                 out_shardings=out_shardings,
@@ -381,10 +395,10 @@ class TrainStep:
             # the body (and the flags it reads) to actually re-trace
             base = self._base_callable
 
-            def retraced(*a):
+            def train_step(*a):
                 return base(*a)
 
-            self._jitted = jax.jit(retraced,
+            self._jitted = jax.jit(train_step,
                                    donate_argnums=self._donate_argnums)
             self._aot = None
             self._aot_sig = None
@@ -401,7 +415,7 @@ class TrainStep:
         are then in effect."""
         base = self._base_callable
 
-        def retraced(*a):
+        def train_step(*a):
             return base(*a)
 
         # same fresh-closure trick as _refresh_overlap_cfg: jax's trace
@@ -413,7 +427,7 @@ class TrainStep:
             kwargs["in_shardings"] = ins
         if outs is not None:
             kwargs["out_shardings"] = outs
-        self._jitted = jax.jit(retraced,
+        self._jitted = jax.jit(train_step,
                                donate_argnums=self._donate_argnums,
                                **kwargs)
         self._aot = None
@@ -515,8 +529,11 @@ class TrainStep:
         batch_vals = _tensor_leaves(batch)
         param_vals = [p._value for p in self.params]
         buffer_vals = [b._value for b in self.buffers]
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        seed = jnp.asarray(self._step_i, jnp.int32)
+        # two eager one-op programs a step (jit_convert_element_type in a
+        # device trace): named here so the gap they leave has an owner
+        with _span("jit.host_scalars", cat="jit"):
+            lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+            seed = jnp.asarray(self._step_i, jnp.int32)
         if not self._linted:
             self._linted = True
             if self._dp_size is not None:
@@ -524,7 +541,9 @@ class TrainStep:
             self._maybe_lint(batch)
         self._step_i += 1
         t0 = time.perf_counter() if self._telemetry else 0.0
-        with _span("jit.train_step", cat="jit"):
+        with _span("jit.train_step", cat="jit",
+                   args={"step": self._step_i - 1}), _step_annotation(
+                       self._step_i - 1):
             out = self._dispatch(
                 param_vals, buffer_vals, self.opt_state, lr, seed, batch_vals
             )

@@ -17,6 +17,8 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
+import jax
+
 from .. import nn
 from ..core.tensor import Tensor
 from ..distributed.fleet.mp_layers import (
@@ -163,14 +165,20 @@ class GPTBlock(nn.Layer):
         self.mlp = GPTMLP(config)
 
     def forward(self, x, rope=None, cache=None, pos=None, segments=None):
+        # named scopes reach op_name in the HLO and the device trace; the
+        # caller's scope (GPTModel: h{i}) is the layer
         if cache is not None:
-            a, new_cache = self.attn(self.ln_1(x), rope=rope, cache=cache,
-                                     pos=pos)
-            x = x + a
-            x = x + self.mlp(self.ln_2(x))
+            with jax.named_scope("attn"):
+                a, new_cache = self.attn(self.ln_1(x), rope=rope,
+                                         cache=cache, pos=pos)
+                x = x + a
+            with jax.named_scope("mlp"):
+                x = x + self.mlp(self.ln_2(x))
             return x, new_cache
-        x = x + self.attn(self.ln_1(x), rope=rope, segments=segments)
-        x = x + self.mlp(self.ln_2(x))
+        with jax.named_scope("attn"):
+            x = x + self.attn(self.ln_1(x), rope=rope, segments=segments)
+        with jax.named_scope("mlp"):
+            x = x + self.mlp(self.ln_2(x))
         return x
 
 
@@ -214,9 +222,27 @@ class GPTModel(nn.Layer):
                     Tensor(cached[1][:seq_len]))
         return None
 
+    def _embed(self, input_ids, positions=None):
+        """Token embedding, plus the learned positions where the model has
+        them (rotary models take theirs inside attention), and dropout."""
+        with jax.named_scope("embed"):
+            h = self.wte(input_ids)
+            if positions is not None:
+                h = h + self.wpe(positions)
+            return self.drop(h)
+
+    def _cached_blocks(self, h, caches, rope, pos):
+        new_caches = []
+        for i, (block, cache) in enumerate(zip(self.blocks, caches)):
+            with jax.named_scope(f"h{i}"):
+                h, nc = block(h, rope=rope, cache=cache, pos=pos)
+            new_caches.append(nc)
+        with jax.named_scope("final_norm"):
+            return self.ln_f(h), new_caches
+
     def forward(self, input_ids, caches=None, pos=None, segments=None):
         b, s = input_ids.shape
-        h = self.wte(input_ids)
+        rotary = self.config.use_rotary
         rope = None
         if caches is not None:
             if segments is not None:
@@ -226,64 +252,41 @@ class GPTModel(nn.Layer):
             import jax.numpy as jnp
             from jax import lax
 
-            if hasattr(caches[0], "block_table"):
+            paged = hasattr(caches[0], "block_table")
+            if paged:
                 # paged decode: PER-SLOT positions (each slot is mid-way
                 # through its own sequence) ride the packed-rope / gathered
                 # wpe form instead of a scalar offset; s > 1 is the
                 # speculative verify window at positions seq_lens..+s-1
-                pos_v = caches[0].seq_lens
-                pos_v = (pos_v._value if isinstance(pos_v, Tensor)
-                         else jnp.asarray(pos_v)).astype(jnp.int32)
-                pos2d = pos_v[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
-                if self.config.use_rotary:
-                    cos, sin = self._rope(
-                        self.config.max_position_embeddings)
-                    rope = (cos, sin, Tensor(pos2d))
-                else:
-                    h = h + self.wpe(Tensor(pos2d))
-                h = self.drop(h)
-                new_caches = []
-                for block, cache in zip(self.blocks, caches):
-                    h, nc = block(h, rope=rope, cache=cache, pos=None)
-                    new_caches.append(nc)
-                return self.ln_f(h), new_caches
+                pos = caches[0].seq_lens
             pos_v = pos._value if isinstance(pos, Tensor) else jnp.asarray(pos)
             pos_v = pos_v.astype(jnp.int32)
-            if pos_v.ndim == 1 and pos_v.shape[0] == b:
-                # ragged batched prefill (serving engine): each row starts
-                # at its OWN offset — per-token positions ride the packed
-                # rope / gathered wpe form, and the cached attention op
-                # takes the per-row offset vector
+            if paged or (pos_v.ndim == 1 and pos_v.shape[0] == b):
+                # the same form serves ragged batched prefill (serving
+                # engine): each row starts at its OWN offset, and the
+                # cached attention op takes the per-row offset vector
                 pos2d = pos_v[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
-                if self.config.use_rotary:
+                if rotary:
                     cos, sin = self._rope(
                         self.config.max_position_embeddings)
                     rope = (cos, sin, Tensor(pos2d))
-                else:
-                    h = h + self.wpe(Tensor(pos2d))
-                h = self.drop(h)
-                new_caches = []
-                for block, cache in zip(self.blocks, caches):
-                    h, nc = block(h, rope=rope, cache=cache,
-                                  pos=Tensor(pos_v))
-                    new_caches.append(nc)
-                return self.ln_f(h), new_caches
+                h = self._embed(input_ids, None if rotary else Tensor(pos2d))
+                return self._cached_blocks(
+                    h, caches, rope, None if paged else Tensor(pos_v))
             pos_v = pos_v.reshape(())
-            if self.config.use_rotary:
+            if rotary:
                 cos, sin = self._rope(self.config.max_position_embeddings)
                 rope = (Tensor(lax.dynamic_slice(
                             cos._value, (pos_v, 0), (s, cos.shape[-1]))),
                         Tensor(lax.dynamic_slice(
                             sin._value, (pos_v, 0), (s, sin.shape[-1]))))
+                h = self._embed(input_ids)
             else:
-                p = api.arange(0, s, 1, dtype="int32") + Tensor(pos_v)
-                h = h + self.wpe(p)
-            h = self.drop(h)
-            new_caches = []
-            for block, cache in zip(self.blocks, caches):
-                h, nc = block(h, rope=rope, cache=cache, pos=Tensor(pos_v))
-                new_caches.append(nc)
-            return self.ln_f(h), new_caches
+                h = self._embed(
+                    input_ids,
+                    api.arange(0, s, 1, dtype="int32") + Tensor(pos_v))
+            return self._cached_blocks(h, caches, rope, Tensor(pos_v))
+        positions = None
         if segments is not None:
             # positions RESTART at each packed document so a packed row
             # embeds exactly like the same documents padded separately
@@ -294,28 +297,29 @@ class GPTModel(nn.Layer):
             seg_v = (segments._value if isinstance(segments, Tensor)
                      else jnp.asarray(segments)).astype(jnp.int32)
             pos2d = packed_positions(seg_v, s)  # [b, s] per-doc positions
-            if self.config.use_rotary:
+            if rotary:
                 # packed rope rides tables + per-token positions; the TPU
                 # kernel gathers rows in-kernel (one-hot MXU lookup)
                 cos_t, sin_t = self._rope(s)
                 rope = (cos_t, sin_t, Tensor(pos2d))
             else:
-                h = h + self.wpe(Tensor(pos2d))
-        elif self.config.use_rotary:
+                positions = Tensor(pos2d)
+        elif rotary:
             rope = self._rope(s)
         else:
-            p = api.arange(0, s, 1, dtype="int32")
-            h = h + self.wpe(p)
-        h = self.drop(h)
-        for block in self.blocks:
-            if self.config.recompute and self.training:
-                from ..distributed.fleet.recompute import recompute
+            positions = api.arange(0, s, 1, dtype="int32")
+        h = self._embed(input_ids, positions)
+        for i, block in enumerate(self.blocks):
+            with jax.named_scope(f"h{i}"):
+                if self.config.recompute and self.training:
+                    from ..distributed.fleet.recompute import recompute
 
-                h = recompute(block, h, rope=rope, segments=segments,
-                              policy=self.config.recompute_policy)
-            else:
-                h = block(h, rope=rope, segments=segments)
-        return self.ln_f(h)
+                    h = recompute(block, h, rope=rope, segments=segments,
+                                  policy=self.config.recompute_policy)
+                else:
+                    h = block(h, rope=rope, segments=segments)
+        with jax.named_scope("final_norm"):
+            return self.ln_f(h)
 
 
 class GPTForCausalLM(nn.Layer, GenerationMixin):
@@ -333,9 +337,10 @@ class GPTForCausalLM(nn.Layer, GenerationMixin):
                 c.max_position_embeddings)
 
     def _head(self, h):
-        if self.config.tie_word_embeddings:
-            return api.matmul(h, self.gpt.wte.weight, transpose_y=True)
-        return self.lm_head(h)
+        with jax.named_scope("lm_head"):
+            if self.config.tie_word_embeddings:
+                return api.matmul(h, self.gpt.wte.weight, transpose_y=True)
+            return self.lm_head(h)
 
     def forward(self, input_ids, labels=None, caches=None, pos=None,
                 segments=None):
@@ -361,21 +366,21 @@ class GPTForCausalLM(nn.Layer, GenerationMixin):
             from ..core.tensor import Tensor
 
             v = self.config.vocab_size
-            shift_logits = api.reshape(logits[:, :-1, :], [-1, v])
-            lab = labels._value if isinstance(labels, Tensor) else \
-                jnp.asarray(labels)
-            shift_lab = lab[:, 1:]
-            if segments is not None:
-                seg_v = (segments._value if isinstance(segments, Tensor)
-                         else jnp.asarray(segments))
-                # a pair crossing a packed-document boundary is not a
-                # next-token example; padding (-1 segment) masks too
-                same_doc = (seg_v[:, 1:] == seg_v[:, :-1]) \
-                    & (seg_v[:, 1:] >= 0)
-                shift_lab = jnp.where(same_doc, shift_lab, -100)
-            loss = F.cross_entropy(shift_logits,
-                                   api.reshape(Tensor(shift_lab), [-1]))
-            return loss
+            with jax.named_scope("loss"):
+                shift_logits = api.reshape(logits[:, :-1, :], [-1, v])
+                lab = labels._value if isinstance(labels, Tensor) else \
+                    jnp.asarray(labels)
+                shift_lab = lab[:, 1:]
+                if segments is not None:
+                    seg_v = (segments._value if isinstance(segments, Tensor)
+                             else jnp.asarray(segments))
+                    # a pair crossing a packed-document boundary is not a
+                    # next-token example; padding (-1 segment) masks too
+                    same_doc = (seg_v[:, 1:] == seg_v[:, :-1]) \
+                        & (seg_v[:, 1:] >= 0)
+                    shift_lab = jnp.where(same_doc, shift_lab, -100)
+                return F.cross_entropy(shift_logits,
+                                       api.reshape(Tensor(shift_lab), [-1]))
         return logits
 
 

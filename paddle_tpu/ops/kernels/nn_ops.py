@@ -943,14 +943,15 @@ def paged_cached_attention(q, k, v, k_pages, v_pages, block_table, seq_lens,
 
     if sq == 1:
         # KV append: one token per slot at (block_table[seq//bs], seq%bs)
-        page = jnp.take_along_axis(
-            block_table.astype(jnp.int32),
-            (seq_lens // bs)[:, None], axis=1)[:, 0]
-        off = seq_lens % bs
-        k_pages = k_pages.at[page, :, off].set(
-            k[:, 0].astype(k_pages.dtype))
-        v_pages = v_pages.at[page, :, off].set(
-            v[:, 0].astype(v_pages.dtype))
+        with jax.named_scope("kv_append"):
+            page = jnp.take_along_axis(
+                block_table.astype(jnp.int32),
+                (seq_lens // bs)[:, None], axis=1)[:, 0]
+            off = seq_lens % bs
+            k_pages = k_pages.at[page, :, off].set(
+                k[:, 0].astype(k_pages.dtype))
+            v_pages = v_pages.at[page, :, off].set(
+                v[:, 0].astype(v_pages.dtype))
         ctx = seq_lens + 1  # the token just written attends to itself
 
         q2 = q[:, 0]
@@ -966,16 +967,17 @@ def paged_cached_attention(q, k, v, k_pages, v_pages, block_table, seq_lens,
         return out[:, None], k_pages, v_pages
 
     # ---- multi-token verify window ----
-    bt = block_table.astype(jnp.int32)
-    pos = seq_lens[:, None] + jnp.arange(sq, dtype=jnp.int32)[None, :]
-    page_idx = pos // bs                                     # [slots, sq]
-    in_table = page_idx < bt.shape[1]
-    gathered = jnp.take_along_axis(
-        bt, jnp.minimum(page_idx, bt.shape[1] - 1), axis=1)
-    page = jnp.where(in_table, gathered, 0)    # overflow -> null page
-    off = pos % bs
-    k_pages = k_pages.at[page, :, off].set(k.astype(k_pages.dtype))
-    v_pages = v_pages.at[page, :, off].set(v.astype(v_pages.dtype))
+    with jax.named_scope("kv_append"):
+        bt = block_table.astype(jnp.int32)
+        pos = seq_lens[:, None] + jnp.arange(sq, dtype=jnp.int32)[None, :]
+        page_idx = pos // bs                                 # [slots, sq]
+        in_table = page_idx < bt.shape[1]
+        gathered = jnp.take_along_axis(
+            bt, jnp.minimum(page_idx, bt.shape[1] - 1), axis=1)
+        page = jnp.where(in_table, gathered, 0)    # overflow -> null page
+        off = pos % bs
+        k_pages = k_pages.at[page, :, off].set(k.astype(k_pages.dtype))
+        v_pages = v_pages.at[page, :, off].set(v.astype(v_pages.dtype))
 
     kernel_ok = _paged_supports((slots, hq, d), k_pages.shape)
     if kernel_ok and _pallas.interpret_mode():

@@ -126,6 +126,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
             _sds((bh, sq, d), q.dtype, qr),
             _sds((bh, sq, 1), jnp.float32, qr),
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(qr, kr, vr)
     o = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
@@ -256,6 +257,7 @@ def _bwd(scale, causal, block_q, block_k, interpret, res, g, dlse=None):
         ],
         out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=_sds((bh, sq, d), qr.dtype, qr),
+        name="flash_bwd_dq",
         interpret=interpret,
     )(qr, kr, vr, do, lse, delta)
 
@@ -280,6 +282,7 @@ def _bwd(scale, causal, block_q, block_k, interpret, res, g, dlse=None):
             _sds((bh, sk, d), kr.dtype, qr),
             _sds((bh, sk, d), vr.dtype, qr),
         ],
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(qr, kr, vr, do, lse, delta)
 
@@ -602,6 +605,7 @@ def _seg_fwd(q, k, v, seg, scale, causal, block_q, block_k, interpret):
             _sds((bh, sq, d), q.dtype, qr),
             _sds((bh, sq, 1), jnp.float32, qr),
         ],
+        name="flash_seg_fwd",
         interpret=interpret,
     )(qr, kr, vr, segr, segr)
     o = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
@@ -643,6 +647,7 @@ def _seg_bwd(scale, causal, block_q, block_k, interpret, res, g):
         ],
         out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=_sds((bh, sq, d), qr.dtype, qr),
+        name="flash_seg_bwd_dq",
         interpret=interpret,
     )(qr, kr, vr, segr, segr, do, lse, delta)
 
@@ -668,6 +673,7 @@ def _seg_bwd(scale, causal, block_q, block_k, interpret, res, g):
             _sds((bh, sk, d), kr.dtype, qr),
             _sds((bh, sk, d), vr.dtype, qr),
         ],
+        name="flash_seg_bwd_dkv",
         interpret=interpret,
     )(qr, kr, vr, segr, segr, do, lse, delta)
 

@@ -103,6 +103,7 @@ def fused_adamw_update(p, g, m, v, *, lr, beta1=0.9, beta2=0.999, eps=1e-8,
             jax.ShapeDtypeStruct(m_.shape, m.dtype),
             jax.ShapeDtypeStruct(v_.shape, v.dtype),
         ],
+        name="fused_adamw",
         interpret=interpret,
     )(p_, g_, m_, v_, sc)
     if pad:

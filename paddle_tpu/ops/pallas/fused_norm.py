@@ -92,6 +92,7 @@ def _run_fwd(x, w, eps, interpret):
             jax.ShapeDtypeStruct((np_, d), x.dtype),
             jax.ShapeDtypeStruct((np_, 1), jnp.float32),
         ],
+        name="rms_norm_fwd",
         interpret=interpret,
     )(xp, wr)
     if pad:
@@ -143,6 +144,7 @@ def _bwd_rule(epsilon, interpret, res, g):
             jax.ShapeDtypeStruct((np_, d), xr.dtype),
             jax.ShapeDtypeStruct((1, d), jnp.float32),
         ],
+        name="rms_norm_bwd",
         interpret=interpret,
     )(xr_p, w.reshape(1, d), rstd_p, gr_p)
     return dx[:n].reshape(orig_shape), dw.reshape(d).astype(w.dtype)

@@ -153,6 +153,7 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, context_lens, scale,
             jax.ShapeDtypeStruct((slots, hkv, kv_splits, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((slots, hkv, kv_splits, g, 1), jnp.float32),
         ],
+        name="paged_decode",
         interpret=interpret,
     )(bt, cl, qr, k_pages, v_pages)
 
@@ -277,6 +278,7 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
             jax.ShapeDtypeStruct((slots, hkv, kv_splits, rows, 1),
                                  jnp.float32),
         ],
+        name="paged_verify",
         interpret=interpret,
     )(bt, cl, qr, k_pages, v_pages)
 

@@ -43,6 +43,12 @@ def _seq_block(s, h, d, itemsize):
     return s
 
 
+def _name(sign):
+    """Kernel name in the trace: the VJP is the forward kernel run with the
+    sign turned."""
+    return "rope_fwd" if sign > 0 else "rope_bwd"
+
+
 def _apply(x, cos, sin, sign, interpret):
     b, s, h, d = x.shape
     bs = _seq_block(s, h, d, x.dtype.itemsize)
@@ -56,6 +62,7 @@ def _apply(x, cos, sin, sign, interpret):
         ],
         out_specs=pl.BlockSpec((None, bs, h, d), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, s, h, d), x.dtype),
+        name=_name(sign),
         interpret=interpret,
     )(x, cos, sin)
 
@@ -175,6 +182,7 @@ def _apply_packed(x, pos2d, cos_tab, sin_tab, sign, interpret):
         ],
         out_specs=pl.BlockSpec((None, bs, h, d), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        name=_name(sign),
         interpret=interpret,
     )(x, pos8, cos_tab.astype(jnp.float32), sin_tab.astype(jnp.float32))
 

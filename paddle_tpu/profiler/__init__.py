@@ -263,6 +263,9 @@ class Profiler:
         if native.available():
             native.trace_clear()
             native.trace_enable(True)
+            # runtime spans mirror themselves into the native tracer
+            # while this session says it records there
+            _obs_spans.session(True, native=True)
         else:
             # pure-Python fallback: open a span-ring session and note the
             # watermark — stop collects everything recorded after it
@@ -290,6 +293,7 @@ class Profiler:
         if native.available():
             self._spans = native.trace_spans()
             native.trace_enable(False)
+            _obs_spans.session(False, native=True)
         else:
             self._spans = _obs_spans.since(self._span_mark)
             _obs_spans.session(False)

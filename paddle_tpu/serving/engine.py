@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import random
 import threading
+import time
 from typing import List, Optional
 
 import jax
@@ -46,6 +47,8 @@ from ..models.generation import init_kv_cache
 from .blocks import BlockAllocator
 from .observability import (
     _PREFILL_TOKENS,
+    PROGRAMS_BUILT,
+    SUBMIT_LOCK_WAIT_H,
     EngineStats,
     ServingObservability,
     new_engine_id,
@@ -341,6 +344,26 @@ class ServingEngine:
         return (paged_fn, static_fn,
                 [p._value for p in params], [b._value for b in buffers])
 
+    def _program(self, kind: str, key, build):
+        """The compiled program under `key`. One not seen before is built
+        (`build()` returns the jitted function, whose name is the XLA
+        module's in a device trace), counted, and handed back so that its
+        first call, the one that traces and compiles, is inside a
+        `serving.program_build` span."""
+        fn = self._jit.get(key)
+        if fn is not None:
+            return fn
+        PROGRAMS_BUILT.inc(kind=kind)
+        fn = self._jit[key] = build()
+        obs = self.obs
+
+        def first_call(*args):
+            with obs.span("serving.program_build", kind=kind, key=str(key)):
+                return fn(*args)
+
+        first_call.lower = fn.lower     # chip_smoke and the compile tests
+        return first_call
+
     def _decode_jit(self, sampled: bool):
         """Two compiled variants: the all-greedy batch skips the threefry
         key derivation + Gumbel draw entirely (~0.2ms/step on CPU for a
@@ -348,29 +371,35 @@ class ServingEngine:
         it. Both share the (tok, pages, bt, sl, temps, seed) signature so
         the engine can switch per tick as the batch mix changes."""
         key = ("decode", self.max_slots, self.max_blocks_per_seq, sampled)
-        if key not in self._jit:
+
+        def build():
             paged_fn = self._functional()[0]
 
+            # the one program whose function keeps the name `step`: the
+            # benchmark's decode_step_ms reads XLA module jit_step
             def step(pv, bv, tok, pages, bt, sl, temps, seed):
                 logits, new_pages = paged_fn(pv, bv, tok[:, None], pages,
                                              bt, sl)
-                lg = logits[:, -1, :].astype(jnp.float32)
-                greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-                if sampled:
-                    key_ = jax.random.fold_in(jax.random.PRNGKey(0), seed)
-                    t = jnp.maximum(temps, 1e-6)[:, None]
-                    draw = jax.random.categorical(
-                        key_, lg / t, axis=-1).astype(jnp.int32)
-                    nxt = jnp.where(temps > 0.0, draw, greedy)
-                else:
-                    nxt = greedy
+                with jax.named_scope("sample"):
+                    lg = logits[:, -1, :].astype(jnp.float32)
+                    greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+                    if sampled:
+                        key_ = jax.random.fold_in(jax.random.PRNGKey(0),
+                                                  seed)
+                        t = jnp.maximum(temps, 1e-6)[:, None]
+                        draw = jax.random.categorical(
+                            key_, lg / t, axis=-1).astype(jnp.int32)
+                        nxt = jnp.where(temps > 0.0, draw, greedy)
+                    else:
+                        nxt = greedy
                 # sl/seed advance on device so steady-state ticks feed these
                 # outputs straight back in (idle slots drift harmlessly —
                 # they re-upload when the slot is next filled)
                 return nxt, new_pages, sl + 1, seed + 1
 
-            self._jit[key] = jax.jit(step, donate_argnums=(3, 5, 7))
-        return self._jit[key]
+            return jax.jit(step, donate_argnums=(3, 5, 7))
+
+        return self._program("decode", key, build)
 
     def _decode_multi_jit(self, k: int):
         """k decode steps fused into ONE compiled program (all-greedy
@@ -380,16 +409,18 @@ class ServingEngine:
         k-fold. Returns the k sampled tokens flattened [k * slots] for the
         deferred-flush path plus the same carry as the 1-step program."""
         key = ("decode_multi", self.max_slots, self.max_blocks_per_seq, k)
-        if key not in self._jit:
+
+        def build():
             paged_fn = self._functional()[0]
 
-            def step(pv, bv, tok, pages, bt, sl, temps, seed):
+            def serve_decode_fused(pv, bv, tok, pages, bt, sl, temps, seed):
                 def body(i, carry):
                     tok, pages, sl, out = carry
                     logits, new_pages = paged_fn(pv, bv, tok[:, None],
                                                  pages, bt, sl)
-                    lg = logits[:, -1, :].astype(jnp.float32)
-                    nxt = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+                    with jax.named_scope("sample"):
+                        lg = logits[:, -1, :].astype(jnp.float32)
+                        nxt = jnp.argmax(lg, axis=-1).astype(jnp.int32)
                     return nxt, new_pages, sl + 1, out.at[i].set(nxt)
 
                 out0 = jnp.zeros((k, tok.shape[0]), jnp.int32)
@@ -397,8 +428,9 @@ class ServingEngine:
                     0, k, body, (tok, pages, sl, out0))
                 return tok, pages, sl, seed + k, out.reshape(-1)
 
-            self._jit[key] = jax.jit(step, donate_argnums=(3, 5, 7))
-        return self._jit[key]
+            return jax.jit(serve_decode_fused, donate_argnums=(3, 5, 7))
+
+        return self._program("decode_multi", key, build)
 
     def _spec_jit(self, W: int, sampled: bool):
         """Speculative verify: score a W-token window (current token +
@@ -414,32 +446,38 @@ class ServingEngine:
         their column-0 logits are the same distribution the plain step
         would compute, and their next token is the categorical draw."""
         key = ("spec", self.max_slots, self.max_blocks_per_seq, W, sampled)
-        if key not in self._jit:
+
+        def build():
             paged_fn = self._functional()[0]
 
-            def step(pv, bv, win, pages, bt, sl, dls, temps, seed):
+            def serve_spec_verify(pv, bv, win, pages, bt, sl, dls, temps,
+                                  seed):
                 logits, new_pages = paged_fn(pv, bv, win, pages, bt, sl)
-                lg = logits.astype(jnp.float32)       # [slots, W, vocab]
-                greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-                # accepted = longest prefix where draft i+1 equals the
-                # greedy target after window position i
-                ok = ((win[:, 1:] == greedy[:, :-1])
-                      & (jnp.arange(W - 1, dtype=jnp.int32)[None, :]
-                         < dls[:, None]))
-                acc = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1),
-                              axis=1)
-                nxt = jnp.take_along_axis(greedy, acc[:, None],
-                                          axis=1)[:, 0]
-                if sampled:
-                    key_ = jax.random.fold_in(jax.random.PRNGKey(0), seed)
-                    t = jnp.maximum(temps, 1e-6)[:, None]
-                    draw = jax.random.categorical(
-                        key_, lg[:, 0, :] / t, axis=-1).astype(jnp.int32)
-                    nxt = jnp.where(temps > 0.0, draw, nxt)
+                with jax.named_scope("sample"):
+                    lg = logits.astype(jnp.float32)   # [slots, W, vocab]
+                    greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+                    # accepted = longest prefix where draft i+1 equals the
+                    # greedy target after window position i
+                    ok = ((win[:, 1:] == greedy[:, :-1])
+                          & (jnp.arange(W - 1, dtype=jnp.int32)[None, :]
+                             < dls[:, None]))
+                    acc = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1),
+                                  axis=1)
+                    nxt = jnp.take_along_axis(greedy, acc[:, None],
+                                              axis=1)[:, 0]
+                    if sampled:
+                        key_ = jax.random.fold_in(jax.random.PRNGKey(0),
+                                                  seed)
+                        t = jnp.maximum(temps, 1e-6)[:, None]
+                        draw = jax.random.categorical(
+                            key_, lg[:, 0, :] / t,
+                            axis=-1).astype(jnp.int32)
+                        nxt = jnp.where(temps > 0.0, draw, nxt)
                 return greedy, acc, nxt, new_pages, sl + acc + 1, seed + 1
 
-            self._jit[key] = jax.jit(step, donate_argnums=(3, 5, 8))
-        return self._jit[key]
+            return jax.jit(serve_spec_verify, donate_argnums=(3, 5, 8))
+
+        return self._program("spec", key, build)
 
     def _clear_slot_jit(self):
         """Fused device-side slot clear for _finish: zero the slot's token,
@@ -452,15 +490,17 @@ class ServingEngine:
         table row points the idle slot at the null block, where its writes
         are harmless and its (len 0) context is never read."""
         key = ("clear_slot", self.max_slots, self.max_blocks_per_seq)
-        if key not in self._jit:
-            def clear(toks, bt, sl, temps, slot):
+
+        def build():
+            def serve_clear_slot(toks, bt, sl, temps, slot):
                 return (toks.at[slot].set(0),
                         bt.at[slot].set(jnp.zeros((bt.shape[1],), bt.dtype)),
                         sl.at[slot].set(0),
                         temps.at[slot].set(0.0))
 
-            self._jit[key] = jax.jit(clear)
-        return self._jit[key]
+            return jax.jit(serve_clear_slot)
+
+        return self._program("clear_slot", key, build)
 
     def _admit_jit(self, chunk):
         """Fused admission for greedy requests: the first token (argmax of
@@ -472,9 +512,10 @@ class ServingEngine:
         slot. No donation: the incoming token vector is also referenced by
         the deferred-flush queue."""
         key = ("admit", chunk, self.max_slots, self.max_blocks_per_seq)
-        if key not in self._jit:
-            def admit(logits, idx, toks, bt, sl, temps, slot, table, plen,
-                      temp):
+
+        def build():
+            def serve_admit(logits, idx, toks, bt, sl, temps, slot, table,
+                            plen, temp):
                 lg = logits[0, idx].astype(jnp.float32)
                 first = jnp.argmax(lg, axis=-1).astype(jnp.int32)
                 return (first[None],
@@ -483,19 +524,22 @@ class ServingEngine:
                         sl.at[slot].set(plen),
                         temps.at[slot].set(temp))
 
-            self._jit[key] = jax.jit(admit)
-        return self._jit[key]
+            return jax.jit(serve_admit)
+
+        return self._program("admit", key, build)
 
     def _prefill_jit(self, chunk, padded):
         key = ("prefill", chunk, padded)
-        if key not in self._jit:
+
+        def build():
             static_fn = self._functional()[1]
 
-            def pf(pv, bv, ids, caches, pos):
+            def serve_prefill(pv, bv, ids, caches, pos):
                 return static_fn(pv, bv, ids, caches, pos)
 
-            self._jit[key] = jax.jit(pf, donate_argnums=(3,))
-        return self._jit[key]
+            return jax.jit(serve_prefill, donate_argnums=(3,))
+
+        return self._program("prefill", key, build)
 
     def _gather_jit(self, padded, mb):
         """Materialize a prefill workspace whose head is a cached prefix
@@ -503,11 +547,12 @@ class ServingEngine:
         chunks run the contiguous cached path on top of it). Pages are NOT
         donated — they stay the live pool."""
         key = ("gather", padded, mb)
-        if key not in self._jit:
+
+        def build():
             bs = self.block_size
             n = mb * bs
 
-            def g(pages, table):
+            def serve_gather(pages, table):
                 out = []
                 for kp, vp in pages:
                     hkv, d = kp.shape[1], kp.shape[3]
@@ -518,8 +563,9 @@ class ServingEngine:
                     out.append((k, v))
                 return out
 
-            self._jit[key] = jax.jit(g)
-        return self._jit[key]
+            return jax.jit(serve_gather)
+
+        return self._program("gather", key, build)
 
     def _admit_cow_jit(self):
         """Full-prompt cache hit: fork the last shared block (device copy
@@ -529,9 +575,10 @@ class ServingEngine:
         state tensors are not (the token vector may be referenced by the
         deferred-flush queue)."""
         key = ("admit_cow", self.max_slots, self.max_blocks_per_seq)
-        if key not in self._jit:
-            def f(pages, toks, bt, sl, temps, src, dst, slot, table, plen,
-                  tok, temp):
+
+        def build():
+            def serve_admit_cow(pages, toks, bt, sl, temps, src, dst, slot,
+                                table, plen, tok, temp):
                 new = [(kp.at[dst].set(kp[src]), vp.at[dst].set(vp[src]))
                        for kp, vp in pages]
                 return (new,
@@ -540,8 +587,9 @@ class ServingEngine:
                         sl.at[slot].set(plen),
                         temps.at[slot].set(temp))
 
-            self._jit[key] = jax.jit(f, donate_argnums=(0,))
-        return self._jit[key]
+            return jax.jit(serve_admit_cow, donate_argnums=(0,))
+
+        return self._program("admit_cow", key, build)
 
     def _batched_prefill_jit(self, S, P):
         """ONE compiled program admitting up to max_slots prompts: gather
@@ -560,12 +608,14 @@ class ServingEngine:
         gathered, so duplicate-index writes are deterministic."""
         n = self.max_slots
         key = ("batched_prefill", n, S, P)
-        if key not in self._jit:
+
+        def build():
             static_fn = self._functional()[1]
             bs = self.block_size
 
-            def bp(pv, bv, pages, ids, pos, tP, last, slots, bt_rows,
-                   plens, temps, d_toks, d_bt, d_sl, d_temps):
+            def serve_batched_prefill(pv, bv, pages, ids, pos, tP, last,
+                                      slots, bt_rows, plens, temps, d_toks,
+                                      d_bt, d_sl, d_temps):
                 caches = [(from_pages(kp[tP]), from_pages(vp[tP]))
                           for kp, vp in pages]
                 logits, ncs = static_fn(pv, bv, ids, caches, pos)
@@ -586,8 +636,9 @@ class ServingEngine:
                         d_sl.at[slots].set(plens),
                         d_temps.at[slots].set(temps))
 
-            self._jit[key] = jax.jit(bp, donate_argnums=(2,))
-        return self._jit[key]
+            return jax.jit(serve_batched_prefill, donate_argnums=(2,))
+
+        return self._program("batched_prefill", key, build)
 
     def _scatter_jit(self, padded, nb):
         """Scatter a prefilled workspace prefix into the pool pages. The
@@ -595,17 +646,19 @@ class ServingEngine:
         layer per prompt is pure dispatch overhead); both the pool and the
         spent workspace are donated."""
         key = ("scatter", padded, nb)
-        if key not in self._jit:
+
+        def build():
             bs = self.block_size
             n = nb * bs
 
-            def sc(pages, caches, table):
+            def serve_scatter(pages, caches, table):
                 return [write_prefix(kp, vp, k[0, :n], v[0, :n], table,
                                      block_size=bs)
                         for (kp, vp), (k, v) in zip(pages, caches)]
 
-            self._jit[key] = jax.jit(sc, donate_argnums=(0,))
-        return self._jit[key]
+            return jax.jit(serve_scatter, donate_argnums=(0,))
+
+        return self._program("scatter", key, build)
 
     # ------------------------------------------------------------- intake
     def submit(self, prompt: List[int], max_new_tokens: int = 16,
@@ -620,7 +673,14 @@ class ServingEngine:
                       request_id=request_id, tier=tier, trace_ctx=trace_ctx,
                       prefill_only=prefill_only)
         max_queue = int(_flags.get_flag("serving_max_queue"))
-        with self._lock:
+        # step() holds the lock all through a tick: what a request waits
+        # here is counted on /metrics always, and a span while one records
+        t0 = time.monotonic()
+        with self.obs.span("serving.submit_wait",
+                           request_id=req.request_id):
+            self._lock.acquire()
+        try:
+            SUBMIT_LOCK_WAIT_H.observe(time.monotonic() - t0)
             if self._draining:
                 self.obs.on_shed(req, "draining")
                 raise EngineDrainingError()
@@ -630,6 +690,8 @@ class ServingEngine:
                 raise QueueFullError(depth, max_queue)
             self.obs.on_submit(req)
             self.sched.submit(req)
+        finally:
+            self._lock.release()
         return req
 
     # ----------------------------------------------------------- drain
@@ -769,7 +831,16 @@ class ServingEngine:
         """One engine tick: admissions, one prefill chunk, one decode step
         over the running batch. Returns per-tick stats."""
         with self._lock:
-            t0 = self.obs.tick_begin()
+            self.obs.tick_begin()
+            with self.obs.span("serving.tick") as tick:
+                out = self._tick()
+                tick.set(step=self.steps, decoded=out["decoded_tokens"],
+                         running=out["running"], waiting=out["waiting"])
+            self.obs.on_tick(out)
+            return out
+
+    def _tick(self) -> dict:
+        with self.obs.span("serving.schedule") as sp:
             admitted = self.sched.admit()
             for req in admitted:
                 self.obs.on_admitted(req)
@@ -778,34 +849,33 @@ class ServingEngine:
             for req in [r for r in self.sched.prefilling
                         if r._cow_src is not None]:
                 self._admit_cached(req)
-            # batched multi-prompt prefill: a burst of short unmatched
-            # suffixes admits in ONE dispatch instead of one per prompt
-            if self.prefill_bucket > 0:
-                batch = [r for r in self.sched.prefilling
-                         if r._ws_caches is None and r.temperature <= 0.0
-                         and 0 < (len(r.prompt) - r.prefill_pos)
-                         <= self.prefill_chunk]
-                if len(batch) >= 2:
-                    self._batched_prefill(batch[:self.max_slots])
-            # one prefill chunk per tick bounds how long a prompt can stall
-            # the running batch — but a slot with NOTHING to decode isn't
-            # stalled, so after a burst (many admissions, few running) keep
-            # prefilling up to one chunk per idle slot and the whole wave
-            # joins decode this tick instead of trickling in serially
-            budget = max(1, self.max_slots - len(self.sched.running))
-            for _ in range(budget):
-                req = self.sched.next_prefill()
-                if req is None:
-                    break
-                self._prefill_one_chunk(req)
-                if self.sched.next_prefill() is req:
-                    break   # long prompt mid-prefill: one chunk per tick
-            decoded = self._decode_step() if self.sched.running else 0
-            self.steps += 1
-            out = {"admitted": len(admitted), "decoded_tokens": decoded,
-                   **self.sched.counts()}
-            self.obs.on_tick(t0, out)
-            return out
+            sp.set(admitted=len(admitted), waiting=len(self.sched.waiting))
+        # batched multi-prompt prefill: a burst of short unmatched
+        # suffixes admits in ONE dispatch instead of one per prompt
+        if self.prefill_bucket > 0:
+            batch = [r for r in self.sched.prefilling
+                     if r._ws_caches is None and r.temperature <= 0.0
+                     and 0 < (len(r.prompt) - r.prefill_pos)
+                     <= self.prefill_chunk]
+            if len(batch) >= 2:
+                self._batched_prefill(batch[:self.max_slots])
+        # one prefill chunk per tick bounds how long a prompt can stall
+        # the running batch — but a slot with NOTHING to decode isn't
+        # stalled, so after a burst (many admissions, few running) keep
+        # prefilling up to one chunk per idle slot and the whole wave
+        # joins decode this tick instead of trickling in serially
+        budget = max(1, self.max_slots - len(self.sched.running))
+        for _ in range(budget):
+            req = self.sched.next_prefill()
+            if req is None:
+                break
+            self._prefill_one_chunk(req)
+            if self.sched.next_prefill() is req:
+                break   # long prompt mid-prefill: one chunk per tick
+        decoded = self._decode_step() if self.sched.running else 0
+        self.steps += 1
+        return {"admitted": len(admitted), "decoded_tokens": decoded,
+                **self.sched.counts()}
 
     def run_until_idle(self, max_steps: int = 1_000_000) -> int:
         steps = 0
@@ -873,58 +943,59 @@ class ServingEngine:
         context (cached prefix + suffix) padded to P tokens. Greedy-only:
         each row's first token is argmaxed on device and its fetch
         deferred like any decode token."""
-        t0 = self.obs.now()
-        _, _, pv, bv = self._functional()
-        n = self.max_slots
-        bs = self.block_size
-        bucket = max(self.prefill_bucket, 1)
         suffixes = [len(r.prompt) - r.prefill_pos for r in reqs]
-        S = -(-max(suffixes) // bucket) * bucket
-        ctx = max(r.prefill_pos + S for r in reqs)
-        # quantize the workspace length to the CHUNK grid, not the bucket
-        # grid: P drives the compiled shape, and a fine grid means a fresh
-        # XLA compile per burst composition (prefill_pos varies with cache
-        # hits) — a compile storm costs far more than the extra padding
-        P = -(-ctx // self.prefill_chunk) * self.prefill_chunk
-        nb = P // bs
-        ids = np.zeros((n, S), np.int32)
-        pos = np.zeros(n, np.int32)
-        tP = np.zeros((n, nb), np.int32)
-        last = np.zeros(n, np.int32)
-        slots = np.full(n, self.max_slots, np.int32)   # OOB -> dropped
-        bt_rows = np.zeros((n, self.max_blocks_per_seq), np.int32)
-        plens = np.zeros(n, np.int32)
-        temps = np.zeros(n, np.float32)
-        for r, req in enumerate(reqs):
-            plen = len(req.prompt)
-            start = req.prefill_pos
-            take = plen - start
-            ids[r, :take] = req.prompt[start:]
-            pos[r] = start
-            table = self.allocator.table(req.request_id)
-            tP[r, :min(nb, len(table))] = table[:nb]
-            last[r] = take - 1
-            slots[r] = req.slot
-            bt_rows[r, :len(table)] = table
-            plens[r] = plen
-            temps[r] = req.temperature
-        if self._dev is None:
-            self._dev_init()
-        d_toks, d_tables, d_lens, d_temps, d_seed = self._dev
-        first_dev, new_layers, n_toks, n_bt, n_sl, n_temps = \
-            self._batched_prefill_jit(S, P)(
-                pv, bv, self.pool.layers, jnp.asarray(ids),
-                jnp.asarray(pos), jnp.asarray(tP), jnp.asarray(last),
-                jnp.asarray(slots), jnp.asarray(bt_rows),
-                jnp.asarray(plens), jnp.asarray(temps),
-                d_toks, d_tables, d_lens, d_temps)
-        self.pool.replace(new_layers)
-        self._dev = (n_toks, n_bt, n_sl, n_temps, d_seed)
-        self._stats.inc("batched_prefills")
-        self._stats.inc("prefill_programs")
-        computed = sum(suffixes)
-        self._stats.inc("prefill_tokens", computed)
-        _PREFILL_TOKENS.inc(computed)
+        with self.obs.span("serving.prefill_chunk", reqs,
+                           tokens=sum(suffixes), batched=True):
+            _, _, pv, bv = self._functional()
+            n = self.max_slots
+            bs = self.block_size
+            bucket = max(self.prefill_bucket, 1)
+            S = -(-max(suffixes) // bucket) * bucket
+            ctx = max(r.prefill_pos + S for r in reqs)
+            # quantize the workspace length to the CHUNK grid, not the bucket
+            # grid: P drives the compiled shape, and a fine grid means a fresh
+            # XLA compile per burst composition (prefill_pos varies with cache
+            # hits) — a compile storm costs far more than the extra padding
+            P = -(-ctx // self.prefill_chunk) * self.prefill_chunk
+            nb = P // bs
+            ids = np.zeros((n, S), np.int32)
+            pos = np.zeros(n, np.int32)
+            tP = np.zeros((n, nb), np.int32)
+            last = np.zeros(n, np.int32)
+            slots = np.full(n, self.max_slots, np.int32)   # OOB -> dropped
+            bt_rows = np.zeros((n, self.max_blocks_per_seq), np.int32)
+            plens = np.zeros(n, np.int32)
+            temps = np.zeros(n, np.float32)
+            for r, req in enumerate(reqs):
+                plen = len(req.prompt)
+                start = req.prefill_pos
+                take = plen - start
+                ids[r, :take] = req.prompt[start:]
+                pos[r] = start
+                table = self.allocator.table(req.request_id)
+                tP[r, :min(nb, len(table))] = table[:nb]
+                last[r] = take - 1
+                slots[r] = req.slot
+                bt_rows[r, :len(table)] = table
+                plens[r] = plen
+                temps[r] = req.temperature
+            if self._dev is None:
+                self._dev_init()
+            d_toks, d_tables, d_lens, d_temps, d_seed = self._dev
+            first_dev, new_layers, n_toks, n_bt, n_sl, n_temps = \
+                self._batched_prefill_jit(S, P)(
+                    pv, bv, self.pool.layers, jnp.asarray(ids),
+                    jnp.asarray(pos), jnp.asarray(tP), jnp.asarray(last),
+                    jnp.asarray(slots), jnp.asarray(bt_rows),
+                    jnp.asarray(plens), jnp.asarray(temps),
+                    d_toks, d_tables, d_lens, d_temps)
+            self.pool.replace(new_layers)
+            self._dev = (n_toks, n_bt, n_sl, n_temps, d_seed)
+            self._stats.inc("batched_prefills")
+            self._stats.inc("prefill_programs")
+            computed = sum(suffixes)
+            self._stats.inc("prefill_tokens", computed)
+            _PREFILL_TOKENS.inc(computed)
         self._pending.append(
             (first_dev, [(r, req.slot, req) for r, req in enumerate(reqs)]))
         flush = False
@@ -947,13 +1018,14 @@ class ServingEngine:
                     self._tables[slot] = 0
                     self._tables[slot, :len(table)] = table
                     d_toks, d_tables, d_lens, d_temps, d_seed = self._dev
-                    self._dev = (
-                        d_toks,
-                        d_tables.at[slot].set(
-                            jnp.asarray(self._tables[slot])),
-                        d_lens, d_temps, d_seed)
+                    with self.obs.span("serving.host_upload",
+                                       what="dedup_table"):
+                        self._dev = (
+                            d_toks,
+                            d_tables.at[slot].set(
+                                jnp.asarray(self._tables[slot])),
+                            d_lens, d_temps, d_seed)
                     self._stats.inc("dedup_admissions")
-            self.obs.on_prefill_chunk(req, t0, suffixes[r], batched=True)
             if req.prefill_only:
                 # the row rode the shared dispatch for its KV only; finish
                 # instead of joining decode (the deferred first-token fetch
@@ -968,40 +1040,40 @@ class ServingEngine:
             self._flush_pending()
 
     def _prefill_one_chunk(self, req: Request) -> None:
-        t0 = self.obs.now()
-        _, _, pv, bv = self._functional()
-        n_layers, n_kv, head_dim = self._geometry
         plen = len(req.prompt)
         chunk = self.prefill_chunk
-        # chunk writes start at prefix_matched (a block multiple, not
-        # necessarily a chunk multiple): the workspace must cover the LAST
-        # chunk window, or dynamic_update_slice would clamp it backwards
-        padded = (req.prefix_matched
-                  + -(-(plen - req.prefix_matched) // chunk) * chunk)
-        if req._ws_caches is None:
-            if req.prefix_matched:
-                # partial prefix hit: seed the workspace with the cached
-                # blocks so the suffix chunks run on top of real context
-                mb = req.prefix_matched // self.block_size
-                head = np.asarray(
-                    self.allocator.table(req.request_id)[:mb], np.int32)
-                req._ws_caches = self._gather_jit(padded, mb)(
-                    self.pool.layers, head)
-            else:
-                req._ws_caches = init_kv_cache(1, padded, n_layers, n_kv,
-                                               head_dim, self._dtype)
         start = req.prefill_pos
-        ids = np.zeros((1, chunk), np.int32)
         take = min(chunk, plen - start)
-        ids[0, :take] = req.prompt[start:start + take]
-        logits, req._ws_caches = self._prefill_jit(chunk, padded)(
-            pv, bv, jnp.asarray(ids), req._ws_caches,
-            jnp.asarray(start, jnp.int32))
-        req.prefill_pos = start + take
-        self._stats.inc("prefill_programs")
-        self._stats.inc("prefill_tokens", take)
-        _PREFILL_TOKENS.inc(take)
-        self.obs.on_prefill_chunk(req, t0, take)
+        with self.obs.request_span("serving.prefill_chunk", req,
+                                   tokens=take, batched=False):
+            _, _, pv, bv = self._functional()
+            n_layers, n_kv, head_dim = self._geometry
+            # chunk writes start at prefix_matched (a block multiple, not
+            # necessarily a chunk multiple): the workspace must cover the LAST
+            # chunk window, or dynamic_update_slice would clamp it backwards
+            padded = (req.prefix_matched
+                      + -(-(plen - req.prefix_matched) // chunk) * chunk)
+            if req._ws_caches is None:
+                if req.prefix_matched:
+                    # partial prefix hit: seed the workspace with the cached
+                    # blocks so the suffix chunks run on top of real context
+                    mb = req.prefix_matched // self.block_size
+                    head = np.asarray(
+                        self.allocator.table(req.request_id)[:mb], np.int32)
+                    req._ws_caches = self._gather_jit(padded, mb)(
+                        self.pool.layers, head)
+                else:
+                    req._ws_caches = init_kv_cache(1, padded, n_layers, n_kv,
+                                                   head_dim, self._dtype)
+            ids = np.zeros((1, chunk), np.int32)
+            ids[0, :take] = req.prompt[start:start + take]
+            logits, req._ws_caches = self._prefill_jit(chunk, padded)(
+                pv, bv, jnp.asarray(ids), req._ws_caches,
+                jnp.asarray(start, jnp.int32))
+            req.prefill_pos = start + take
+            self._stats.inc("prefill_programs")
+            self._stats.inc("prefill_tokens", take)
+            _PREFILL_TOKENS.inc(take)
         if req.prefill_pos < plen:
             return
         # prompt fully prefilled: sample the first token from the last REAL
@@ -1054,20 +1126,25 @@ class ServingEngine:
             self._pending.append((first_dev, [(0, slot, req)]))
             req._pending_n += 1
         else:
-            first = self._sample_host(
-                np.asarray(jax.device_get(logits[0, plen - 1 - start])), req)
+            with self.obs.span("serving.fetch", what="first_token_logits",
+                               ticks=0, tokens=1):
+                last = np.asarray(
+                    jax.device_get(logits[0, plen - 1 - start]))
+            first = self._sample_host(last, req)
             self._toks[slot] = first
             if self._dev is not None:
                 # join the live decode batch by scattering this slot's
                 # state into the device copies (host-known scalars — no
                 # sync, the other slots' in-flight tokens are untouched)
                 d_toks, d_tables, d_lens, d_temps, d_seed = self._dev
-                self._dev = (d_toks.at[slot].set(first),
-                             d_tables.at[slot].set(
-                                 jnp.asarray(self._tables[slot])),
-                             d_lens.at[slot].set(plen),
-                             d_temps.at[slot].set(req.temperature),
-                             d_seed)
+                with self.obs.span("serving.host_upload",
+                                   what="slot_state"):
+                    self._dev = (d_toks.at[slot].set(first),
+                                 d_tables.at[slot].set(
+                                     jnp.asarray(self._tables[slot])),
+                                 d_lens.at[slot].set(plen),
+                                 d_temps.at[slot].set(req.temperature),
+                                 d_seed)
             req.output_tokens.append(first)
             req._progress.set()
         self.sched.start_running(req)
@@ -1094,25 +1171,18 @@ class ServingEngine:
 
     # ------------------------------------------------------------ decode
     def _dev_init(self):
-        self._dev = (jnp.asarray(self._toks), jnp.asarray(self._tables),
-                     jnp.asarray(self._lens), jnp.asarray(self._temps),
-                     jnp.asarray(self._step_seed, jnp.int32))
+        with self.obs.span("serving.host_upload", what="decode_state"):
+            self._dev = (jnp.asarray(self._toks),
+                         jnp.asarray(self._tables),
+                         jnp.asarray(self._lens), jnp.asarray(self._temps),
+                         jnp.asarray(self._step_seed, jnp.int32))
 
     def _decode_step(self) -> int:
         if self.spec_k > 0:
             decoded = self._spec_step()
             if decoded is not None:
                 return decoded
-        t0 = self.obs.now()
-        _, _, pv, bv = self._functional()
         running = list(self.sched.running.items())
-        if self._dev is None:
-            self._dev_init()
-        d_toks, d_tables, d_lens, d_temps, d_seed = self._dev
-        # block tables are the full worst-case reservation, uploaded once
-        # at admission — a steady-state decode tick touches NO host state
-        # but the pending counters: no allocator call, no table scatter,
-        # just one compiled-program dispatch
         needs_sampling = any(req.temperature > 0.0 for _, req in running)
         # fuse 4 decode steps into one dispatch for all-greedy batches. A
         # slot whose budget runs out mid-chunk just overshoots: the extra
@@ -1123,29 +1193,38 @@ class ServingEngine:
         # dispatch, so fusing costs admission at most 3 steps of latency
         # per queued prompt.
         k = 1 if needs_sampling else self.fuse_steps
-        if k == 1:
-            nxt, new_layers, new_lens, new_seed = self._decode_jit(
-                needs_sampling)(
-                pv, bv, d_toks, self.pool.layers, d_tables, d_lens, d_temps,
-                d_seed)
-            toks = nxt
-            items = [(slot, slot, req) for slot, req in running]
-        else:
-            nxt, new_layers, new_lens, new_seed, toks = \
-                self._decode_multi_jit(k)(
+        with self.obs.span("serving.decode", self.sched.running.values(),
+                           batch=len(running), steps=k):
+            _, _, pv, bv = self._functional()
+            if self._dev is None:
+                self._dev_init()
+            d_toks, d_tables, d_lens, d_temps, d_seed = self._dev
+            # block tables are the full worst-case reservation, uploaded
+            # once at admission — a steady-state decode tick touches NO
+            # host state but the pending counters: no allocator call, no
+            # table scatter, just one compiled-program dispatch
+            if k == 1:
+                nxt, new_layers, new_lens, new_seed = self._decode_jit(
+                    needs_sampling)(
                     pv, bv, d_toks, self.pool.layers, d_tables, d_lens,
                     d_temps, d_seed)
-            items = [(i * self.max_slots + slot, slot, req)
-                     for i in range(k) for slot, req in running]
-        self.pool.replace(new_layers)
-        self._dev = (nxt, d_tables, new_lens, d_temps, new_seed)
-        self._step_seed += k
-        # defer the token fetch: host bookkeeping below only needs COUNTS.
-        # Flush (one batched transfer) when a token value can matter — a
-        # request with an eos_token_id (checked every token), or one whose
-        # count reached its length cap this tick.
-        self._pending.append((toks, items))
-        self.obs.on_decode(t0, running, k)
+                toks = nxt
+                items = [(slot, slot, req) for slot, req in running]
+            else:
+                nxt, new_layers, new_lens, new_seed, toks = \
+                    self._decode_multi_jit(k)(
+                        pv, bv, d_toks, self.pool.layers, d_tables, d_lens,
+                        d_temps, d_seed)
+                items = [(i * self.max_slots + slot, slot, req)
+                         for i in range(k) for slot, req in running]
+            self.pool.replace(new_layers)
+            self._dev = (nxt, d_tables, new_lens, d_temps, new_seed)
+            self._step_seed += k
+            # defer the token fetch: host bookkeeping below only needs
+            # COUNTS. Flush (one batched transfer) when a token value can
+            # matter — a request with an eos_token_id (checked every
+            # token), or one whose count reached its length cap this tick.
+            self._pending.append((toks, items))
         flush = False
         for slot, req in running:
             req._pending_n += k
@@ -1227,18 +1306,22 @@ class ServingEngine:
             win[slot, 1:1 + len(d)] = d
             dls[slot] = len(d)
         needs_sampling = any(req.temperature > 0.0 for _, req in running)
-        t0 = self.obs.now()
-        greedy, acc, nxt, new_layers, new_sl, new_seed = self._spec_jit(
-            W, needs_sampling)(
-            pv, bv, jnp.asarray(win), self.pool.layers, d_tables, d_lens,
-            jnp.asarray(dls), d_temps, d_seed)
-        self.pool.replace(new_layers)
-        self._dev = (nxt, d_tables, new_sl, d_temps, new_seed)
-        self._step_seed += 1
-        self._stats.inc("spec_ticks")
-        self.obs.on_decode(t0, running, 1, kind="spec_verify",
-                           window=W)
-        greedy_h, acc_h, nxt_h = jax.device_get((greedy, acc, nxt))
+        with self.obs.span("serving.spec_verify",
+                           self.sched.running.values(),
+                           batch=len(running), steps=1, window=W):
+            greedy, acc, nxt, new_layers, new_sl, new_seed = self._spec_jit(
+                W, needs_sampling)(
+                pv, bv, jnp.asarray(win), self.pool.layers, d_tables,
+                d_lens, jnp.asarray(dls), d_temps, d_seed)
+            self.pool.replace(new_layers)
+            self._dev = (nxt, d_tables, new_sl, d_temps, new_seed)
+            self._step_seed += 1
+            self._stats.inc("spec_ticks")
+        # speculation needs this tick's values before the next draft
+        with self.obs.span("serving.fetch", what="spec_verify",
+                           ticks=1) as fetch:
+            greedy_h, acc_h, nxt_h = jax.device_get((greedy, acc, nxt))
+            fetch.set(tokens=int(acc_h.sum()) + len(running))
         decoded = 0
         touched = []
         for slot, req in running:
@@ -1303,7 +1386,11 @@ class ServingEngine:
         if not self._pending:
             return
         pending, self._pending = self._pending, []
-        vals = jax.device_get([arr for arr, _ in pending])
+        # the one place a steady tick waits for the device
+        with self.obs.span("serving.fetch", what="tokens",
+                           ticks=len(pending),
+                           tokens=sum(len(it) for _, it in pending)):
+            vals = jax.device_get([arr for arr, _ in pending])
         touched = {}
         for arr, (_, items) in zip(vals, pending):
             a = np.asarray(arr)

@@ -10,9 +10,13 @@ per-request TTFT/TPOT/goodput, not aggregate averages):
   * ``RequestTrace`` — per-request lifecycle spans (queue-wait, admission,
     each prefill chunk, decode ticks, speculative verify, rollback,
     finish/cancel) recorded through the process-wide ``observability.spans``
-    ring, so a profiler fallback session (``profiler.Profiler``) collects
-    them into its chrome-trace export automatically; ``export_request_trace``
-    writes one request's own spans as a standalone chrome trace.
+    ring, so a ``profiler.Profiler`` session collects them into its
+    chrome-trace export and a jax profiler session gets them as
+    annotations on its host plane; ``export_request_trace`` writes one
+    request's own spans as a standalone chrome trace. The engine opens its
+    spans with ``with obs.span(name, reqs, **args):`` around the work;
+    only the two whose ends are request timestamps (``serving.queue``,
+    ``serving.admit``) are appended after the fact, and so are ring-only.
   * SLO metrics on the shared registry, labeled by admission ``tier``
     (one tier today — "default" — the label is the seam the router's
     priority classes plug into): TTFT, TPOT (mean inter-token latency),
@@ -20,8 +24,8 @@ per-request TTFT/TPOT/goodput, not aggregate averages):
     counters. All ``always=True`` like the rest of the serving_* family —
     serving runs don't require FLAGS_metrics.
   * Engine gauges sampled every TICK_SAMPLE engine ticks
-    (FLAGS_metrics-gated — the metrics-off tick path stays a
-    two-attribute no-op): slot occupancy,
+    (FLAGS_metrics-gated — with metrics off the tick path is a flag
+    check): slot occupancy,
     batch size, rolling prefix-cache hit rate, speculative acceptance.
     Block-pool live/evictable/free gauges are published by the allocator
     itself (blocks.py, always on).
@@ -42,7 +46,6 @@ from __future__ import annotations
 import itertools
 import json
 import os
-import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
@@ -115,6 +118,18 @@ _SHED = _counter("serving_shed_requests_total",
                  "Requests evicted before normal completion, by reason "
                  "(timeout, disconnect, cancelled, shed).",
                  labelnames=("tier", "reason"), always=True)
+
+SUBMIT_LOCK_WAIT_H = _histogram(
+    "serving_submit_lock_wait_seconds",
+    "submit(): entry -> engine lock held. The lock is held all through a "
+    "tick, so under load this is what a request waits before it is even "
+    "queued.", always=True)
+PROGRAMS_BUILT = _counter(
+    "serving_programs_built_total",
+    "Engine programs built for a shape not seen before, by kind (prefill, "
+    "scatter, gather, batched_prefill, admit, decode, ...): each is a "
+    "compile that stalls every running request.",
+    labelnames=("kind",), always=True)
 
 # per-tick engine gauges: FLAGS_metrics-gated (stats() is the always-on
 # view of the same numbers)
@@ -205,10 +220,11 @@ class RequestTrace:
     """Per-request span list, mirrored into the global spans ring.
 
     Attached to a Request at submit when span recording is enabled
-    (FLAGS_metrics=on or an open profiler fallback session). Request-scoped
-    spans go through ``add`` (ring + local list); batch-scoped spans the
-    engine records once for everyone land in each participant's list via
-    ``note`` without re-recording. Bounded so one long-running request
+    (FLAGS_metrics=on, an open ``Profiler`` session or a live jax profiler
+    trace). Spans the engine opens around its work (``ServingObservability
+    .span``) are recorded once and land in each participant's list by
+    reference; ``add`` is for the spans made after the fact from request
+    timestamps (ring + local list). Bounded so one long-running request
     cannot grow without bound."""
 
     MAX_SPANS = 1024
@@ -222,7 +238,7 @@ class RequestTrace:
         # fleet trace context (fleet_request_id / attempt / cause) stamped
         # by the router at dispatch: baked into every request-scoped
         # span's args so a cross-replica merge needs no re-tagging.
-        # Batch-scoped spans (shared dict, see on_decode) are tagged at
+        # Batch-scoped spans (shared dict, see _EngineSpan) are tagged at
         # export time on copies instead.
         self.ctx = dict(ctx) if ctx else None
         # decode slot, captured at admission (the scheduler clears
@@ -230,28 +246,16 @@ class RequestTrace:
         self.slot: Optional[int] = None
         self.spans: deque = deque(maxlen=self.MAX_SPANS)
 
-    def _span(self, name: str, begin_ns: int, end_ns: int,
-              **args) -> Dict[str, Any]:
-        base = {"request_id": self.request_id}
-        if self.ctx:
-            base.update(self.ctx)
-        base.update(args)
-        return {"name": str(name), "begin_ns": int(begin_ns),
-                "end_ns": int(end_ns), "cat": "serving",
-                "tid": threading.get_ident() & 0xFFFF,
-                "args": base}
+    def scoped(self, args: Dict[str, Any]) -> Dict[str, Any]:
+        """`args` of a request-scoped span: the request's id and fleet
+        trace context first."""
+        return {"request_id": self.request_id, **(self.ctx or {}), **args}
 
     def add(self, name: str, begin_ns: int, end_ns: int, **args) -> None:
-        """Record a request-scoped span (local list + global ring)."""
-        d = self._span(name, begin_ns, end_ns, **args)
-        self.spans.append(d)
-        _spans.record_span(name, begin_ns, end_ns, cat="serving",
-                           args=d["args"])
-
-    def note(self, name: str, begin_ns: int, end_ns: int, **args) -> None:
-        """Attach a batch-scoped span (already in the ring) to this
-        request's list only."""
-        self.spans.append(self._span(name, begin_ns, end_ns, **args))
+        """Record a request-scoped span whose ends are request timestamps
+        (local list + global ring; ring-only, see spans.record_span)."""
+        self.spans.append(_spans.record_span(
+            name, begin_ns, end_ns, cat="serving", args=self.scoped(args)))
 
     def names(self) -> List[str]:
         return [s["name"] for s in self.spans]
@@ -266,7 +270,7 @@ def chrome_trace_events(span_dicts, *, pid: Optional[int] = None,
 
     Every event gets its OWN args dict (deep-copied from the span): the
     engine appends one shared per-tick span dict by reference to every
-    traced participant (on_decode), so tagging export-time fields on the
+    traced participant (_EngineSpan), so tagging export-time fields on the
     original would corrupt every other request's trace. `pid`/`tid`
     override the lane (the fleet merge maps pid=replica, tid=slot);
     `extra_args` fills attribution keys (attempt/cause) without
@@ -306,12 +310,34 @@ def export_request_trace(req, path: str) -> str:
     return path
 
 
-class ServingObservability:
-    """Per-engine observability hub: the engine calls the ``on_*`` hooks
-    under its own lock; HTTP handlers read through ``health_snapshot``.
+class _EngineSpan(_spans.Span):
+    """A span the engine opens around work done for `reqs`: recorded once,
+    and its ring dict handed by reference to every traced participant —
+    this runs every engine tick for every running request, so per-request
+    dict construction is exactly the overhead the <=3% budget forbids."""
 
-    Cheap when FLAGS_metrics is off: ``tick_begin``/``on_tick`` reduce to
-    a flag check + one attribute write, traces are never attached, and the
+    __slots__ = ("_reqs",)
+
+    def __init__(self, name: str, reqs, args):
+        super().__init__(name, "serving", args)
+        self._reqs = reqs
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        for req in self._reqs:
+            if req.trace is not None:
+                req.trace.spans.append(self.record)
+        return False
+
+
+class ServingObservability:
+    """Per-engine observability hub: the engine opens ``span``s around its
+    work and calls the ``on_*`` hooks under its own lock; HTTP handlers
+    read through ``health_snapshot``.
+
+    Cheap when nothing records: ``span`` hands out the shared no-op after
+    three reads (spans.enabled), ``tick_begin``/``on_tick`` reduce to a
+    flag check + one attribute write, traces are never attached, and the
     SLO histogram observes (always-on by contract) were already paid by
     the pre-r16 engine."""
 
@@ -343,17 +369,27 @@ class ServingObservability:
         #                           produce a snapshot + anomaly record)
         self._ttft_acc: List[float] = []
         self._on = False          # metrics enabled, refreshed per tick
-        self._trace_on = False    # span recording enabled, per tick
         self._anomaly: Optional[_anomaly.AnomalyEngine] = None
         self._dump_armed_at = -1
         self.last_tick_ts: Optional[float] = None
         self.dumps: List[str] = []
 
-    def now(self) -> Optional[int]:
-        """Span start timestamp, or None when nothing records this tick
-        (the engine brackets its dispatches with now()/on_* pairs; a None
-        t0 makes the matching hook a no-op)."""
-        return time.monotonic_ns() if self._trace_on else None
+    def span(self, name: str, reqs=(), **args):
+        """``with obs.span("serving.decode", reqs, batch=n):`` around the
+        work. `reqs` are the requests it is done for: the finished span
+        lands in each one's trace, by reference."""
+        if not _spans.enabled():
+            return _spans.NOOP
+        return _EngineSpan(name, tuple(reqs), args)
+
+    def request_span(self, name: str, req, **args):
+        """A span of work done for ONE request: its id (and fleet trace
+        context) lead the args."""
+        if not _spans.enabled():
+            return _spans.NOOP
+        tr = req.trace
+        return _EngineSpan(name, (req,), tr.scoped(args) if tr is not None
+                           else {"request_id": req.request_id, **args})
 
     # -- request lifecycle hooks (engine lock held) ------------------------
     def on_submit(self, req) -> None:
@@ -388,15 +424,6 @@ class ServingObservability:
                        prompt_tokens=len(req.prompt),
                        prefix_matched=req.prefix_matched)
 
-    def on_prefill_chunk(self, req, t0_ns: Optional[int],
-                         tokens: int, batched: bool = False) -> None:
-        if t0_ns is None:
-            return
-        tr = req.trace
-        if tr is not None:
-            tr.add("serving.prefill_chunk", t0_ns, time.monotonic_ns(),
-                   tokens=int(tokens), batched=bool(batched))
-
     def on_first_token(self, req) -> None:
         """Prefill -> running (all three admission-completion sites): SLO
         queue/TTFT observes + the admission span."""
@@ -415,35 +442,11 @@ class ServingObservability:
                    int(req.first_token_time * 1e9),
                    cached=req._cow_src is not None)
 
-    def on_decode(self, t0_ns: Optional[int], running, k: int = 1,
-                  kind: str = "decode", **args) -> None:
-        """One decode / speculative-verify dispatch over the batch: one
-        ring span, attached to every traced participant. The participants
-        share ONE span dict by reference — this runs every engine tick for
-        every running request, so per-request dict construction is exactly
-        the overhead the <=3% budget forbids."""
-        if t0_ns is None:
-            return
-        t1 = time.monotonic_ns()
-        name = f"serving.{kind}"
-        span_args = {"batch": len(running), "steps": int(k), **args}
-        _spans.record_span(name, t0_ns, t1, cat="serving", args=span_args)
-        shared = None
-        for _, req in running:
-            tr = req.trace
-            if tr is not None:
-                if shared is None:
-                    shared = {"name": name, "begin_ns": int(t0_ns),
-                              "end_ns": int(t1), "cat": "serving",
-                              "tid": threading.get_ident() & 0xFFFF,
-                              "args": span_args}
-                tr.spans.append(shared)
-
     def on_rollback(self, req, rejected: int) -> None:
-        tr = req.trace
-        if tr is not None:
-            now = time.monotonic_ns()
-            tr.add("serving.rollback", now, now, rejected=int(rejected))
+        if req.trace is not None:
+            with self.request_span("serving.rollback", req,
+                                   rejected=int(rejected)):
+                pass
 
     def on_finish(self, req, reason: str) -> None:
         """Any terminal transition (stop/length/cancel/timeout/disconnect):
@@ -467,36 +470,27 @@ class ServingObservability:
             _SHED.inc(tier=tier, reason=str(reason))
         tr = req.trace
         if tr is not None:
-            now = time.monotonic_ns()
-            tr.add("serving.finish", now, now, reason=str(reason),
-                   output_tokens=n)
+            with self.request_span("serving.finish", req,
+                                   reason=str(reason), output_tokens=n):
+                pass
         if self._on or tr is not None:
             self._records.append(self._request_record(req))
 
     # -- per-tick sampling -------------------------------------------------
-    def tick_begin(self) -> Optional[int]:
-        """Start-of-tick: refresh the cached enable flags; returns the
-        tick's start timestamp when anything records, else None."""
+    def tick_begin(self) -> None:
+        """Start-of-tick: refresh the cached metrics flag (the engine then
+        opens the ``serving.tick`` span itself)."""
         self._on = metrics_enabled()
-        self._trace_on = _spans.enabled()
-        if self._on or self._trace_on:
-            return time.monotonic_ns()
-        return None
 
-    def on_tick(self, t0_ns: Optional[int], out: Dict[str, Any]) -> None:
-        """End-of-tick: tick span, then — every TICK_SAMPLE-th step —
-        engine gauges, the tick snapshot record, and anomaly detection
-        (+ flight dump). Between samples the hot path is one liveness
-        timestamp and a decoded-token accumulate. Called under the engine
-        lock."""
+    def on_tick(self, out: Dict[str, Any]) -> None:
+        """End-of-tick, after the tick span has closed: every
+        TICK_SAMPLE-th step engine gauges, the tick snapshot record, and
+        anomaly detection (+ flight dump). Between samples the hot path is
+        one liveness timestamp and a decoded-token accumulate. Called
+        under the engine lock."""
         eng = self.engine
         now = time.monotonic()
         self.last_tick_ts = now
-        if t0_ns is not None and self._trace_on:
-            _spans.record_span(
-                "serving.tick", t0_ns, time.monotonic_ns(), cat="serving",
-                args={"step": eng.steps, "decoded": out["decoded_tokens"],
-                      "running": out["running"]})
         if not self._on:
             return
         self._decoded_acc += int(out["decoded_tokens"])

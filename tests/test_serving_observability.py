@@ -97,7 +97,7 @@ class TestRequestTraces:
 
     def test_export_tagging_never_leaks_into_shared_tick_spans(
             self, metrics_on):
-        """on_decode appends ONE shared per-tick span dict by reference
+        """The engine appends ONE shared per-tick span dict by reference
         to every traced participant (perf): export-time tagging must
         copy, or exporting request A's trace with attribution args would
         corrupt request B's."""
@@ -489,10 +489,12 @@ class TestMetricsOffNoop:
     def test_tick_begin_is_cheap_noop(self):
         eng = _engine()
         obs = eng.obs
-        t0 = obs.tick_begin()
-        assert t0 is None and obs.now() is None
-        obs.on_tick(t0, {"admitted": 0, "decoded_tokens": 0, "running": 0,
-                         "waiting": 0, "prefilling": 0, "free_slots": 2,
-                         "reserved_blocks": 0})
+        obs.tick_begin()
+        # with nothing recording every span is the one shared no-op
+        assert obs.span("serving.tick") is spans.NOOP
+        assert obs.span("serving.decode", [], batch=0) is spans.NOOP
+        obs.on_tick({"admitted": 0, "decoded_tokens": 0, "running": 0,
+                     "waiting": 0, "prefilling": 0, "free_slots": 2,
+                     "reserved_blocks": 0})
         assert obs.last_tick_ts is not None  # liveness still tracked
         assert list(obs._ticks) == []

@@ -124,3 +124,88 @@ def test_fused_rope(one_chip):
     x = ((8, 1024, 16, 128), jnp.bfloat16)
     tab = ((1024, 128), jnp.float32)
     _compile(fused_rope, one_chip, x, x, tab, tab)
+
+
+# ---- the names a device trace shows: XLA modules after the jitted function,
+# ---- kernel events after pl.pallas_call(name=) (the benchmark's readers
+# ---- find them by these, so a rename is a change to the yardstick)
+def _tiny_train_step():
+    import paddle_tpu as paddle
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+
+    cfg = GPTConfig(vocab_size=1024, hidden_size=128, num_layers=2,
+                    num_heads=2, max_position_embeddings=256,
+                    hidden_dropout_prob=0.0, attention_dropout_prob=0.0)
+    model = GPTForCausalLM(cfg)
+    opt = paddle.optimizer.AdamW(1e-3, parameters=model.parameters())
+
+    def loss_fn(b):
+        with paddle.amp.auto_cast(level="O1", dtype="bfloat16"):
+            return model(b, labels=b)
+
+    return TrainStep(model, loss_fn, opt)
+
+
+def test_train_step_is_jit_train_step_with_named_flash_kernels(
+        one_chip, monkeypatch):
+    """Through TrainStep, as the chip runs it: under the program's own
+    autograd the kernels come out as %flash_fwd.N, %flash_bwd_dq.N and
+    %flash_bwd_dkv.N (a plain jax.grad would call them %jvp_flash_fwd_.N)."""
+    step = _tiny_train_step()
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        ([p._value for p in step.params], [b._value for b in step.buffers],
+         step.opt_state, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32),
+         [jnp.zeros((8, 256), jnp.int32)]))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = step._jitted.lower(*args).compile().as_text()
+    assert text.startswith("HloModule jit_train_step,")
+    for kernel in ("%flash_fwd.", "%flash_bwd_dq.", "%flash_bwd_dkv."):
+        assert kernel in text, kernel
+    kernels = [ln.split()[0] for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert kernels and all(k.startswith("%flash_") for k in kernels), kernels
+    for scope in ("h0/attn", "h1/mlp", "loss", "optimizer", "lm_head"):
+        assert f'op_name="jit(train_step)/{scope}/' in text, scope
+
+
+def test_decode_program_is_jit_step_with_a_named_kernel(one_chip,
+                                                        monkeypatch):
+    """The engine's decode program at head size 128, compiled as on the
+    chip (the op asks jax.default_backend() which attention to take, and
+    is steered here, not by an option of the program)."""
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.serving import ServingEngine
+
+    cfg = GPTConfig(vocab_size=512, hidden_size=256, num_layers=2,
+                    num_heads=2, max_position_embeddings=256,
+                    hidden_dropout_prob=0.0, attention_dropout_prob=0.0)
+    engine = ServingEngine(GPTForCausalLM(cfg).bfloat16(), max_slots=_SLOTS,
+                           block_size=_BLOCK, prefill_chunk=32)
+    _, _, pv, bv = engine._functional()
+    engine._dev_init()
+    toks, tables, lens, temps, seed = engine._dev
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (pv, bv, toks, engine.pool.layers, tables, lens, temps, seed))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = engine._decode_jit(False).lower(*args).compile().as_text()
+    assert text.startswith("HloModule jit_step,")
+    assert "%paged_decode." in text and "tpu_custom_call" in text
+    assert 'op_name="jit(step)/h1/attn/paged_decode' in text
+    for scope in ("embed", "h0/attn/kv_append", "h1/mlp", "final_norm",
+                  "lm_head", "sample"):
+        assert f'op_name="jit(step)/{scope}/' in text, scope
+
+
+def test_every_way_to_build_the_train_step_names_it_train_step(one_chip):
+    import numpy as np
+
+    import paddle_tpu as paddle
+
+    step = _tiny_train_step()
+    ids = paddle.to_tensor(np.zeros((2, 16), "int32"))
+    assert "module @jit_train_step " in step.lower(ids).as_text()
+    step.invalidate_executables()       # the re-traced wrapper too
+    assert "module @jit_train_step " in step.lower(ids).as_text()

@@ -916,17 +916,29 @@ def paged_cached_attention(q, k, v, k_pages, v_pages, block_table, seq_lens,
     q: [slots, sq, q_heads, d]; k, v: [slots, sq, kv_heads, d];
     k_pages, v_pages: [num_blocks, kv_heads, block_size, d];
     block_table: [slots, max_blocks] int32; seq_lens: [slots] int32.
-    Returns (out [slots, sq, q_heads, d], k_pages, v_pages). Idle slots
-    (block tables full of the null page 0) write and read garbage there
-    harmlessly — the engine masks their sampled tokens.
+    Returns (out [slots, sq, q_heads, d], k_pages, v_pages).
 
-    sq > 1 is the speculative-verification window: the sq tokens are
-    written at positions seq_lens..seq_lens+sq-1 and each query attends
+    The append: slot s's token i (position p = seq_lens[s] + i) lands, cast
+    to the pool's dtype, at pages[block_table[s, p // block_size], :,
+    p % block_size, :]; every other element of the pool comes back
+    bit-identical. A position past the slot's block table goes to the null
+    page 0 instead (never clamped onto the table's last real block), and an
+    idle slot's table is all null pages, so duplicate targets occur only on
+    page 0, whose content is never read — the engine masks idle slots'
+    tokens and rolls rejected window tokens back by length.
+
+    Layout contract (stated here, enforced by tests/test_tpu_compile.py):
+    the pool is row-major [num_blocks, kv_heads, block_size, d], the layout
+    the Pallas kernels read and every engine program takes and returns.
+    Every writer indexes LEADING dimensions only — here all three, so the
+    scatter's window is the [d] row — and the pool is updated where it
+    lies. An index with a slice between two index arrays
+    (`pages.at[page, :, off]`) makes the TPU compiler relayout the whole
+    pool into the scatter's preferred layout and back: four pool-sized
+    copies a layer a step.
+
+    sq > 1 is the speculative-verification window: each query attends
     causally within the window (query i sees pos < seq_lens + i + 1).
-    Window positions that would fall past a slot's block table land in the
-    null page 0 instead of clamping onto the table's last real block —
-    the engine rolls rejected tokens back by length, so those writes are
-    never read.
     """
     slots, sq, hq, d = q.shape
     bs = k_pages.shape[2]
@@ -941,17 +953,20 @@ def paged_cached_attention(q, k, v, k_pages, v_pages, block_table, seq_lens,
         supports as _paged_supports,
     )
 
+    with jax.named_scope("kv_append"):
+        bt = block_table.astype(jnp.int32)
+        pos = seq_lens[:, None] + jnp.arange(sq, dtype=jnp.int32)[None, :]
+        page_idx = pos // bs                                 # [slots, sq]
+        gathered = jnp.take_along_axis(
+            bt, jnp.minimum(page_idx, bt.shape[1] - 1), axis=1)
+        # overflow -> null page
+        page = jnp.where(page_idx < bt.shape[1], gathered, 0)[..., None]
+        off = (pos % bs)[..., None]
+        head = jnp.arange(k_pages.shape[1], dtype=jnp.int32)[None, None, :]
+        k_pages = k_pages.at[page, head, off].set(k.astype(k_pages.dtype))
+        v_pages = v_pages.at[page, head, off].set(v.astype(v_pages.dtype))
+
     if sq == 1:
-        # KV append: one token per slot at (block_table[seq//bs], seq%bs)
-        with jax.named_scope("kv_append"):
-            page = jnp.take_along_axis(
-                block_table.astype(jnp.int32),
-                (seq_lens // bs)[:, None], axis=1)[:, 0]
-            off = seq_lens % bs
-            k_pages = k_pages.at[page, :, off].set(
-                k[:, 0].astype(k_pages.dtype))
-            v_pages = v_pages.at[page, :, off].set(
-                v[:, 0].astype(v_pages.dtype))
         ctx = seq_lens + 1  # the token just written attends to itself
 
         q2 = q[:, 0]
@@ -967,18 +982,6 @@ def paged_cached_attention(q, k, v, k_pages, v_pages, block_table, seq_lens,
         return out[:, None], k_pages, v_pages
 
     # ---- multi-token verify window ----
-    with jax.named_scope("kv_append"):
-        bt = block_table.astype(jnp.int32)
-        pos = seq_lens[:, None] + jnp.arange(sq, dtype=jnp.int32)[None, :]
-        page_idx = pos // bs                                 # [slots, sq]
-        in_table = page_idx < bt.shape[1]
-        gathered = jnp.take_along_axis(
-            bt, jnp.minimum(page_idx, bt.shape[1] - 1), axis=1)
-        page = jnp.where(in_table, gathered, 0)    # overflow -> null page
-        off = pos % bs
-        k_pages = k_pages.at[page, :, off].set(k.astype(k_pages.dtype))
-        v_pages = v_pages.at[page, :, off].set(v.astype(v_pages.dtype))
-
     kernel_ok = _paged_supports((slots, hq, d), k_pages.shape)
     if kernel_ok and _pallas.interpret_mode():
         out = _paged_multi(q, k_pages, v_pages, block_table, seq_lens,

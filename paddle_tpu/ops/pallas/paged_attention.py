@@ -18,6 +18,13 @@ computes one decode step of attention STRAIGHT from
 without materializing contiguous per-sequence caches — the "ragged" part:
 every slot attends over its own length, fully-masked pages are skipped.
 
+The pool's layout is a contract with its writers, stated in one place
+(ops/kernels/nn_ops.py: paged_cached_attention's docstring) and held by
+tests/test_tpu_compile.py: pages are [num_blocks, kv_heads, block_size, d]
+row-major; every writer (the decode step's append, the engine's scatter,
+batched prefill and copy-on-write admit) indexes leading dimensions only,
+so no program relayouts the pool around these kernels.
+
 Kernel shape: grid (slots, kv_heads, kv_splits, pages_per_split) with the
 block table + context lens as SCALAR-PREFETCH operands, so each grid step's
 BlockSpec index_map picks the next physical page to DMA (data-dependent

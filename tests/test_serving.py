@@ -285,6 +285,76 @@ class TestPagedAttentionNumerics:
         np.testing.assert_array_equal(np.asarray(kp2), kp_ref)
         np.testing.assert_array_equal(np.asarray(vp2), vp_ref)
 
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    @pytest.mark.parametrize("hkv", [4, 1], ids=["mha", "gqa4"])
+    @pytest.mark.parametrize("sq", [1, 4])
+    def test_append_writes_rows_where_the_pool_lies(self, sq, hkv, dtype):
+        """The append against a NumPy loop that writes row by row: a live
+        slot's token at position p lands at (block_table[p // bs], :,
+        p % bs), every other element of every real page comes back
+        bit-identical, and idle slots and positions past the block table
+        touch only the null page 0."""
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops.kernels.nn_ops import paged_cached_attention
+        from paddle_tpu.ops.pallas.paged_attention import (
+            paged_attention_xla,
+            paged_attention_xla_multi,
+        )
+
+        rng = np.random.default_rng(100 * sq + 10 * hkv
+                                    + (dtype == "float32"))
+        slots, hq, d, bs, bps = 5, 4, 8, 4, 3
+        nb = 1 + slots * bps
+        pool_dt = jnp.dtype(dtype)
+        q = rng.standard_normal((slots, sq, hq, d)).astype(np.float32)
+        k = rng.standard_normal((slots, sq, hkv, d)).astype(np.float32)
+        v = rng.standard_normal((slots, sq, hkv, d)).astype(np.float32)
+        kp = rng.standard_normal((nb, hkv, bs, d)).astype(pool_dt)
+        vp = rng.standard_normal((nb, hkv, bs, d)).astype(pool_dt)
+        bt = np.arange(1, nb, dtype=np.int32).reshape(slots, bps)
+        bt[2] = 0                                    # slot 2 is idle
+        # block start; crossing a block boundary (sq 4); idle; the table's
+        # last position (the window overflows); past the table altogether
+        lens = np.array([0, bs + 2, 0, bps * bs - 1, bps * bs], np.int32)
+
+        out, kp2, vp2 = jax.jit(paged_cached_attention)(
+            q, k, v, jnp.asarray(kp), jnp.asarray(vp), bt, lens)
+        kp2, vp2 = np.asarray(kp2), np.asarray(vp2)
+        assert kp2.dtype == pool_dt and vp2.dtype == pool_dt
+
+        kp_ref, vp_ref = kp.copy(), vp.copy()
+        null_offsets = set()
+        for s in range(slots):
+            for i in range(sq):
+                p = int(lens[s]) + i
+                pg = int(bt[s, p // bs]) if p // bs < bps else 0
+                if pg == 0:
+                    null_offsets.add(p % bs)
+                    continue
+                kp_ref[pg, :, p % bs] = k[s, i].astype(pool_dt)
+                vp_ref[pg, :, p % bs] = v[s, i].astype(pool_dt)
+        bits = f"uint{8 * pool_dt.itemsize}"
+        for got, ref, before in ((kp2, kp_ref, kp), (vp2, vp_ref, vp)):
+            np.testing.assert_array_equal(got[1:].view(bits),
+                                          ref[1:].view(bits))
+            spared = sorted(set(range(bs)) - null_offsets)
+            np.testing.assert_array_equal(got[0][:, spared].view(bits),
+                                          before[0][:, spared].view(bits))
+        assert null_offsets, "no slot exercised the null page"
+
+        if sq == 1:
+            live = [0, 1, 3]                # slots whose window is in table
+            want = np.asarray(paged_attention_xla(
+                q[:, 0], kp_ref, vp_ref, bt, lens + 1))[:, None]
+        else:
+            live = [0, 1]
+            want = np.asarray(paged_attention_xla_multi(
+                q, kp_ref, vp_ref, bt, lens))
+        np.testing.assert_allclose(np.asarray(out)[live], want[live],
+                                   rtol=1e-5, atol=1e-5)
+
     def test_null_block_rows_are_ignored(self):
         # poison the null block: masked idle context must not leak into out
         from paddle_tpu.ops.pallas.paged_attention import paged_attention_xla

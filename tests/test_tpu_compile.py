@@ -9,6 +9,8 @@ Nothing runs, so nothing here says anything about results or speed.
 Only one process may hold libtpu, so the topology is described inside a
 module-scoped fixture: never at import, in a skipif or in parametrize.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -103,6 +105,102 @@ def test_paged_attention_verify_window(one_chip, hq, hkv, dtype):
              *_paged_shapes(hq, hkv, dtype, sq=5))       # spec_k = 4
 
 
+# ---- the KV pool's layout contract (paged_cached_attention's docstring): every
+# ---- writer updates the pool where it lies. A scatter whose indexed dimensions
+# ---- are not the leading ones made the TPU compiler relayout the whole pool
+# ---- there and back, 96 copies of 168 MB a decode step at these shapes
+_KV_HEADS, _NUM_BLOCKS = 16, 2560        # the serve cells' pool, per layer
+_POOL = (_NUM_BLOCKS, _KV_HEADS, _BLOCK, _D)
+_POOL_BYTES = 2 * _NUM_BLOCKS * _KV_HEADS * _BLOCK * _D
+
+
+def _pool_relayouts(text):
+    """The optimized HLO's instructions that copy or transpose a whole pool."""
+    made = re.escape("= bf16[%d,%d,%d,%d]{" % _POOL) + r"[^}]*\} (copy|transpose)\("
+    return [ln.strip()[:200] for ln in text.splitlines() if re.search(made, ln)]
+
+
+@pytest.mark.parametrize("sq", [1, 4], ids=["decode", "verify_window"])
+def test_kv_append_writes_the_pool_in_place(one_chip, monkeypatch, sq):
+    from paddle_tpu.ops.kernels.nn_ops import paged_cached_attention
+
+    qkv = ((_SLOTS, sq, _KV_HEADS, _D), jnp.bfloat16)
+    pool = (_POOL, jnp.bfloat16)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        qkv, qkv, qkv, pool, pool, ((_SLOTS, _MAX_BLOCKS), jnp.int32),
+        ((_SLOTS,), jnp.int32))]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(paged_cached_attention,
+                       donate_argnums=(3, 4)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in it"
+    assert not _pool_relayouts(text)
+    aliases = re.search(r"input_output_alias=\{(.*?) \}, ", text).group(1)
+    assert {3, 4} <= {int(n) for n in re.findall(r"\((\d+), ", aliases)}
+    assert compiled.memory_analysis().temp_size_in_bytes < _POOL_BYTES
+
+
+@pytest.fixture(scope="module")
+def pool_programs(one_chip):
+    """Every engine program that returns the pool, with the shapes to lower
+    it: a 2-layer model whose pages have the serve cells' shape."""
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.serving import ServingEngine
+
+    cfg = GPTConfig(vocab_size=512, hidden_size=_KV_HEADS * _D, num_layers=2,
+                    num_heads=_KV_HEADS, intermediate_size=256,
+                    max_position_embeddings=_BLOCK * _MAX_BLOCKS,
+                    hidden_dropout_prob=0.0, attention_dropout_prob=0.0)
+    # the engine's own pool stays tiny: the programs are lowered from shapes
+    engine = ServingEngine(GPTForCausalLM(cfg).bfloat16(), max_slots=_SLOTS,
+                           block_size=_BLOCK, num_blocks=2, prefill_chunk=256)
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32, f32 = jnp.int32, jnp.float32
+    pv, bv = jax.tree_util.tree_map(lambda x: sd(x.shape, x.dtype),
+                                    engine._functional()[2:])
+    pages = [(sd(_POOL, jnp.bfloat16),) * 2] * cfg.num_layers
+    toks, lens, temps = sd((_SLOTS,), i32), sd((_SLOTS,), i32), sd((_SLOTS,), f32)
+    tables, row = sd((_SLOTS, _MAX_BLOCKS), i32), sd((_MAX_BLOCKS,), i32)
+    one = seed = sd((), i32)
+    W, S, P = 5, 32, 1280                 # spec_k 4; a docqa question on its document
+    work = [(sd((1, P, _KV_HEADS, _D), jnp.bfloat16),) * 2] * cfg.num_layers
+    decode = (pv, bv, toks, pages, tables, lens, temps, seed)
+    return {
+        "step": (engine._decode_jit(False), decode),
+        "serve_decode_fused": (engine._decode_multi_jit(4), decode),
+        "serve_spec_verify": (engine._spec_jit(W, False), (
+            pv, bv, sd((_SLOTS, W), i32), pages, tables, lens, lens, temps,
+            seed)),
+        "serve_scatter": (engine._scatter_jit(P, P // _BLOCK), (
+            pages, work, sd((P // _BLOCK,), i32))),
+        "serve_batched_prefill": (engine._batched_prefill_jit(S, P), (
+            pv, bv, pages, sd((_SLOTS, S), i32), lens,
+            sd((_SLOTS, P // _BLOCK), i32), lens, lens, tables, lens, temps,
+            toks, tables, lens, temps)),
+        "serve_admit_cow": (engine._admit_cow_jit(), (
+            pages, toks, tables, lens, temps, one, one, one, row, one, one,
+            sd((), f32))),
+    }
+
+
+@pytest.mark.parametrize("name", [
+    "step", "serve_decode_fused", "serve_spec_verify", "serve_scatter",
+    "serve_batched_prefill", "serve_admit_cow"])
+def test_no_engine_program_relayouts_the_pool(pool_programs, monkeypatch,
+                                              name):
+    fn, args = pool_programs[name]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule jit_{name},")
+    assert not _pool_relayouts(text)
+    # both pools of both layers are updated in the buffers they came in
+    assert compiled.memory_analysis().alias_size_in_bytes >= 4 * _POOL_BYTES
+
+
 # ---- LLaMA-family fused ops at the widths the issue names ----
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "fp32"])
@@ -170,27 +268,14 @@ def test_train_step_is_jit_train_step_with_named_flash_kernels(
         assert f'op_name="jit(train_step)/{scope}/' in text, scope
 
 
-def test_decode_program_is_jit_step_with_a_named_kernel(one_chip,
+def test_decode_program_is_jit_step_with_a_named_kernel(pool_programs,
                                                         monkeypatch):
     """The engine's decode program at head size 128, compiled as on the
     chip (the op asks jax.default_backend() which attention to take, and
     is steered here, not by an option of the program)."""
-    from paddle_tpu.models import GPTConfig, GPTForCausalLM
-    from paddle_tpu.serving import ServingEngine
-
-    cfg = GPTConfig(vocab_size=512, hidden_size=256, num_layers=2,
-                    num_heads=2, max_position_embeddings=256,
-                    hidden_dropout_prob=0.0, attention_dropout_prob=0.0)
-    engine = ServingEngine(GPTForCausalLM(cfg).bfloat16(), max_slots=_SLOTS,
-                           block_size=_BLOCK, prefill_chunk=32)
-    _, _, pv, bv = engine._functional()
-    engine._dev_init()
-    toks, tables, lens, temps, seed = engine._dev
-    args = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        (pv, bv, toks, engine.pool.layers, tables, lens, temps, seed))
+    fn, args = pool_programs["step"]
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    text = engine._decode_jit(False).lower(*args).compile().as_text()
+    text = fn.lower(*args).compile().as_text()
     assert text.startswith("HloModule jit_step,")
     assert "%paged_decode." in text and "tpu_custom_call" in text
     assert 'op_name="jit(step)/h1/attn/paged_decode' in text

@@ -11,7 +11,7 @@ import shutil
 import threading
 import time
 
-from . import correct, device, manifest, stats, trace, traffic, workmodel
+from . import correct, device, manifest, stats, trace, traffic
 
 LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 DRAIN_S = 60.0
@@ -72,16 +72,22 @@ def warm_up(engine, mix, sv, vocab, seed):
     return n
 
 
-def _trace_thread(at, secs, tdir, out):
+def _trace_thread(at, secs, tdir, engine, out):
+    """Traces `secs` seconds from `at`; leaves in `out` the period's two
+    instants and the engine's counts at each."""
     import jax
+
+    def mark(tag):
+        out[tag] = {"t": time.monotonic(), "ticks": engine.steps,
+                    "prefill_tokens": engine.prefill_tokens}
 
     def body():
         time.sleep(max(0.0, at - time.monotonic()))
         jax.profiler.start_trace(tdir)
         with jax.profiler.TraceAnnotation(trace.WINDOW):
-            out["t0"] = time.monotonic()
+            mark("begin")
             time.sleep(secs)
-            out["t1"] = time.monotonic()
+            mark("end")
         jax.profiler.stop_trace()
 
     th = threading.Thread(target=body, name="bench-trace", daemon=True)
@@ -175,20 +181,11 @@ def drive(engine, source, close, eos):
             time.sleep(max(0.0, min(0.002, source.next_due(now) - now)))
 
 
-def _served_work(cfg, recs, n_close):
-    """Forward operations of what the window computed, from each request's
-    prompt, cached prefix and tokens at the close."""
-    flops = 0.0
-    for rec, n in zip(recs, n_close):
-        req = rec["req"]
-        if req is None or n < 1:
-            continue
-        plen, m = len(req.prompt), int(req.prefix_matched)
-        pairs = (plen * (plen + 1) - m * (m + 1)) / 2.0
-        flops += workmodel.forward_flops(cfg, plen - m, pairs)
-        d = n - 1
-        flops += workmodel.forward_flops(cfg, d, d * plen + d * (d + 1) / 2.0)
-    return flops
+def _request_facts(recs, n_close):
+    """(prompt_tokens, prefix_matched, tokens_at_close) of every request
+    the engine took: what a work module counts the window from."""
+    return [(len(rec["req"].prompt), int(rec["req"].prefix_matched), n)
+            for rec, n in zip(recs, n_close) if rec["req"] is not None]
 
 
 def _decode_contexts(recs, ta, tb):
@@ -248,7 +245,7 @@ def run(cell, seed, seconds, trace_on, devs, t_start, work_dir):
     cfg, mix = cell["config"], cell["traffic"]
     sv = cfg["serve"]
     vocab = int(cfg["vocab_size"])
-    ref_mod, prog_mod = manifest.models(cfg["models"])
+    ref_mod, prog_mod, work_mod = manifest.models(cfg["models"])
     lowerings = LoweringCount()
     model, engine = prog_mod.build_engine(cfg, seed)
     n_warm = warm_up(engine, mix, sv, vocab, seed)
@@ -263,7 +260,8 @@ def run(cell, seed, seconds, trace_on, devs, t_start, work_dir):
         tdir = work_dir + "/trace"
         shutil.rmtree(tdir, ignore_errors=True)
         secs = min(float(mix["trace_seconds"]), 0.5 * seconds)
-        tr_thread = _trace_thread(t0 + 0.4 * seconds, secs, tdir, tr_times)
+        tr_thread = _trace_thread(t0 + 0.4 * seconds, secs, tdir, engine,
+                                  tr_times)
     source = (OpenLoop if mix["kind"] == "open_loop" else ClosedLoop)(
         plan, t0, seconds, mix=mix, seed=seed, vocab=vocab)
     recs = drive(engine, source, t0 + seconds, vocab - 1)
@@ -299,8 +297,9 @@ def run(cell, seed, seconds, trace_on, devs, t_start, work_dir):
             for r in answered if len(r["req"].output_tokens) > 1]
     late = [r["sent"] - r["due"] for r in recs]
     tokens = sum(n_close)
-    prompt_tokens = sum(len(r["req"].prompt) for r in recs if r["req"] is not None)
-    matched = sum(int(r["req"].prefix_matched) for r in recs if r["req"] is not None)
+    requests = _request_facts(recs, n_close)
+    prompt_tokens = sum(plen for plen, _, _ in requests)
+    matched = sum(m for _, m, _ in requests)
     counters = {
         "compiles_in_window": (after["jit"] - before["jit"])
         + (after["low"] - before["low"]),
@@ -308,12 +307,16 @@ def run(cell, seed, seconds, trace_on, devs, t_start, work_dir):
         "decode_tokens": tokens, "ticks": after["ticks"] - before["ticks"],
         "prefill_tokens": after["prefill"] - before["prefill"],
         "batched_prefills": after["batched"] - before["batched"],
-        "model_flops": _served_work(cfg, recs, n_close), "window_s": window_s,
+        "model_flops": work_mod.served_flops(cfg, requests),
+        "window_s": window_s,
     }
-    work = {}
-    if tr_times.get("t1"):
-        work["paged_attention"] = workmodel.paged_attention_decode(
-            cfg, _decode_contexts(recs, tr_times["t0"], tr_times["t1"]))
+    facts, work = {}, {}
+    if "end" in tr_times:
+        a, b = tr_times["begin"], tr_times["end"]
+        facts = {"decode_contexts": _decode_contexts(recs, a["t"], b["t"]),
+                 "ticks": b["ticks"] - a["ticks"],
+                 "prefill_tokens": b["prefill_tokens"] - a["prefill_tokens"]}
+        work = work_mod.traced_work(cfg, facts)
 
     # ---- free the program's state, then the reference reads the sample
     rows = [(list(r["req"].prompt), list(r["req"].output_tokens))
@@ -362,6 +365,7 @@ def run(cell, seed, seconds, trace_on, devs, t_start, work_dir):
             "tpot_p90_ms": stats.tail_with_misses(tpot, unanswered, 90),
             "setup_s": setup_s},
         "ctx": {"counters": counters, "clocks": clocks, "trace": red,
-                "work": work, "peaks": cell["peaks"], "chips": len(devs)},
+                "work": work, "facts": facts, "requests": requests,
+                "config": cfg, "peaks": cell["peaks"], "chips": len(devs)},
         "device": dev, "notes": notes,
     }

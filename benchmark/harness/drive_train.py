@@ -9,7 +9,7 @@ import time
 
 import numpy as np
 
-from . import correct, device, manifest, stats, trace, traffic, workmodel
+from . import correct, device, manifest, stats, trace, traffic
 
 
 def feed(ids: np.ndarray):
@@ -42,7 +42,7 @@ def run(cell, seed, seconds, trace_on, devs, t_start, work_dir):
 
     cfg, mix = cell["config"], cell["traffic"]
     tr = cfg["train"]
-    ref_mod, prog_mod = manifest.models(cfg["models"])
+    ref_mod, prog_mod, work_mod = manifest.models(cfg["models"])
     batch, seq, vocab = int(tr["batch"]), int(tr["sequence"]), int(cfg["vocab_size"])
     model, step, names = prog_mod.build_train_step(cfg, seed)
     mine = [p for _, p in model.named_parameters()]
@@ -55,7 +55,7 @@ def run(cell, seed, seconds, trace_on, devs, t_start, work_dir):
 
     # ---- set-up: the first steps, which the reference follows
     def parts(name, x):
-        return ref_mod.comparison_parts(name, x, int(cfg["num_heads"]))
+        return ref_mod.comparison_parts(name, x, cfg)
 
     losses, grad_norm = [], None
     for i, ids in enumerate(first):
@@ -131,11 +131,13 @@ def run(cell, seed, seconds, trace_on, devs, t_start, work_dir):
             extra[tag] = correct.train_numbers(alt, ref)["numbers"]
 
     peaks = cell["peaks"]
-    counters = {"model_flops": tokens * workmodel.train_flops_per_token(cfg, seq),
+    counters = {"model_flops": tokens * work_mod.train_flops_per_token(cfg, seq),
                 "window_s": window_s, "steps": steps, "tokens": tokens}
     clocks = {"step_ms_p50": 1e3 * stats.median(chunk) if chunk else None}
-    per_step = workmodel.flash_attention_train(cfg, batch, seq)
-    work = {"flash_attention": {k: v * traced_steps for k, v in per_step.items()}}
+    facts, work = {}, {}
+    if traced_steps:
+        facts = {"batch": batch, "sequence": seq, "steps": traced_steps}
+        work = work_mod.traced_work(cfg, facts)
     return {
         "correct": ok and np.isfinite(last_loss), "checks": checks,
         "attempted": steps, "failed": 0 if np.isfinite(last_loss) else 1,
@@ -143,7 +145,8 @@ def run(cell, seed, seconds, trace_on, devs, t_start, work_dir):
             "train_tokens_per_s": stats.rate(tokens, window_s) / len(devs),
             "setup_s": setup_s},
         "ctx": {"counters": counters, "clocks": clocks, "trace": red,
-                "work": work, "peaks": peaks, "chips": len(devs)},
+                "work": work, "facts": facts, "requests": [], "config": cfg,
+                "peaks": peaks, "chips": len(devs)},
         "device": dev,
         "notes": {"where": cmp_["where"], "program": prog["losses"],
                   "reference": ref["losses"], "last_loss": last_loss,

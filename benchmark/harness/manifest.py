@@ -1,9 +1,38 @@
 """BENCHMARK.json and the data files it names. Everything that belongs to one
 configuration, one traffic mix or one per-layer metric is a file of its own,
 found by the name in BENCHMARK.json: a later PR adds files and entries and
-edits nothing here."""
+edits nothing here.
+
+A model family (a configuration's "models": "<family>") is three modules
+under benchmark/models/, which `models()` finds by name:
+
+- <family>_reference.py: the plain reference and the seeded weights;
+  imports nothing of the program.
+- <family>_program.py: builds the system under test from the configuration.
+- <family>_work.py: the operations and bytes the family's work needs. Plain
+  arithmetic on the configuration file and on facts; imports nothing of the
+  program. The drivers call only these three, and hand the same facts to
+  every per-layer reader in `ctx`:
+    served_flops(cfg, requests) -> forward operations of a serving window,
+      from one (prompt_tokens, prefix_matched, tokens_at_close) a request;
+    traced_work(cfg, facts) -> {work name: {"flops", "bytes"}}, the work of
+      the traced period by kernel or step. Serving facts: "decode_contexts"
+      (the live context of every token decoded in the period),
+      "prefill_tokens" and "ticks" (the engine's counts over the same
+      period). Training facts: "batch", "sequence", "steps" traced;
+    train_flops_per_token(cfg, sequence) -> forward and backward operations
+      a trained token needs.
+  A reader finds a work by the name its layer_metrics/<metric>.json gives
+  ("work": "paged_attention"); a family that has no such work leaves the
+  name out and the metric stays silent there.
+
+`ctx`, which every reader gets: "counters", "clocks", "trace" (the reduced
+trace), "work" (traced_work's), "facts", "requests" (the triples above; a
+training cell has none), "config" (the configuration file), "peaks",
+"chips"."""
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
@@ -67,10 +96,7 @@ def layer_metric(name: str):
 
 
 def models(name: str):
-    """(reference module, program module) of a model family: models/
-    <name>_reference.py imports nothing of the program; <name>_program.py
-    builds the system under test."""
-    import importlib
-
-    return (importlib.import_module(f"benchmark.models.{name}_reference"),
-            importlib.import_module(f"benchmark.models.{name}_program"))
+    """(reference, program, work) modules of a model family: see the top of
+    this file for what each holds."""
+    return tuple(importlib.import_module(f"benchmark.models.{name}_{part}")
+                 for part in ("reference", "program", "work"))

@@ -182,25 +182,25 @@ def loss_fn(params, ids, n_heads, mm=highest_matmul, remat=True):
 
 
 # ------------------------------------------------------------- train steps
-def comparison_parts(name, x, n_heads):
+def comparison_parts(name, x, cfg):
     """One program-sized leaf as the parts whose norms are compared. The
     fused QKV bias is three leaves in one: its key part has no gradient
     under softmax (it moves under Adam by round-off alone) and would hide in
     the norm of the whole, so q, k and v are compared apart."""
     if name.endswith("qkv_b"):
-        r = x.reshape(n_heads, 3, -1)
+        r = x.reshape(int(cfg["num_heads"]), 3, -1)
         return {name + ".q": r[:, 0], name + ".k": r[:, 1], name + ".v": r[:, 2]}
     return {name: x}
 
 
-def _leaf_norms(tree, n_heads):
+def _leaf_norms(tree, cfg):
     """name -> l2 norm, one entry per compared part of a per-layer leaf."""
     out = {}
     for k in TOP_LEAVES:
         out[k] = tree[k]
     for k in LAYER_LEAVES:
         for i in range(tree[k].shape[0]):
-            out.update(comparison_parts(f"blocks.{i}.{k}", tree[k][i], n_heads))
+            out.update(comparison_parts(f"blocks.{i}.{k}", tree[k][i], cfg))
     return {k: jnp.sqrt(jnp.sum(jnp.square(v.astype(jnp.float32))))
             for k, v in out.items()}
 
@@ -237,9 +237,9 @@ def train_reference(cfg, seed, batches, opt, precision="highest",
 
     add = jax.jit(lambda a, b: jax.tree_util.tree_map(jnp.add, a, b))
     scale = jax.jit(lambda a, s: jax.tree_util.tree_map(lambda x: x * s, a))
-    norms = jax.jit(lambda a: _leaf_norms(a, n_heads))
+    norms = jax.jit(lambda a: _leaf_norms(a, cfg))
     diff_norms = jax.jit(lambda a, b: _leaf_norms(
-        jax.tree_util.tree_map(jnp.subtract, a, b), n_heads))
+        jax.tree_util.tree_map(jnp.subtract, a, b), cfg))
 
     p0 = init_weights(cfg, seed, "float32")
     p = p0

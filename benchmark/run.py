@@ -11,7 +11,8 @@ end-to-end metrics, with --trace 1 its per-layer metrics.
 
 A cell is data: its configuration (configs/), traffic mix (traffic/), limits
 (limits/) and per-layer metrics (layer_metrics/) are files found by the names
-in BENCHMARK.json; the one driver per traffic kind is in harness/.
+in BENCHMARK.json; the one driver per traffic kind is in harness/, and a kind
+that DRIVERS does not list is driven by harness/drive_<kind>.py.
 """
 from __future__ import annotations
 
@@ -37,6 +38,22 @@ def log(*a):
     print(*a, file=sys.stderr, flush=True)
 
 
+def driver_for(kind: str):
+    """The driver module of a traffic kind: DRIVERS' entry, else
+    harness/drive_<kind>.py, which a later PR brings with its kind."""
+    import importlib
+
+    name = "benchmark.harness." + DRIVERS.get(kind, f"drive_{kind}")
+    try:
+        return importlib.import_module(name)
+    except ModuleNotFoundError as e:
+        if not (e.name and name.startswith(e.name)):
+            raise               # the driver is there; something it imports is not
+        raise SystemExit(f"unknown traffic kind {kind!r}: known are "
+                         f"{sorted(DRIVERS)}, and there is no "
+                         f"benchmark/harness/drive_{kind}.py") from None
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -57,8 +74,6 @@ def main(argv=None) -> int:
                          "rate other than the mix's")
     args = ap.parse_args(argv)
 
-    import importlib
-
     from benchmark.harness import correct, device, manifest, readers
 
     bench = manifest.benchmark() if args.manifest is None else \
@@ -68,6 +83,7 @@ def main(argv=None) -> int:
                      "describe_trace": args.describe_trace}
     if args.rate is not None:
         cell["traffic"]["rate_per_s"] = args.rate
+    driver = driver_for(cell["traffic"]["kind"])     # fails by name, before jax
     devs = device.require_chips(cell["chips"], allow_cpu=args.rehearse_on_cpu)
     if devs[0].platform == "tpu":
         cell["peaks"] = device.peaks_for(devs[0].device_kind)
@@ -82,8 +98,6 @@ def main(argv=None) -> int:
     log(f"[{cell['name']}] {devs[0].device_kind} x{len(devs)}, seed {args.seed}, "
         f"{args.seconds} s, trace {args.trace}, compile cache {cache_dir}")
 
-    driver = importlib.import_module(
-        "benchmark.harness." + DRIVERS[cell["traffic"]["kind"]])
     res = driver.run(cell, args.seed, args.seconds, bool(args.trace), devs,
                      T_START, WORK_DIR)
 
@@ -104,8 +118,8 @@ def main(argv=None) -> int:
                              "idle_gaps": red["idle_gaps"]}
     line["notes"] = res.get("notes", {})
     line["checks"] = res["checks"]          # each number beside its limit, last
-    log(json.dumps({"notes": line["notes"],
-                    "counters": res["ctx"]["counters"]}, default=str))
+    log(json.dumps({"notes": line["notes"], "counters": res["ctx"]["counters"],
+                    "work": res["ctx"]["work"]}, default=str))
     correct.report(res["checks"], line["correct"])
     print(json.dumps(line, default=float), flush=True)
     return 0

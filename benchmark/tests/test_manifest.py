@@ -71,6 +71,48 @@ def test_per_layer_workloads_name_cells_and_share_layers():
             assert m["unit"] == "%"
 
 
+def test_the_metric_that_went_and_the_one_that_came():
+    """PR 29: paged_attn_roofline (silent since PR 26 named the kernel) is
+    out; mfu.decode, the whole decode step's share of the peak, is what a
+    claim on tpot_p90_ms stands on. Every per-layer entry names its cells."""
+    by_name = {m["name"]: m for m in BENCH["per_layer"]}
+    assert "paged_attn_roofline" not in by_name
+    assert not os.path.exists(os.path.join(
+        manifest.BENCH_DIR, "layer_metrics", "paged_attn_roofline.json"))
+    serve = ["serve-1.3B.chat", "serve-1.3B.docqa"]
+    assert by_name["mfu.decode"] == {
+        "name": "mfu.decode", "unit": "%", "better": "higher",
+        "source": "device_trace", "layer": "whole step",
+        "moves": "tpot_p90_ms", "workloads": serve}
+    assert by_name["paged_decode_roofline"]["workloads"] == serve
+    assert by_name["mfu.serve"]["moves"] == "serve_tokens_per_s"
+    assert all(m.get("workloads") for m in BENCH["per_layer"])
+    # every roofline that moves an end-to-end metric has a whole-step mfu
+    # beside it that moves the same one in the same cells
+    for m in BENCH["per_layer"]:
+        if m["name"].endswith("_roofline"):
+            assert any("mfu" in re.split(r"[._]", w["name"])
+                       and w["moves"] == m["moves"]
+                       and set(w["workloads"]) >= set(m["workloads"])
+                       for w in BENCH["per_layer"]), m["name"]
+
+
+def test_mfu_decode_reads_the_familys_count_over_the_decode_modules():
+    spec, mod = manifest.layer_metric("mfu.decode")
+    ctx = {"trace": {"modules": {"jit_step(7)": [0.05, 0.03, 0.02],
+                                 "jit_serve_prefill(8)": [0.4]}},
+           "work": {"decode_step": {"flops": 2.0e9, "bytes": 1.0}},
+           "peaks": {"flops_per_s": 1.0e12, "bytes_per_s": 1.0}, "chips": 1}
+    # 2 GFLOP over 0.1 s of decode modules at 1 TFLOP/s: 2%
+    assert mod.read(ctx, spec) == pytest.approx(2.0)
+    # nothing decoded, no decode module, or a family without that work:
+    # silent, never 0
+    for quiet in ({"work": {"decode_step": {"flops": 0.0, "bytes": 0.0}}},
+                  {"work": {}}, {"trace": {"modules": {"jit_pf(1)": [0.1]}}},
+                  {"trace": {}}):
+        assert mod.read({**ctx, **quiet}, spec) is None
+
+
 def test_a_reader_that_finds_nothing_returns_nothing():
     ctx = {"counters": {}, "clocks": {}, "trace": {}, "work": {},
            "peaks": {"flops_per_s": 1.0, "bytes_per_s": 1.0}, "chips": 1}

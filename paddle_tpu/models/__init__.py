@@ -2,6 +2,8 @@ from . import gpt  # noqa: F401
 from .gpt import GPTConfig, GPTForCausalLM, GPTModel  # noqa: F401
 from . import llama  # noqa: F401
 from .llama import LlamaConfig, LlamaForCausalLM, LlamaModel  # noqa: F401
+from . import laguna  # noqa: F401
+from .laguna import LagunaConfig, LagunaForCausalLM, LagunaModel  # noqa: F401
 from . import bert  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig,

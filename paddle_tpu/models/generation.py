@@ -20,6 +20,7 @@ TPU-first design:
 """
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import List, Optional, Tuple
 
 import jax
@@ -30,13 +31,57 @@ from ..core import random as _random
 from ..core.tensor import Tensor
 
 
-def init_kv_cache(batch: int, max_len: int, num_layers: int,
-                  num_kv_heads: int, head_dim: int, dtype=jnp.float32):
-    """Allocate the per-layer static KV ring: list of (k, v) arrays."""
+@dataclass(frozen=True)
+class LayerCacheSpec:
+    """What one layer keeps between decode steps: the contract between a
+    model and whatever holds its cache (generate() below, the serving
+    engine). `kind` "full": keys and values of every earlier position;
+    "window": of the last `window` positions only, so a holder may keep
+    no more. `counters`: int32 counters the layer adds up beside its cache
+    (a sparse layer's pairs by expert); the serving engine keeps them on the
+    device and hands them to the layer as `cache.counters`."""
+
+    kind: str
+    kv_heads: int
+    head_dim: int
+    window: int = 0
+    counters: int = 0
+
+    def __post_init__(self):
+        if self.kind not in ("full", "window"):
+            raise ValueError(f"cache kind {self.kind!r}: full or window")
+        if (self.kind == "window") != (self.window > 0):
+            raise ValueError("a window layer states its window, a full "
+                             "layer none")
+
+    @property
+    def group(self):
+        """Layers that share a block table: same kind, same window."""
+        return (self.kind, self.window)
+
+
+@dataclass(frozen=True)
+class CacheSpec:
+    layers: Tuple[LayerCacheSpec, ...]
+    max_positions: int
+
+
+def uniform_cache_spec(num_layers: int, num_kv_heads: int, head_dim: int,
+                       max_positions: int) -> CacheSpec:
+    """Every layer full attention over the same K/V heads (GPT, LLaMA)."""
+    return CacheSpec((LayerCacheSpec("full", num_kv_heads, head_dim),)
+                     * num_layers, max_positions)
+
+
+def init_kv_cache(batch: int, max_len: int, spec: CacheSpec,
+                  dtype=jnp.float32):
+    """Allocate the per-layer static KV buffers [batch, max_len, kv_heads,
+    head_dim]: list of (k, v) arrays. A window layer's is as long as the
+    others: its attention masks what lies before the window."""
     return [
-        (jnp.zeros((batch, max_len, num_kv_heads, head_dim), dtype),
-         jnp.zeros((batch, max_len, num_kv_heads, head_dim), dtype))
-        for _ in range(num_layers)
+        (jnp.zeros((batch, max_len, l.kv_heads, l.head_dim), dtype),
+         jnp.zeros((batch, max_len, l.kv_heads, l.head_dim), dtype))
+        for l in spec.layers
     ]
 
 
@@ -70,8 +115,10 @@ class GenerationMixin:
     """Adds `generate()` to a CausalLM whose forward supports
     `forward(input_ids, caches=..., pos=...) -> (logits, caches)`.
 
-    Subclass contract (GPTForCausalLM / LlamaForCausalLM):
-      * `_decode_geometry() -> (num_layers, num_kv_heads, head_dim, max_pos)`
+    Subclass contract (GPTForCausalLM / LlamaForCausalLM /
+    LagunaForCausalLM):
+      * `cache_spec() -> CacheSpec`: per layer, what it keeps (full or
+        window, K/V heads, head size), and the positions the model allows
       * forward threading as above with static-shape caches.
     """
 
@@ -132,15 +179,15 @@ class GenerationMixin:
         ids = input_ids._value if isinstance(input_ids, Tensor) else jnp.asarray(input_ids)
         ids = ids.astype(jnp.int32)
         b, s0 = ids.shape
-        n_layers, n_kv, hd, max_pos = self._decode_geometry()
+        spec = self.cache_spec()
+        max_pos = spec.max_positions
         max_len = min(int(max_pos), s0 + max_new_tokens)
         n_new = max_len - s0
         if n_new <= 0:
             raise ValueError(
                 f"prompt length {s0} leaves no room under "
                 f"max_position_embeddings={max_pos}")
-        caches = init_kv_cache(b, max_len, n_layers, n_kv, hd,
-                               self._cache_dtype())
+        caches = init_kv_cache(b, max_len, spec, self._cache_dtype())
 
         fn, params, buffers = self._functional_forward()
         param_vals = [p._value for p in params]

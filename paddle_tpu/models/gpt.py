@@ -28,7 +28,7 @@ from ..distributed.fleet.mp_layers import (
 )
 from ..nn import functional as F
 from ..ops import api
-from .generation import GenerationMixin
+from .generation import GenerationMixin, uniform_cache_spec
 
 
 @dataclass
@@ -331,10 +331,11 @@ class GPTForCausalLM(nn.Layer, GenerationMixin):
             self.lm_head = ColumnParallelLinear(config.hidden_size, config.vocab_size,
                                                 has_bias=False, gather_output=True)
 
-    def _decode_geometry(self):
+    def cache_spec(self):
         c = self.config
-        return (c.num_layers, c.num_heads, c.hidden_size // c.num_heads,
-                c.max_position_embeddings)
+        return uniform_cache_spec(c.num_layers, c.num_heads,
+                                  c.hidden_size // c.num_heads,
+                                  c.max_position_embeddings)
 
     def _head(self, h):
         with jax.named_scope("lm_head"):

@@ -26,7 +26,7 @@ from ..distributed.fleet.mp_layers import (
 )
 from ..nn import functional as F
 from ..ops import api
-from .generation import GenerationMixin
+from .generation import GenerationMixin, uniform_cache_spec
 
 
 @dataclass
@@ -267,10 +267,11 @@ class LlamaForCausalLM(nn.Layer, GenerationMixin):
             self.lm_head = ColumnParallelLinear(config.hidden_size, config.vocab_size,
                                                 has_bias=False)
 
-    def _decode_geometry(self):
+    def cache_spec(self):
         c = self.config
-        return (c.num_layers, c.num_key_value_heads,
-                c.hidden_size // c.num_heads, c.max_position_embeddings)
+        return uniform_cache_spec(c.num_layers, c.num_key_value_heads,
+                                  c.hidden_size // c.num_heads,
+                                  c.max_position_embeddings)
 
     def _head(self, h):
         if self.lm_head is None:

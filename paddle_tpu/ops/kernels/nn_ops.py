@@ -7,6 +7,8 @@ fuses; attention additionally has a Pallas fast path (ops/pallas/flash_attention
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -844,8 +846,98 @@ def _rope_xla(q, k, cos, sin, rotate_half):
     return q_out, k_out
 
 
+def rotary_from_positions(q, k, positions, inv_freq, factor=1.0):
+    """Rotary embedding computed from the positions themselves: no table, so
+    a model of a million positions costs nothing until they are used.
+    q, k: [b, s, heads, d]; positions: [b, s] int32; inv_freq: the r/2
+    inverse frequencies (a tuple of floats, r <= d). Rotates the first r
+    dimensions, pairing i with i + r/2, and passes the other d - r through;
+    cos and sin are multiplied by `factor` (YaRN's attention factor)."""
+    inv = jnp.asarray(inv_freq, jnp.float32)
+    r = 2 * inv.shape[0]
+    ang = positions.astype(jnp.float32)[..., None] * inv        # [b, s, r/2]
+    cos = (jnp.cos(ang) * factor)[:, :, None, :]
+    sin = (jnp.sin(ang) * factor)[:, :, None, :]
+
+    def rot(x):
+        xf = x.astype(jnp.float32)
+        x1, x2 = xf[..., :r // 2], xf[..., r // 2:r]
+        out = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+        if r < x.shape[-1]:
+            out.append(xf[..., r:])
+        return jnp.concatenate(out, axis=-1).astype(x.dtype)
+
+    return rot(q), rot(k)
+
+
+# ------------------------------------------------ sparse experts (dropless)
+def moe_experts(x, router_w, w13, w2, expert_lo=0, top_k=1, scale=1.0,
+                norm_topk=True):
+    """The routed experts of a dropless top-k mixture-of-experts layer, for
+    the experts whose weights are HERE: a chip's share under expert
+    parallelism, or all of them.
+
+    x [tokens, hidden]; router_w [hidden, experts published]; w13
+    [held, hidden, 2 * width] (an expert's gate and up projections, gate
+    columns first); w2 [held, width, hidden]. The held experts are ids
+    expert_lo .. expert_lo + held - 1 of the router's. Routing is over ALL
+    the router's experts: softmax in float32, the top_k largest, normalised
+    to sum 1 (norm_topk) and times `scale`. Every (token, expert) pair whose
+    expert is held is computed, none is dropped; a pair whose expert lives
+    elsewhere adds nothing here (on one chip there is no exchange, and no
+    code stands in for one).
+
+    The products are ONE grouped matmul each way over the pairs sorted by
+    expert (ops/pallas/grouped_matmul.py on the TPU, jax.lax.ragged_dot
+    elsewhere): an expert nobody was routed to is never read.
+
+    Returns (y [tokens, hidden], counts [held + 1] int32: pairs by held
+    expert, and last the pairs routed to experts not held).
+    """
+    from .. import pallas as _pallas
+    from ..pallas.grouped_matmul import grouped_matmul, grouped_matmul_xla
+
+    tokens, _ = x.shape
+    held, width = w2.shape[0], w2.shape[1]
+    with jax.named_scope("router"):
+        logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
+        top, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        if norm_topk:
+            top = top / jnp.sum(top, axis=-1, keepdims=True)
+        top = top * scale
+    with jax.named_scope("experts"):
+        local = idx.reshape(-1) - expert_lo                      # [pairs]
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held)          # pairs of others: last
+        order = jnp.argsort(key, stable=True)
+        counts = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)
+        pairs = tokens * top_k
+        tm = 128 if pairs >= 1024 else 32
+        rows = x[order // top_k]
+        pad = (-pairs) % tm
+        if pad:                              # rows of no group, at the end
+            rows = jnp.pad(rows, ((0, pad), (0, 0)))
+        if _pallas.pallas_enabled():
+            gmm = functools.partial(grouped_matmul, tm=tm,
+                                    interpret=_pallas.interpret_mode())
+        else:
+            gmm = grouped_matmul_xla
+        h = gmm(rows, w13, counts[:held])
+        act = (jax.nn.silu(h[:, :width].astype(jnp.float32))
+               * h[:, width:].astype(jnp.float32)).astype(x.dtype)
+        out = gmm(act, w2, counts[:held])[:pairs]
+        # back to (token, choice) order; a pair of another chip's adds 0
+        back = jnp.zeros((pairs,), jnp.int32).at[order].set(
+            jnp.arange(pairs, dtype=jnp.int32))
+        w = jnp.where(mine, top.reshape(-1), 0.0)
+        y = jnp.sum((out[back].astype(jnp.float32) * w[:, None])
+                    .reshape(tokens, top_k, -1), axis=1)
+    return y.astype(x.dtype), counts
+
+
 # ------------------------------------------------- cached decode attention
-def cached_multihead_attention(q, k, v, k_cache, v_cache, pos, scale=None):
+def cached_multihead_attention(q, k, v, k_cache, v_cache, pos, scale=None,
+                               window=None):
     """Cache-carrying attention for autoregressive decoding (reference: the
     cache-KV path of fused_multi_transformer —
     paddle/fluid/operators/fused/fused_multi_transformer_op.cu — which fuses
@@ -861,7 +953,14 @@ def cached_multihead_attention(q, k, v, k_cache, v_cache, pos, scale=None):
     already in the cache) — or a PER-ROW int32 vector [b] for ragged
     batched prefill (each row's new tokens land at its own offset; writes
     past max_len are dropped, and each row masks to its own prefix).
+    `window`: query i sees the last `window` keys only (its own included).
     Returns (out [b, sq, hq, d], k_cache, v_cache).
+
+    A chunk of queries at a scalar offset over grouped K/V heads or under a
+    window runs, on the TPU, the prefill kernel of ops/pallas/
+    flash_attention.py (K/V heads unrepeated, key blocks outside the causal
+    band or the window skipped); everything else, and every other platform,
+    the masked XLA composition below.
     """
     b, sq, hq, d = q.shape
     max_len = k_cache.shape[1]
@@ -875,8 +974,10 @@ def cached_multihead_attention(q, k, v, k_cache, v_cache, pos, scale=None):
         k_cache = k_cache.at[bidx, idx].set(k.astype(k_cache.dtype))
         v_cache = v_cache.at[bidx, idx].set(v.astype(v_cache.dtype))
         # [b, sq, max_len]: row r's query i sees keys <= pos[r] + i
-        mask = (jnp.arange(max_len)[None, None, :]
-                <= idx[:, :, None])
+        keys = jnp.arange(max_len)[None, None, :]
+        mask = keys <= idx[:, :, None]
+        if window is not None:
+            mask = mask & (keys > idx[:, :, None] - window)
         attn_mask = mask[:, None]        # broadcast over heads
     else:
         pos = pos.reshape(())
@@ -884,10 +985,27 @@ def cached_multihead_attention(q, k, v, k_cache, v_cache, pos, scale=None):
             k_cache, k.astype(k_cache.dtype), (0, pos, 0, 0))
         v_cache = jax.lax.dynamic_update_slice(
             v_cache, v.astype(v_cache.dtype), (0, pos, 0, 0))
+        if hkv != hq or window is not None:
+            from .. import pallas as _pallas
+            from ..pallas.flash_attention import (
+                flash_attention_prefill as _prefill,
+                prefill_supports as _prefill_ok,
+            )
+
+            if _prefill_ok(q.shape, k_cache.shape) and (
+                    _pallas.interpret_mode()
+                    or jax.default_backend() == "tpu"):
+                out = _prefill(q, k_cache.astype(q.dtype),
+                               v_cache.astype(q.dtype), pos, scale=scale,
+                               window=window,
+                               interpret=_pallas.interpret_mode())
+                return out, k_cache, v_cache
         # rows: new queries at absolute positions pos..pos+sq-1; each sees
         # keys at absolute positions <= its own (causal over the prefix)
-        mask = (jnp.arange(max_len)[None, :]
-                <= pos + jnp.arange(sq)[:, None])  # [sq, max_len]
+        rows = pos + jnp.arange(sq)[:, None]
+        mask = jnp.arange(max_len)[None, :] <= rows     # [sq, max_len]
+        if window is not None:
+            mask = mask & (jnp.arange(max_len)[None, :] > rows - window)
         attn_mask = mask[None, None]
     k_all, v_all = k_cache, v_cache
     if hkv != hq:
@@ -902,7 +1020,7 @@ def cached_multihead_attention(q, k, v, k_cache, v_cache, pos, scale=None):
 
 
 def paged_cached_attention(q, k, v, k_pages, v_pages, block_table, seq_lens,
-                           scale=None):
+                           scale=None, window=None):
     """One decode step of attention over a PAGED KV cache (the serving
     engine's per-step op; see paddle_tpu/serving/ and
     ops/pallas/paged_attention.py).
@@ -939,8 +1057,19 @@ def paged_cached_attention(q, k, v, k_pages, v_pages, block_table, seq_lens,
 
     sq > 1 is the speculative-verification window: each query attends
     causally within the window (query i sees pos < seq_lens + i + 1).
+
+    `window` (a window layer of a per-layer cache spec): the query sees the
+    last `window` keys only, and block_table's row is the slot's RING of
+    blocks: position p lives in entry (p // block_size) % ring, so a slot
+    holds the window however long its context grows. Masks go by absolute
+    position; the kernel visits the window's pages alone. One query token a
+    slot: a verify window over a ring is not written.
     """
     slots, sq, hq, d = q.shape
+    if window is not None and sq != 1:
+        raise NotImplementedError(
+            "paged_cached_attention: a multi-token verify window over a "
+            "window layer's ring of blocks is not written")
     bs = k_pages.shape[2]
     seq_lens = jnp.asarray(seq_lens, jnp.int32).reshape(slots)
 
@@ -957,10 +1086,15 @@ def paged_cached_attention(q, k, v, k_pages, v_pages, block_table, seq_lens,
         bt = block_table.astype(jnp.int32)
         pos = seq_lens[:, None] + jnp.arange(sq, dtype=jnp.int32)[None, :]
         page_idx = pos // bs                                 # [slots, sq]
-        gathered = jnp.take_along_axis(
-            bt, jnp.minimum(page_idx, bt.shape[1] - 1), axis=1)
-        # overflow -> null page
-        page = jnp.where(page_idx < bt.shape[1], gathered, 0)[..., None]
+        if window is not None:
+            page = jnp.take_along_axis(bt, page_idx % bt.shape[1],
+                                       axis=1)[..., None]
+        else:
+            gathered = jnp.take_along_axis(
+                bt, jnp.minimum(page_idx, bt.shape[1] - 1), axis=1)
+            # overflow -> null page
+            page = jnp.where(page_idx < bt.shape[1], gathered,
+                             0)[..., None]
         off = (pos % bs)[..., None]
         head = jnp.arange(k_pages.shape[1], dtype=jnp.int32)[None, None, :]
         k_pages = k_pages.at[page, head, off].set(k.astype(k_pages.dtype))
@@ -973,12 +1107,13 @@ def paged_cached_attention(q, k, v, k_pages, v_pages, block_table, seq_lens,
         kernel_ok = _paged_supports(q2.shape, k_pages.shape)
         if kernel_ok and _pallas.interpret_mode():
             out = _paged_kernel(q2, k_pages, v_pages, block_table, ctx,
-                                scale, interpret=True)
+                                scale, interpret=True, window=window)
         elif kernel_ok and jax.default_backend() == "tpu":
             out = _paged_kernel(q2, k_pages, v_pages, block_table, ctx,
-                                scale)
+                                scale, window=window)
         else:
-            out = _paged_xla(q2, k_pages, v_pages, block_table, ctx, scale)
+            out = _paged_xla(q2, k_pages, v_pages, block_table, ctx, scale,
+                             window)
         return out[:, None], k_pages, v_pages
 
     # ---- multi-token verify window ----

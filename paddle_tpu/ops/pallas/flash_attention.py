@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -738,6 +739,100 @@ def _flash_lse_bwd_rule(scale, causal, block_q, block_k, interpret, res, g):
 
 
 flash_attention_with_lse.defvjp(_flash_lse_fwd_rule, _flash_lse_bwd_rule)
+
+
+# ----------------------------------------------- serving prefill (forward)
+def _prefill_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *, scale, window,
+                    block_k, sk):
+    # The forward kernel for a chunk of queries at an offset into a cache:
+    # off_ref [1] (scalar prefetch) is the absolute position of the chunk's
+    # first query; q_ref [block_q, d] one query head; k_ref/v_ref [sk, d]
+    # the K/V head it reads (the index_map maps a group of query heads onto
+    # one K/V head, which is fetched once a group). Query at position p sees
+    # keys p - window < j <= p; key blocks wholly outside that band are not
+    # visited.
+    qi = pl.program_id(1)
+    block_q, d = q_ref.shape
+    q = q_ref[:].astype(jnp.float32) * scale
+    q_lo = off_ref[0] + qi * block_q
+    hi = jnp.minimum(jax.lax.div(q_lo + block_q - 1, block_k) + 1,
+                     sk // block_k)
+    lo = 0 if window is None else \
+        jax.lax.div(jnp.maximum(q_lo - window + 1, 0), block_k)
+
+    def body(j, carry):
+        m_prev, l_prev, acc = carry
+        k = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
+        v = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        q_ids = q_lo + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+        k_ids = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+        seen = q_ids >= k_ids
+        if window is not None:
+            seen = jnp.logical_and(seen, k_ids > q_ids - window)
+        s = jnp.where(seen, s, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        alpha = jnp.exp(m_prev - m_new)
+        pr = jnp.where(seen, jnp.exp(s - m_new[:, None]), 0.0)
+        l_new = l_prev * alpha + jnp.sum(pr, axis=1)
+        acc = acc * alpha[:, None] + jax.lax.dot_general(
+            pr, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((block_q,), jnp.float32)
+    acc0 = jnp.zeros((block_q, d), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, acc0))
+    o_ref[:] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+
+
+def prefill_supports(q_shape, k_shape, block_q=DEFAULT_BLOCK_Q,
+                     block_k=DEFAULT_BLOCK_K) -> bool:
+    b, sq, hq, d = q_shape
+    sk, hkv = k_shape[1], k_shape[2]
+    return (sq % block_q == 0 and sk % block_k == 0 and d % 128 == 0
+            and d <= 256 and hq % hkv == 0)
+
+
+def flash_attention_prefill(q, k, v, q_offset, *, scale=None, window=None,
+                            block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                            interpret=False):
+    """Attention of a chunk of queries over a cache that already holds
+    their keys (serving prefill; forward only). q [b, sq, hq, d]; k, v
+    [b, sk, hkv, d] with hq a multiple of hkv (query head h reads K/V head
+    h // (hq / hkv), unrepeated); q_offset: scalar int32, the absolute
+    position of q[:, 0]. Query at position p sees keys p - window < j <= p
+    (`window` None: every key up to p). Returns [b, sq, hq, d]."""
+    b, sq, hq, d = q.shape
+    sk, hkv = k.shape[1], k.shape[2]
+    g = hq // hkv
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    qr = q.transpose(0, 2, 1, 3).reshape(b * hq, sq, d)
+    kr = k.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
+    vr = v.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
+    off = jnp.asarray(q_offset, jnp.int32).reshape(1)
+    out = pl.pallas_call(
+        functools.partial(_prefill_kernel, scale=scale, window=window,
+                          block_k=block_k, sk=sk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * hq, sq // block_q),
+            in_specs=[
+                pl.BlockSpec((None, block_q, d), lambda i, j, off: (i, j, 0)),
+                pl.BlockSpec((None, sk, d), lambda i, j, off: (i // g, 0, 0)),
+                pl.BlockSpec((None, sk, d), lambda i, j, off: (i // g, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, block_q, d),
+                                   lambda i, j, off: (i, j, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
+        name="flash_prefill",
+        interpret=interpret,
+    )(off, qr, kr, vr)
+    return out.reshape(b, hq, sq, d).transpose(0, 2, 1, 3)
 
 
 def supports(q_shape, k_shape, attn_mask, dropout_p, is_causal=False,

@@ -57,12 +57,22 @@ NEG_INF = -1e30
 
 
 # ------------------------------------------------------------------- kernel
+def _first_block(cl, window, block_size):
+    """Logical block of the oldest key a window layer's query still sees
+    (context cl counts the current token): keys cl - window .. cl - 1."""
+    return jnp.maximum(cl - window, 0) // block_size
+
+
 def _decode_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref,
                    acc_ref, m_ref, l_ref,
-                   acc_s, m_s, l_s, *, block_size, pages_per_split, scale):
+                   acc_s, m_s, l_s, *, block_size, pages_per_split, scale,
+                   window=None):
     # scalar prefetch: bt_ref [slots, max_blocks], cl_ref [slots] (SMEM)
     # blocks: q_ref [g, d]; k_ref/v_ref [block_size, d] (one physical page,
     # this kv head); outputs are per-split partials.
+    # window: the grid's pages are the LOGICAL blocks from the window's
+    # first on (the index_map wraps them onto the slot's ring of blocks),
+    # and keys older than the window are masked by absolute position.
     i = pl.program_id(0)           # slot
     s = pl.program_id(2)           # split
     j = pl.program_id(3)           # page within split
@@ -73,8 +83,10 @@ def _decode_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref,
         l_s[:] = jnp.zeros_like(l_s)
         acc_s[:] = jnp.zeros_like(acc_s)
 
-    page_idx = s * pages_per_split + j
     cl = cl_ref[i]
+    page_idx = s * pages_per_split + j
+    if window is not None:
+        page_idx = page_idx + _first_block(cl, window, block_size)
 
     @pl.when(page_idx * block_size < cl)   # ragged skip: page has live tokens
     def _compute():
@@ -88,6 +100,8 @@ def _decode_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref,
         pos = page_idx * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (g, block_size), 1)
         live = pos < cl
+        if window is not None:
+            live = jnp.logical_and(live, pos >= cl - window)
         sc = jnp.where(live, sc, NEG_INF)
         m_prev = m_s[:]                       # [g, 1]
         l_prev = l_s[:]
@@ -108,18 +122,36 @@ def _decode_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref,
         l_ref[:] = l_s[:]
 
 
+def window_pages(window, block_size, table_width):
+    """Pages a window layer's decode visits a slot: the window's blocks and
+    one more for a window that starts inside a block, never more than the
+    slot's ring holds."""
+    return min(table_width, -(-window // block_size) + 1)
+
+
 def _paged_pallas(q, k_pages, v_pages, block_tables, context_lens, scale,
-                  kv_splits, interpret):
+                  kv_splits, interpret, window=None):
     slots, hq, d = q.shape
     hkv = k_pages.shape[1]
     bs = k_pages.shape[2]
     g = hq // hkv
     max_bps = block_tables.shape[1]
-    pad = (-max_bps) % kv_splits
-    if pad:
-        # padded entries point at the null page; context_lens masks them
-        block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
-    nps = (max_bps + pad) // kv_splits
+    if window is None:
+        pad = (-max_bps) % kv_splits
+        if pad:
+            # padded entries point at the null page; context_lens masks them
+            block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
+        nps = (max_bps + pad) // kv_splits
+
+        def page_of(i, s, j, bt, cl):
+            return bt[i, s * nps + j]
+    else:
+        # the table is a ring: logical block b lives in entry b % max_bps
+        nps = -(-window_pages(window, bs, max_bps) // kv_splits)
+
+        def page_of(i, s, j, bt, cl):
+            return bt[i, (_first_block(cl[i], window, bs) + s * nps + j)
+                      % max_bps]
     qr = q.reshape(slots, hkv, g, d)
     bt = block_tables.astype(jnp.int32)
     cl = context_lens.astype(jnp.int32)
@@ -131,11 +163,11 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, context_lens, scale,
             pl.BlockSpec((None, None, g, d),
                          lambda i, h, s, j, bt, cl: (i, h, 0, 0)),
             pl.BlockSpec((None, None, bs, d),
-                         lambda i, h, s, j, bt, cl, nps=nps:
-                         (bt[i, s * nps + j], h, 0, 0)),
+                         lambda i, h, s, j, bt, cl:
+                         (page_of(i, s, j, bt, cl), h, 0, 0)),
             pl.BlockSpec((None, None, bs, d),
-                         lambda i, h, s, j, bt, cl, nps=nps:
-                         (bt[i, s * nps + j], h, 0, 0)),
+                         lambda i, h, s, j, bt, cl:
+                         (page_of(i, s, j, bt, cl), h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, None, None, g, d),
@@ -153,7 +185,7 @@ def _paged_pallas(q, k_pages, v_pages, block_tables, context_lens, scale,
     )
     acc, m, l = pl.pallas_call(
         functools.partial(_decode_kernel, block_size=bs,
-                          pages_per_split=nps, scale=scale),
+                          pages_per_split=nps, scale=scale, window=window),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((slots, hkv, kv_splits, g, d), jnp.float32),
@@ -353,12 +385,15 @@ def paged_attention_multi(q, k_pages, v_pages, block_tables, context_lens,
 
 # ------------------------------------------------------------- XLA fallback
 def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
-                        scale=None):
+                        scale=None, window=None):
     """Dense-gather reference: gather each slot's pages into a contiguous
     [max_ctx] view, mask past context_lens, fp32 softmax. The default CPU
-    path and the numerics oracle for the kernel tests."""
+    path and the numerics oracle for the kernel tests. With `window` the
+    table is a ring (logical block b in entry b % width): an entry's keys
+    are masked by the absolute position of the newest block it can hold."""
     slots, hq, d = q.shape
     hkv = k_pages.shape[1]
+    bs = k_pages.shape[2]
     g = hq // hkv
     if scale is None:
         scale = 1.0 / math.sqrt(d)
@@ -367,8 +402,18 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
     qg = q.reshape(slots, hkv, g, d).astype(jnp.float32)
     sc = jnp.einsum("bhgd,bkhd->bhgk", qg,
                     k.astype(jnp.float32)) * scale
-    live = (jnp.arange(max_ctx)[None, :]
-            < context_lens.astype(jnp.int32)[:, None])  # [slots, max_ctx]
+    cl = context_lens.astype(jnp.int32)[:, None]
+    if window is None:
+        live = jnp.arange(max_ctx)[None, :] < cl        # [slots, max_ctx]
+    else:
+        width = block_tables.shape[1]
+        last = (cl - 1) // bs
+        entry = jnp.arange(width, dtype=jnp.int32)[None, :]
+        block = last - (last - entry) % width           # newest block there
+        pos = (block[:, :, None] * bs
+               + jnp.arange(bs, dtype=jnp.int32)[None, None, :]
+               ).reshape(slots, max_ctx)
+        live = (pos >= 0) & (pos < cl) & (pos >= cl - window)
     sc = jnp.where(live[:, None, None, :], sc, NEG_INF)
     p = jax.nn.softmax(sc, axis=-1)
     out = jnp.einsum("bhgk,bkhd->bhgd", p, v.astype(jnp.float32))
@@ -377,13 +422,14 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
 
 # ---------------------------------------------------------------- public API
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
-                    scale=None, kv_splits=1, interpret=False):
+                    scale=None, kv_splits=1, interpret=False, window=None):
     """One decode step of ragged paged attention (see module docstring).
-    q: [slots, q_heads, d]; returns [slots, q_heads, d]."""
+    q: [slots, q_heads, d]; returns [slots, q_heads, d]. `window`: a window
+    layer, whose table row is the slot's ring of blocks."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     return _paged_pallas(q, k_pages, v_pages, block_tables, context_lens,
-                         scale, kv_splits, interpret)
+                         scale, kv_splits, interpret, window)
 
 
 def supports(q_shape, k_pages_shape) -> bool:
@@ -406,10 +452,11 @@ _SPLIT_CANDIDATES = [
 
 @_autotune(_SPLIT_CANDIDATES)
 def paged_attention_tuned(q, k_pages, v_pages, block_tables, context_lens,
-                          scale=None, interpret=False, *, kv_splits):
+                          scale=None, interpret=False, window=None, *,
+                          kv_splits):
     """paged_attention with the flash-decoding split count chosen by the
     autotune cache when FLAGS_use_autotune is on; otherwise 1 split."""
     if block_tables.shape[1] < kv_splits:
         raise ValueError("more splits than pages")  # tuner skips
     return paged_attention(q, k_pages, v_pages, block_tables, context_lens,
-                           scale, kv_splits, interpret)
+                           scale, kv_splits, interpret, window)

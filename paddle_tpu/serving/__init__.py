@@ -61,7 +61,7 @@ This package replaces that with the vLLM/TPU-serving shape:
                    detectors (hedge spike, re-dispatch storm, breaker
                    flap, replica TTFT skew) with router-state dumps.
 """
-from .blocks import BlockAllocator  # noqa: F401
+from .blocks import BlockAllocator, WindowRings  # noqa: F401
 from .observability import (  # noqa: F401
     RequestTrace,
     ServingObservability,
@@ -98,6 +98,7 @@ from .server import FleetServer, ServingServer  # noqa: F401
 
 __all__ = [
     "BlockAllocator",
+    "WindowRings",
     "CircuitBreaker",
     "EngineDrainingError",
     "FleetAutoscaler",

@@ -98,6 +98,94 @@ _PREFIX_IMPORT_DEDUPS = _counter("serving_prefix_import_dedup_total",
                                  "resident (idempotent no-op).", always=True)
 
 
+_WINDOW_BLOCKS = _counter(
+    "serving_window_blocks_total",
+    "Blocks of window layers' rings: written (a block of a sequence's keys "
+    "went into a ring entry) and recycled (the entry it went into held an "
+    "older block of the same sequence). written - recycled of a sequence "
+    "never passes its ring.", labelnames=("event",), always=True)
+
+
+class WindowRings:
+    """Host-side bookkeeping of a WINDOW group's pool: a layer that sees
+    only the last `window` keys needs no more of a sequence than that, so a
+    sequence holds a fixed RING of `ring_blocks` blocks however long its
+    context grows: position p lives in ring entry (p // block_size) %
+    ring_blocks, the newest block overwriting the oldest, and the attention
+    masks by absolute position (ops/pallas/paged_attention.py).
+
+    ring_blocks = ceil(window / block_size) + 1: the window's blocks, and
+    one more because a window seldom starts on a block's edge. The pool has
+    a ring for each of `max_seqs` sequences plus the null block 0, so a
+    sequence that has a slot has a ring; `can_reserve` is still asked at
+    admission, as of every cache group."""
+
+    NULL_BLOCK = 0
+
+    def __init__(self, max_seqs: int, window: int, block_size: int):
+        self.window = int(window)
+        self.block_size = int(block_size)
+        self.ring_blocks = -(-self.window // self.block_size) + 1
+        self.max_seqs = int(max_seqs)
+        self.num_blocks = 1 + self.max_seqs * self.ring_blocks
+        self._free: List[int] = list(range(self.max_seqs - 1, -1, -1))
+        self._rings: Dict[object, int] = {}
+
+    def can_reserve(self) -> bool:
+        return bool(self._free)
+
+    def reserve(self, seq_id) -> List[int]:
+        """The sequence's ring: its table row, fixed until it is freed."""
+        if seq_id in self._rings:
+            raise ValueError(f"sequence {seq_id!r} already holds a ring")
+        if not self._free:
+            raise MemoryError("no window ring free")
+        self._rings[seq_id] = self._free.pop()
+        return self.table(seq_id)
+
+    def table(self, seq_id) -> List[int]:
+        first = 1 + self._rings[seq_id] * self.ring_blocks
+        return list(range(first, first + self.ring_blocks))
+
+    def free(self, seq_id, n_tokens: int = 0) -> int:
+        """Release the ring; `n_tokens` positions were written into it, and
+        the counters say how many blocks that was and how many of them
+        overwrote an older one. Returns the blocks held at the end."""
+        self._free.append(self._rings.pop(seq_id))
+        blocks = -(-int(n_tokens) // self.block_size)
+        recycled = max(0, blocks - self.ring_blocks)
+        if blocks:
+            _WINDOW_BLOCKS.inc(blocks, event="written")
+        if recycled:
+            _WINDOW_BLOCKS.inc(recycled, event="recycled")
+        return blocks - recycled
+
+    def sequences(self):
+        return list(self._rings)
+
+    @property
+    def used_blocks(self) -> int:
+        return len(self._rings) * self.ring_blocks
+
+    def check_invariants(self) -> None:
+        held = set(self._rings.values())
+        free = set(self._free)
+        assert len(held) == len(self._rings), "two sequences share a ring"
+        assert not (held & free), "a ring is held and free at once"
+        assert held | free == set(range(self.max_seqs)), "a ring leaked"
+
+    def conservation_ok(self) -> bool:
+        return len(self._rings) + len(self._free) == self.max_seqs
+
+    def occupancy_report(self) -> dict:
+        return {"conservation_ok": self.conservation_ok(),
+                "window": self.window, "ring_blocks": self.ring_blocks,
+                "num_blocks": self.num_blocks - 1,
+                "block_size": self.block_size,
+                "used_blocks": self.used_blocks,
+                "sequences": len(self._rings)}
+
+
 class BlockAllocator:
     """Host-side allocator over a pool of `num_blocks` blocks of
     `block_size` tokens each. Block ids index the device pool directly."""

@@ -26,6 +26,15 @@ and sampled-token fetches are deferred and batched until a token's VALUE
 can matter (eos check, length cap) — so a steady-state tick is a single
 dispatch with no host round-trip.
 
+The cache is the model's to state (`model.cache_spec()`: per layer, full or
+window, K/V heads, head size): layers of one kind share a block table, a
+range of columns of a slot's table row, and each layer has a pool of its
+group's size. The full group's table is the worst-case reservation; a window
+group's is a fixed ring of blocks a slot (blocks.WindowRings), so a window
+layer holds the window however long the context. What the rings do not serve
+yet (prefix-cache hits, speculation, fused steps, the KV wire) refuses by
+name for a model with window layers.
+
 Compiled-program keys are shape-stable: one decode program per engine, one
 prefill/admit program per chunk bucket, one scatter per (workspace, block
 count) — no per-request recompiles at steady state.
@@ -43,8 +52,9 @@ import numpy as np
 
 from ..core import flags as _flags
 from ..core.tensor import Tensor
+from ..observability.registry import counter as _counter, gauge as _gauge
 from ..models.generation import init_kv_cache
-from .blocks import BlockAllocator
+from .blocks import BlockAllocator, WindowRings
 from .observability import (
     _PREFILL_TOKENS,
     PROGRAMS_BUILT,
@@ -54,7 +64,7 @@ from .observability import (
     new_engine_id,
 )
 from ..ops.pallas.paged_attention import from_pages, to_pages
-from .paged import PagedKVPool, PagedLayerCache, write_prefix
+from .paged import PagedKVPool, PagedLayerCache, write_prefix, write_ring
 from .scheduler import Request, Scheduler
 from .speculative import NgramDrafter, SpecState
 
@@ -127,6 +137,24 @@ _flags.define_flag("serving_prefill_bucket", 16,
                    "(per-prompt chunked prefill only).")
 
 
+_MOE_PAIRS = _counter(
+    "serving_moe_pairs_total",
+    "(token, expert) pairs a decode step routed, by whether the expert's "
+    "weights are held here (a chip's share under expert parallelism). "
+    "Added up on the device, published when a request finishes and with "
+    "stats().", labelnames=("held",), always=True)
+_MOE_LOAD = _gauge(
+    "serving_moe_expert_load_max_over_mean",
+    "Busiest held expert's pairs over the mean held expert's, by layer, "
+    "over the decode steps so far.", labelnames=("layer",), always=True)
+_WINDOW_KEYS = _counter(
+    "serving_window_keys_total",
+    "Keys of window layers a decode step's attention fetched (`read`: the "
+    "pages the kernel visits, in tokens) beside the keys of the same "
+    "contexts (`context`: what a full layer would fetch), a layer a slot.",
+    labelnames=("kind",), always=True)
+
+
 class QueueFullError(RuntimeError):
     """submit() rejected: the scheduler queue is at FLAGS_serving_max_queue.
     Carries the depth/limit and a Retry-After hint so the HTTP layer can
@@ -183,7 +211,10 @@ class ServingEngine:
                  spec_pause: Optional[int] = None):
         self.model = model
         model.eval()
-        n_layers, n_kv, head_dim, max_pos = model._decode_geometry()
+        # the one cache contract: per layer, what it keeps (models/
+        # generation.LayerCacheSpec)
+        spec = self._spec = model.cache_spec()
+        max_pos = spec.max_positions
         self.block_size = int(block_size or
                               _flags.get_flag("serving_block_size"))
         self.max_slots = int(max_slots or _flags.get_flag("serving_slots"))
@@ -201,18 +232,60 @@ class ServingEngine:
                               _flags.get_flag("serving_kv_blocks") or
                               auto_blocks)
         self._dtype = model._cache_dtype()
-        self._geometry = (n_layers, n_kv, head_dim)
+        # cache groups: the layers of one kind share a block table, which is
+        # a range of columns of a slot's table row. The full group's is the
+        # worst-case reservation [0, max_blocks_per_seq); a window group's
+        # is the slot's ring of blocks (blocks.WindowRings), whatever the
+        # context
+        windows = sorted({l.window for l in spec.layers
+                          if l.kind == "window"})
+        self.window_rings = [WindowRings(self.max_slots, w, self.block_size)
+                             for w in windows]
+        cols, at = {0: (0, self.max_blocks_per_seq)}, self.max_blocks_per_seq
+        for rings in self.window_rings:
+            cols[rings.window] = (at, at + rings.ring_blocks)
+            at += rings.ring_blocks
+        self._table_cols = at
+        self._layer_cols = [cols[l.window] for l in spec.layers]
+        # (rings, its columns, how many layers read them), for the tick
+        self._ring_cols = [
+            (r, cols[r.window],
+             sum(1 for l in spec.layers if l.window == r.window))
+            for r in self.window_rings]
+        if windows:
+            # what the ring does not serve yet refuses here, by name
+            prefix_cache = self._refuse_over_windows(
+                "prefix_cache", prefix_cache, False,
+                "a prefix hit would have to find the window layers' keys, "
+                "which a ring keeps for the last window only")
+            prefill_bucket = self._refuse_over_windows(
+                "prefill_bucket", prefill_bucket, 0,
+                "the batched prefill program writes whole prompts back "
+                "through one block table")
         self.prefix_cache = (bool(_flags.get_flag("serving_prefix_cache"))
                              if prefix_cache is None else bool(prefix_cache))
         self.prefill_bucket = int(
             _flags.get_flag("serving_prefill_bucket")
             if prefill_bucket is None else prefill_bucket)
-        self.pool = PagedKVPool(self.num_blocks, self.block_size, n_layers,
-                                n_kv, head_dim, self._dtype)
+        by_window = {r.window: r.num_blocks for r in self.window_rings}
+        self.pool = PagedKVPool(
+            [(by_window.get(l.window, self.num_blocks), l.kv_heads,
+              l.head_dim) for l in spec.layers],
+            self.block_size, self._dtype)
         self.allocator = BlockAllocator(self.num_blocks, self.block_size,
                                         prefix_cache=self.prefix_cache)
         self.sched = Scheduler(self.allocator, self.max_slots,
-                               self.max_model_len)
+                               self.max_model_len, self.window_rings)
+        # LayerCacheSpec.counters: a layer's int32 counters, kept on the
+        # device beside the pool and threaded through the decode step
+        self._counter_layers = [i for i, l in enumerate(spec.layers)
+                                if l.counters]
+        self._counters = tuple(
+            jnp.zeros((spec.layers[i].counters,), jnp.int32)
+            for i in self._counter_layers)
+        self._counters_published = [np.zeros(spec.layers[i].counters,
+                                             np.int64)
+                                    for i in self._counter_layers]
         # host mirror of per-slot decode state; the authoritative copies
         # live on device in _dev and are updated incrementally (per-slot
         # scatter on admission / block-table growth) — the decode loop
@@ -220,7 +293,7 @@ class ServingEngine:
         # straight back in, and sampled-token fetches are DEFERRED and
         # batched (one transfer per flush) so host dispatch runs ahead of
         # device compute instead of syncing every tick
-        self._tables = np.zeros((self.max_slots, self.max_blocks_per_seq),
+        self._tables = np.zeros((self.max_slots, self._table_cols),
                                 np.int32)
         self._lens = np.zeros(self.max_slots, np.int32)
         self._toks = np.zeros(self.max_slots, np.int32)
@@ -236,6 +309,14 @@ class ServingEngine:
                               if spec_ngram is None else spec_ngram)
         self.spec_pause = int(_flags.get_flag("serving_spec_pause")
                               if spec_pause is None else spec_pause)
+        if windows:
+            self._refuse_over_windows(
+                "spec_k", self.spec_k, 0,
+                "a verify window of several tokens over a ring of blocks "
+                "is not written, nor its rollback")
+            self._refuse_over_windows(
+                "FLAGS_serving_fuse_steps", self.fuse_steps, 1,
+                "the fused loop does not thread the window layers' state")
         if self.spec_k > 0 and self.fuse_steps > 1:
             raise ValueError(
                 "FLAGS_serving_fuse_steps > 1 and speculative decoding "
@@ -261,6 +342,17 @@ class ServingEngine:
         # lifecycle hooks: request traces, SLO histograms, per-tick
         # gauges, serving anomaly detectors + flight arm
         self.obs = ServingObservability(self)
+
+    def _refuse_over_windows(self, name, asked, allowed, why):
+        """A feature the window layers' rings do not serve yet: left at
+        its default it is off; asked for, it refuses by name."""
+        if asked is None or asked == allowed:
+            return allowed
+        raise ValueError(
+            f"ServingEngine: {name}={asked!r} cannot serve a model whose "
+            f"cache spec has window layers (windows "
+            f"{[r.window for r in self.window_rings]}): {why}. "
+            f"Leave it at {allowed!r}.")
 
     # -- registry-backed counter views (historical int attributes) --------
     @property
@@ -316,7 +408,13 @@ class ServingEngine:
             model = self.model
             static_fn, params, buffers = model._functional_forward()
 
-            def paged_fn(pv, bv, ids, pages, bt, sl):
+            cols, n_cols = self._layer_cols, self._table_cols
+            counted = set(self._counter_layers)
+
+            def paged_fn(pv, bv, ids, pages, bt, sl, counters=()):
+                """counters: the arrays of the layers that keep some, in
+                layer order, or () to leave them be. Returns (logits, new
+                pages, new counters)."""
                 saved_p = [(p._value, p.stop_gradient) for p in params]
                 saved_b = [b._value for b in buffers]
                 try:
@@ -325,14 +423,20 @@ class ServingEngine:
                         p.stop_gradient = True
                     for b, v in zip(buffers, bv):
                         b._value = v
-                    caches_t = [
-                        PagedLayerCache(Tensor(k), Tensor(v), Tensor(bt),
-                                        Tensor(sl))
-                        for k, v in pages]
+                    mine = iter(counters)
+                    caches_t = []
+                    for i, ((k, v), (a, b)) in enumerate(zip(pages, cols)):
+                        # the layer's group's columns of the table row
+                        t = bt if (a, b) == (0, n_cols) else bt[:, a:b]
+                        c = Tensor(next(mine)) \
+                            if counters and i in counted else None
+                        caches_t.append(PagedLayerCache(
+                            Tensor(k), Tensor(v), Tensor(t), Tensor(sl), c))
                     logits, ncs = model.forward(Tensor(ids), caches=caches_t,
                                                 pos=None)
-                    return logits._value, [(k._value, v._value)
-                                           for k, v in ncs]
+                    return (logits._value,
+                            [(nc[0]._value, nc[1]._value) for nc in ncs],
+                            tuple(nc[2]._value for nc in ncs if len(nc) > 2))
                 finally:
                     for p, (v, sg) in zip(params, saved_p):
                         p._value, p.stop_gradient = v, sg
@@ -370,16 +474,16 @@ class ServingEngine:
         tiny model — a real fraction of the tick); temperature batches pay
         it. Both share the (tok, pages, bt, sl, temps, seed) signature so
         the engine can switch per tick as the batch mix changes."""
-        key = ("decode", self.max_slots, self.max_blocks_per_seq, sampled)
+        key = ("decode", self.max_slots, self._table_cols, sampled)
 
         def build():
             paged_fn = self._functional()[0]
 
             # the one program whose function keeps the name `step`: the
             # benchmark's decode_step_ms reads XLA module jit_step
-            def step(pv, bv, tok, pages, bt, sl, temps, seed):
-                logits, new_pages = paged_fn(pv, bv, tok[:, None], pages,
-                                             bt, sl)
+            def step(pv, bv, tok, pages, bt, sl, temps, seed, counters=()):
+                logits, new_pages, counters = paged_fn(
+                    pv, bv, tok[:, None], pages, bt, sl, counters)
                 with jax.named_scope("sample"):
                     lg = logits[:, -1, :].astype(jnp.float32)
                     greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
@@ -395,9 +499,9 @@ class ServingEngine:
                 # sl/seed advance on device so steady-state ticks feed these
                 # outputs straight back in (idle slots drift harmlessly —
                 # they re-upload when the slot is next filled)
-                return nxt, new_pages, sl + 1, seed + 1
+                return nxt, new_pages, sl + 1, seed + 1, counters
 
-            return jax.jit(step, donate_argnums=(3, 5, 7))
+            return jax.jit(step, donate_argnums=(3, 5, 7, 8))
 
         return self._program("decode", key, build)
 
@@ -408,7 +512,7 @@ class ServingEngine:
         real fraction of a small model's step on CPU, and it amortizes
         k-fold. Returns the k sampled tokens flattened [k * slots] for the
         deferred-flush path plus the same carry as the 1-step program."""
-        key = ("decode_multi", self.max_slots, self.max_blocks_per_seq, k)
+        key = ("decode_multi", self.max_slots, self._table_cols, k)
 
         def build():
             paged_fn = self._functional()[0]
@@ -416,8 +520,8 @@ class ServingEngine:
             def serve_decode_fused(pv, bv, tok, pages, bt, sl, temps, seed):
                 def body(i, carry):
                     tok, pages, sl, out = carry
-                    logits, new_pages = paged_fn(pv, bv, tok[:, None],
-                                                 pages, bt, sl)
+                    logits, new_pages, _ = paged_fn(pv, bv, tok[:, None],
+                                                    pages, bt, sl)
                     with jax.named_scope("sample"):
                         lg = logits[:, -1, :].astype(jnp.float32)
                         nxt = jnp.argmax(lg, axis=-1).astype(jnp.int32)
@@ -445,14 +549,14 @@ class ServingEngine:
         Sampled slots (temperature > 0) ride with a zero draft length:
         their column-0 logits are the same distribution the plain step
         would compute, and their next token is the categorical draw."""
-        key = ("spec", self.max_slots, self.max_blocks_per_seq, W, sampled)
+        key = ("spec", self.max_slots, self._table_cols, W, sampled)
 
         def build():
             paged_fn = self._functional()[0]
 
             def serve_spec_verify(pv, bv, win, pages, bt, sl, dls, temps,
                                   seed):
-                logits, new_pages = paged_fn(pv, bv, win, pages, bt, sl)
+                logits, new_pages, _ = paged_fn(pv, bv, win, pages, bt, sl)
                 with jax.named_scope("sample"):
                     lg = logits.astype(jnp.float32)   # [slots, W, vocab]
                     greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
@@ -489,7 +593,7 @@ class ServingEngine:
         slot (slot-LIFO and block-LIFO reuse can misalign). An all-zero
         table row points the idle slot at the null block, where its writes
         are harmless and its (len 0) context is never read."""
-        key = ("clear_slot", self.max_slots, self.max_blocks_per_seq)
+        key = ("clear_slot", self.max_slots, self._table_cols)
 
         def build():
             def serve_clear_slot(toks, bt, sl, temps, slot):
@@ -511,7 +615,7 @@ class ServingEngine:
         nothing. The slot index is traced, so one program serves every
         slot. No donation: the incoming token vector is also referenced by
         the deferred-flush queue."""
-        key = ("admit", chunk, self.max_slots, self.max_blocks_per_seq)
+        key = ("admit", chunk, self.max_slots, self._table_cols)
 
         def build():
             def serve_admit(logits, idx, toks, bt, sl, temps, slot, table,
@@ -574,7 +678,7 @@ class ServingEngine:
         dispatch. Pages are donated (in-place pool update); the decode
         state tensors are not (the token vector may be referenced by the
         deferred-flush queue)."""
-        key = ("admit_cow", self.max_slots, self.max_blocks_per_seq)
+        key = ("admit_cow", self.max_slots, self._table_cols)
 
         def build():
             def serve_admit_cow(pages, toks, bt, sl, temps, src, dst, slot,
@@ -650,11 +754,23 @@ class ServingEngine:
         def build():
             bs = self.block_size
             n = nb * bs
+            cols = self._layer_cols
+            full = (0, self.max_blocks_per_seq)
 
-            def serve_scatter(pages, caches, table):
-                return [write_prefix(kp, vp, k[0, :n], v[0, :n], table,
-                                     block_size=bs)
-                        for (kp, vp), (k, v) in zip(pages, caches)]
+            def serve_scatter(pages, caches, row, last_block):
+                """row: the slot's table row, every group's columns;
+                last_block: the logical block of the prompt's last token
+                (a window layer keeps the blocks that end there)."""
+                out = []
+                for (kp, vp), (k, v), (a, b) in zip(pages, caches, cols):
+                    if (a, b) == full:
+                        out.append(write_prefix(
+                            kp, vp, k[0, :n], v[0, :n], row[a:a + nb],
+                            block_size=bs))
+                    else:
+                        out.append(write_ring(kp, vp, k[0], v[0], row[a:b],
+                                              last_block, block_size=bs))
+                return out
 
             return jax.jit(serve_scatter, donate_argnums=(0,))
 
@@ -731,6 +847,15 @@ class ServingEngine:
             return True
 
     # ------------------------------------------- KV-block streaming wire
+    def _no_kv_wire_over_windows(self, name):
+        if self.window_rings:
+            raise NotImplementedError(
+                f"ServingEngine.{name}: the KV wire carries prefix-cache "
+                f"blocks of one block table, and a model whose cache spec "
+                f"has window layers keeps those layers' keys in rings "
+                f"(windows {[r.window for r in self.window_rings]}), with "
+                f"no prefix cache over them")
+
     def export_kv_blocks(self, tokens: List[int]) -> List[dict]:
         """Serialize the RESIDENT full-block prefix of `tokens` for
         streaming to another replica: one record per indexed block, chain
@@ -739,6 +864,7 @@ class ServingEngine:
         bytes gathered from the device pool. Read-only; the wire format is
         what ingest_kv_blocks() (and the HTTP /kv/ingest endpoint, after
         base64) accepts."""
+        self._no_kv_wire_over_windows("export_kv_blocks")
         with self._lock:
             recs = self.allocator.export_prefix(tokens)
             if not recs:
@@ -766,6 +892,7 @@ class ServingEngine:
         (descendants could never be matched past the hole). Idempotent:
         already-resident digests are deduped without touching the pool.
         Returns {"imported", "dedup", "rejected", "skipped", "bytes"}."""
+        self._no_kv_wire_over_windows("ingest_kv_blocks")
         n_layers = len(self.pool.layers)
         kp0 = self.pool.layers[0][0]
         np_dtype = np.dtype(kp0.dtype)
@@ -849,7 +976,10 @@ class ServingEngine:
             for req in [r for r in self.sched.prefilling
                         if r._cow_src is not None]:
                 self._admit_cached(req)
-            sp.set(admitted=len(admitted), waiting=len(self.sched.waiting))
+            sp.set(admitted=len(admitted), waiting=len(self.sched.waiting),
+                   reserved_full=self.sched._reserved_blocks,
+                   reserved_window=sum(r.used_blocks
+                                       for r in self.window_rings))
         # batched multi-prompt prefill: a burst of short unmatched
         # suffixes admits in ONE dispatch instead of one per prompt
         if self.prefill_bucket > 0:
@@ -899,6 +1029,16 @@ class ServingEngine:
         return [r.prompt + r.output_tokens for r in reqs]
 
     # ----------------------------------------------------------- prefill
+    def _table_row(self, req: Request) -> np.ndarray:
+        """The request's table row: every cache group's table in its
+        columns, the full group's reservation first."""
+        row = np.zeros(self._table_cols, np.int32)
+        table = self.allocator.table(req.request_id)
+        row[:len(table)] = table
+        for rings, (a, b), _ in self._ring_cols:
+            row[a:b] = rings.table(req.request_id)
+        return row
+
     def _admit_cached(self, req: Request) -> None:
         """Full-prompt prefix-cache hit: every prompt block is already in
         the pool, so the request enters decode DIRECTLY — zero prefill
@@ -918,8 +1058,7 @@ class ServingEngine:
         table = np.asarray(self.allocator.table(req.request_id), np.int32)
         dst = int(table[plen // self.block_size - 1])
         src = int(req._cow_src)
-        self._tables[slot] = 0
-        self._tables[slot, :len(table)] = table
+        self._tables[slot] = self._table_row(req)
         self._lens[slot] = plen - 1
         self._toks[slot] = req.prompt[-1]
         self._temps[slot] = req.temperature
@@ -963,7 +1102,7 @@ class ServingEngine:
             tP = np.zeros((n, nb), np.int32)
             last = np.zeros(n, np.int32)
             slots = np.full(n, self.max_slots, np.int32)   # OOB -> dropped
-            bt_rows = np.zeros((n, self.max_blocks_per_seq), np.int32)
+            bt_rows = np.zeros((n, self._table_cols), np.int32)
             plens = np.zeros(n, np.int32)
             temps = np.zeros(n, np.float32)
             for r, req in enumerate(reqs):
@@ -976,7 +1115,7 @@ class ServingEngine:
                 tP[r, :min(nb, len(table))] = table[:nb]
                 last[r] = take - 1
                 slots[r] = req.slot
-                bt_rows[r, :len(table)] = table
+                bt_rows[r] = self._table_row(req)
                 plens[r] = plen
                 temps[r] = req.temperature
             if self._dev is None:
@@ -1013,10 +1152,7 @@ class ServingEngine:
                     # live dedup: identical blocks prefilled concurrently
                     # in this burst now share storage — adopt the swapped
                     # table on host AND in the already-uploaded device row
-                    table = np.asarray(
-                        self.allocator.table(req.request_id), np.int32)
-                    self._tables[slot] = 0
-                    self._tables[slot, :len(table)] = table
+                    self._tables[slot] = self._table_row(req)
                     d_toks, d_tables, d_lens, d_temps, d_seed = self._dev
                     with self.obs.span("serving.host_upload",
                                        what="dedup_table"):
@@ -1047,7 +1183,6 @@ class ServingEngine:
         with self.obs.request_span("serving.prefill_chunk", req,
                                    tokens=take, batched=False):
             _, _, pv, bv = self._functional()
-            n_layers, n_kv, head_dim = self._geometry
             # chunk writes start at prefix_matched (a block multiple, not
             # necessarily a chunk multiple): the workspace must cover the LAST
             # chunk window, or dynamic_update_slice would clamp it backwards
@@ -1063,8 +1198,8 @@ class ServingEngine:
                     req._ws_caches = self._gather_jit(padded, mb)(
                         self.pool.layers, head)
                 else:
-                    req._ws_caches = init_kv_cache(1, padded, n_layers, n_kv,
-                                                   head_dim, self._dtype)
+                    req._ws_caches = init_kv_cache(1, padded, self._spec,
+                                                   self._dtype)
             ids = np.zeros((1, chunk), np.int32)
             ids[0, :take] = req.prompt[start:start + take]
             logits, req._ws_caches = self._prefill_jit(chunk, padded)(
@@ -1081,10 +1216,10 @@ class ServingEngine:
         # decode. The table is the WHOLE worst-case reservation (scheduler
         # admit); only the prompt-covering prefix is scattered — decode
         # appends fill the rest position by position.
-        table = np.asarray(self.allocator.table(req.request_id), np.int32)
         nb = -(-plen // self.block_size)
         new_layers = self._scatter_jit(padded, nb)(
-            self.pool.layers, req._ws_caches, table[:nb])
+            self.pool.layers, req._ws_caches, self._table_row(req),
+            np.int32((plen - 1) // self.block_size))
         self.pool.replace(new_layers)
         req._ws_caches = None
         if self.prefix_cache:
@@ -1093,10 +1228,7 @@ class ServingEngine:
             self.allocator.register_prefix(req.request_id, req.prompt)
             if self.allocator.last_dedup:
                 # live dedup (a twin registered first while this prompt
-                # prefilled): adopt the swapped table before the slot's
-                # device row is uploaded below
-                table = np.asarray(self.allocator.table(req.request_id),
-                                   np.int32)
+                # prefilled): the slot's row below is read after the swap
                 self._stats.inc("dedup_admissions")
         if req.prefill_only:
             # disaggregated prefill pass: the prompt's KV is scattered and
@@ -1106,8 +1238,7 @@ class ServingEngine:
             self._finish(req, "prefill_complete")
             return
         slot = req.slot
-        self._tables[slot] = 0
-        self._tables[slot, :len(table)] = table
+        self._tables[slot] = self._table_row(req)
         self._lens[slot] = plen
         self._temps[slot] = req.temperature
         # a greedy no-eos request never needs its first token's VALUE on
@@ -1204,10 +1335,10 @@ class ServingEngine:
             # host state but the pending counters: no allocator call, no
             # table scatter, just one compiled-program dispatch
             if k == 1:
-                nxt, new_layers, new_lens, new_seed = self._decode_jit(
-                    needs_sampling)(
-                    pv, bv, d_toks, self.pool.layers, d_tables, d_lens,
-                    d_temps, d_seed)
+                nxt, new_layers, new_lens, new_seed, self._counters = \
+                    self._decode_jit(needs_sampling)(
+                        pv, bv, d_toks, self.pool.layers, d_tables, d_lens,
+                        d_temps, d_seed, self._counters)
                 toks = nxt
                 items = [(slot, slot, req) for slot, req in running]
             else:
@@ -1225,6 +1356,8 @@ class ServingEngine:
             # matter — a request with an eos_token_id (checked every
             # token), or one whose count reached its length cap this tick.
             self._pending.append((toks, items))
+        if self.window_rings:
+            self._count_window_keys([slot for slot, _ in running])
         flush = False
         for slot, req in running:
             req._pending_n += k
@@ -1237,6 +1370,40 @@ class ServingEngine:
         if flush:
             self._flush_pending()
         return len(running) * k
+
+    def _count_window_keys(self, slots) -> None:
+        """serving_window_keys_total for one decode step: the pages of each
+        window layer's ring that the kernel visits (the blocks from the
+        window's first key to the current token), in tokens, beside the
+        context a full layer would read."""
+        ctx = self._lens[slots].astype(np.int64) + 1
+        bs = self.block_size
+        for rings, _, layers in self._ring_cols:
+            pages = (ctx - 1) // bs - np.maximum(ctx - rings.window, 0) // bs + 1
+            _WINDOW_KEYS.inc(int(pages.sum()) * bs * layers, kind="read")
+            _WINDOW_KEYS.inc(int(ctx.sum()) * layers, kind="context")
+
+    def layer_counters(self) -> dict:
+        """{layer index: counters} fetched from the device (one transfer),
+        and what they add since the last fetch published to the registry:
+        for a sparse layer, pairs by held expert and last the pairs of
+        experts held elsewhere."""
+        if not self._counters:
+            return {}
+        with self.obs.span("serving.fetch", what="layer_counters", ticks=0,
+                           tokens=0):
+            vals = [np.asarray(v, np.int64)
+                    for v in jax.device_get(list(self._counters))]
+        for i, v, seen in zip(self._counter_layers, vals,
+                              self._counters_published):
+            new = v - seen
+            _MOE_PAIRS.inc(int(new[:-1].sum()), held="yes")
+            _MOE_PAIRS.inc(int(new[-1]), held="no")
+            seen[:] = v
+            if v[:-1].sum() > 0:
+                _MOE_LOAD.set(float(v[:-1].max() / v[:-1].mean()),
+                              layer=f"h{i}")
+        return dict(zip(self._counter_layers, vals))
 
     def _spec_step(self) -> Optional[int]:
         """One speculative tick, or None to fall through to the plain
@@ -1439,6 +1606,8 @@ class ServingEngine:
                 d_toks, d_tables, d_lens, d_temps, d_seed = self._dev
                 self._dev = (*self._clear_slot_jit()(
                     d_toks, d_tables, d_lens, d_temps, slot), d_seed)
+        if self._counters and reason in ("stop", "length"):
+            self.layer_counters()       # the flush before it just waited
         self.obs.on_finish(req, reason)
 
     # ------------------------------------------------------------ status
@@ -1460,6 +1629,9 @@ class ServingEngine:
         return {
             "steps": self.steps,
             "kv": self.allocator.occupancy_report(),
+            "kv_window": [r.occupancy_report() for r in self.window_rings],
+            "layer_counters": {f"h{i}": [int(x) for x in v]
+                               for i, v in self.layer_counters().items()},
             "prefix_cache": self.prefix_cache,
             "prefill_programs": self.prefill_programs,
             "batched_prefills": self.batched_prefills,

@@ -4,6 +4,12 @@ The pool is ONE preallocated array pair per layer:
 
     k_pages, v_pages : [num_blocks, kv_heads, block_size, head_dim]
 
+with the layer's own K/V heads and head size, and as many blocks as its
+CACHE GROUP has: the layers of one kind (models/generation.LayerCacheSpec:
+full, or a window of w keys) share a block table, and each group is sized
+for what its layers keep (a full group by the engine's num_blocks, a window
+group a ring of blocks a slot: blocks.WindowRings).
+
 Head-major inside a page: one (page, kv head) is a contiguous
 [block_size, head_dim] tile, which is what the TPU lowering of the paged
 attention kernel needs for the last two dims of its K/V block
@@ -37,37 +43,38 @@ class PagedLayerCache:
     token of the current decode step is written at position seq_lens and
     included in attention by the op)."""
 
-    __slots__ = ("k_pages", "v_pages", "block_table", "seq_lens")
+    __slots__ = ("k_pages", "v_pages", "block_table", "seq_lens",
+                 "counters")
 
-    def __init__(self, k_pages, v_pages, block_table, seq_lens):
+    def __init__(self, k_pages, v_pages, block_table, seq_lens,
+                 counters=None):
         self.k_pages = k_pages
         self.v_pages = v_pages
-        self.block_table = block_table
+        self.block_table = block_table      # the layer's group's table
         self.seq_lens = seq_lens
+        # LayerCacheSpec.counters: the layer returns (k, v, counters + its
+        # own) and the engine keeps the sum on the device
+        self.counters = counters
 
 
 class PagedKVPool:
     """Owns the per-layer page arrays. Holds plain jax arrays (not Tensors):
     the compiled decode step takes and returns them as donated buffers."""
 
-    def __init__(self, num_blocks: int, block_size: int, num_layers: int,
-                 num_kv_heads: int, head_dim: int, dtype=jnp.float32):
-        self.num_blocks = int(num_blocks)
+    def __init__(self, layer_blocks, block_size: int, dtype=jnp.float32):
+        """layer_blocks: for each layer in the model's order, (blocks of its
+        cache group, kv_heads, head_dim)."""
         self.block_size = int(block_size)
-        self.num_layers = int(num_layers)
-        self.num_kv_heads = int(num_kv_heads)
-        self.head_dim = int(head_dim)
         self.dtype = dtype
-        shape = (self.num_blocks, self.num_kv_heads, self.block_size,
-                 self.head_dim)
-        self.layers: List[Tuple[jax.Array, jax.Array]] = [
-            (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-            for _ in range(self.num_layers)
-        ]
+        self.layers: List[Tuple[jax.Array, jax.Array]] = []
+        for blocks, kv_heads, head_dim in layer_blocks:
+            shape = (int(blocks), int(kv_heads), self.block_size,
+                     int(head_dim))
+            self.layers.append((jnp.zeros(shape, dtype),
+                                jnp.zeros(shape, dtype)))
 
     def nbytes(self) -> int:
-        k, _ = self.layers[0]
-        return 2 * self.num_layers * k.size * k.dtype.itemsize
+        return sum(2 * k.size * k.dtype.itemsize for k, _ in self.layers)
 
     def replace(self, new_layers) -> None:
         """Swap in the page arrays a compiled step returned (the old ones
@@ -87,3 +94,25 @@ def write_prefix(k_pages, v_pages, k, v, table, *, block_size):
     return (
         k_pages.at[table].set(to_pages(k, block_size).astype(k_pages.dtype)),
         v_pages.at[table].set(to_pages(v, block_size).astype(v_pages.dtype)))
+
+
+def write_ring(k_pages, v_pages, k, v, ring, last_block, *, block_size):
+    """Scatter the END of a contiguous KV prefix into a window layer's ring.
+
+    k, v: [plen_padded, kv_heads, d]; ring: [ring_blocks] int32, the slot's
+    ring; last_block: int32, the logical block of the prompt's last token.
+    Logical blocks last_block - ring_blocks + 1 .. last_block (those that
+    exist) go to ring entries block % ring_blocks, where the decode step's
+    append and attention expect them; earlier blocks are behind every
+    window that will ever be asked for. Indexes leading dimensions only,
+    like write_prefix."""
+    n = ring.shape[0]
+    blocks = last_block - (n - 1) + jnp.arange(n, dtype=jnp.int32)
+    src = jnp.clip(blocks, 0, k.shape[0] // block_size - 1)
+    # a block before the prompt's first: to the null page
+    dst = jnp.where(blocks >= 0, ring[blocks % n], 0)
+    return (
+        k_pages.at[dst].set(
+            to_pages(k, block_size)[src].astype(k_pages.dtype)),
+        v_pages.at[dst].set(
+            to_pages(v, block_size)[src].astype(v_pages.dtype)))

@@ -158,8 +158,13 @@ class Scheduler:
     drives it: admit() between decode steps, next_prefill() for chunked
     prefill work, start_running()/finish() on transitions."""
 
-    def __init__(self, allocator, max_slots: int, max_model_len: int):
+    def __init__(self, allocator, max_slots: int, max_model_len: int,
+                 window_rings=()):
         self.allocator = allocator
+        # the window cache groups (blocks.WindowRings), one per window: a
+        # request is admitted when EVERY group can take its need, the full
+        # group its worst case and a window group one ring
+        self.window_rings = list(window_rings)
         self.max_slots = int(max_slots)
         self.max_model_len = int(max_model_len)
         self.waiting: Deque[Request] = deque()
@@ -195,7 +200,8 @@ class Scheduler:
             req = self.waiting[0]
             total = min(len(req.prompt) + req.max_new_tokens,
                         self.max_model_len)
-            if not self.allocator.can_reserve_prefix(req.prompt, total):
+            if not (self.allocator.can_reserve_prefix(req.prompt, total)
+                    and all(r.can_reserve() for r in self.window_rings)):
                 break
             self.waiting.popleft()
             req.slot = self._free_slots.pop()
@@ -211,6 +217,8 @@ class Scheduler:
             req._cow_src = cow_src
             req._reserved_blocks = new_blocks
             self._reserved_blocks += new_blocks
+            for rings in self.window_rings:
+                rings.reserve(req.request_id)
             req.state = "prefill"
             req.prefill_start = time.monotonic()
             self.prefilling.append(req)
@@ -250,7 +258,13 @@ class Scheduler:
             self._free_slots.append(req.slot)
             req.slot = None
         if req.request_id in self.allocator.sequences():
+            # a window ring saw every position whose keys were written:
+            # the prompt at the end of prefill, then a token a decode step
+            cached = 0 if req.first_token_time is None else \
+                len(req.prompt) + max(0, len(req.output_tokens) - 1)
             self.allocator.free(req.request_id)
+            for rings in self.window_rings:
+                rings.free(req.request_id, cached)
         self._reserved_blocks -= req._reserved_blocks
         req._reserved_blocks = 0
         req._ws_caches = None
@@ -272,7 +286,10 @@ class Scheduler:
                 "prefilling": len(self.prefilling),
                 "running": len(self.running),
                 "free_slots": len(self._free_slots),
-                "reserved_blocks": self._reserved_blocks}
+                "reserved_blocks": self._reserved_blocks,
+                **({"reserved_window_blocks":
+                    sum(r.used_blocks for r in self.window_rings)}
+                   if self.window_rings else {})}
 
     def _publish(self):
         _QUEUED.set(len(self.waiting))

@@ -175,7 +175,7 @@ def pool_programs(one_chip):
             pv, bv, sd((_SLOTS, W), i32), pages, tables, lens, lens, temps,
             seed)),
         "serve_scatter": (engine._scatter_jit(P, P // _BLOCK), (
-            pages, work, sd((P // _BLOCK,), i32))),
+            pages, work, row, one)),
         "serve_batched_prefill": (engine._batched_prefill_jit(S, P), (
             pv, bv, pages, sd((_SLOTS, S), i32), lens,
             sd((_SLOTS, P // _BLOCK), i32), lens, lens, tables, lens, temps,
@@ -294,3 +294,120 @@ def test_every_way_to_build_the_train_step_names_it_train_step(one_chip):
     assert "module @jit_train_step " in step.lower(ids).as_text()
     step.invalidate_executables()       # the re-traced wrapper too
     assert "module @jit_train_step " in step.lower(ids).as_text()
+
+
+# ---- Laguna-S-2.1 at the published widths: 8 K/V heads of 128, 48 query
+# ---- heads in full layers and 72 in window layers (groups of 6 and 9), a
+# ---- 512-key window, 128 experts held of width 1,024 over a hidden of 3,072
+_L_SLOTS, _L_KV, _L_WINDOW, _L_FULL_BLOCKS = 32, 8, 512, 384
+
+
+@pytest.mark.parametrize("block", [16, 128])
+@pytest.mark.parametrize("hq,window", [(48, None), (72, _L_WINDOW)],
+                         ids=["full_g6", "window_g9"])
+def test_paged_decode_at_lagunas_heads_and_window(one_chip, hq, window, block):
+    from paddle_tpu.ops.pallas.paged_attention import paged_attention
+    from paddle_tpu.serving.blocks import WindowRings
+
+    if window is None:
+        width = _L_FULL_BLOCKS * 16 // block
+        blocks = 163840 // block + 1
+    else:
+        rings = WindowRings(_L_SLOTS, window, block)
+        width, blocks = rings.ring_blocks, rings.num_blocks
+    pages = ((blocks, _L_KV, block, _D), jnp.bfloat16)
+    _compile(lambda q, k, v, bt, cl: paged_attention(q, k, v, bt, cl,
+                                                     window=window),
+             one_chip, ((_L_SLOTS, hq, _D), jnp.bfloat16), pages, pages,
+             ((_L_SLOTS, width), jnp.int32), ((_L_SLOTS,), jnp.int32))
+
+
+@pytest.mark.parametrize("hq,window", [(48, None), (72, _L_WINDOW)],
+                         ids=["full_g6", "window_g9"])
+@pytest.mark.parametrize("cached", [512, 4096])
+def test_windowed_prefill_at_lagunas_heads(one_chip, hq, window, cached):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_prefill
+
+    kv = ((1, cached, _L_KV, _D), jnp.bfloat16)
+    _compile(lambda q, k, v, off: flash_attention_prefill(
+        q, k, v, off, window=window),
+        one_chip, ((1, 512, hq, _D), jnp.bfloat16), kv, kv, ((), jnp.int32))
+
+
+@pytest.mark.parametrize("rows,tm", [(320, 32), (5120, 128)],
+                         ids=["decode_32x10", "prefill_512x10"])
+@pytest.mark.parametrize("k,n", [(3072, 2048), (1024, 3072)],
+                         ids=["gate_up", "down"])
+def test_grouped_products_at_lagunas_widths(one_chip, rows, tm, k, n):
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    compiled = _compile(lambda a, w, g: grouped_matmul(a, w, g, tm=tm),
+                        one_chip, ((rows, k), jnp.bfloat16),
+                        ((128, k, n), jnp.bfloat16), ((128,), jnp.int32))
+    assert "moe_grouped_matmul" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def laguna_pool_programs(one_chip):
+    """The engine programs that return a Laguna model's pools, both cache
+    groups at the cell's page shapes; the model's hidden size and experts
+    are small, its heads, K/V heads and window the published ones."""
+    from paddle_tpu.models import LagunaConfig, LagunaForCausalLM
+    from paddle_tpu.serving import ServingEngine
+
+    cfg = LagunaConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=512, num_layers=5,
+        num_experts=16, experts_held=(0, 8), num_experts_per_tok=4,
+        moe_intermediate_size=128, shared_expert_intermediate_size=128,
+        max_position_embeddings=8192)
+    engine = ServingEngine(LagunaForCausalLM(cfg).bfloat16(),
+                           max_slots=_L_SLOTS, block_size=_BLOCK,
+                           num_blocks=2, prefill_chunk=512,
+                           max_model_len=_L_FULL_BLOCKS * _BLOCK)
+    rings, = engine.window_rings
+    shapes = {"full": (10241, _L_KV, _BLOCK, _D),
+              "window": (rings.num_blocks, _L_KV, _BLOCK, _D)}
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32 = jnp.int32
+    pv, bv = jax.tree_util.tree_map(lambda x: sd(x.shape, x.dtype),
+                                    engine._functional()[2:])
+    pages = [(sd(shapes[l.kind], jnp.bfloat16),) * 2
+             for l in engine._spec.layers]
+    lens = sd((_L_SLOTS,), i32)
+    tables = sd((_L_SLOTS, engine._table_cols), i32)
+    counters = tuple(sd((9,), i32) for _ in engine._counter_layers)
+    P = 4096
+    work = [(sd((1, P, _L_KV, _D), jnp.bfloat16),) * 2] * cfg.num_layers
+    pool_bytes = sum(2 * 2 * n * _L_KV * _BLOCK * _D
+                     for n in (10241, 10241, rings.num_blocks,
+                               rings.num_blocks, rings.num_blocks))
+    return shapes, pool_bytes, {
+        "step": (engine._decode_jit(False), (
+            pv, bv, lens, pages, tables, lens, sd((_L_SLOTS,), jnp.float32),
+            sd((), i32), counters)),
+        "serve_scatter": (engine._scatter_jit(P, P // _BLOCK), (
+            pages, work, sd((engine._table_cols,), i32), sd((), i32))),
+    }
+
+
+@pytest.mark.parametrize("name", ["step", "serve_scatter"])
+def test_no_program_relayouts_either_cache_groups_pool(laguna_pool_programs,
+                                                       monkeypatch, name):
+    shapes, pool_bytes, programs = laguna_pool_programs
+    fn, args = programs[name]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule jit_{name},")
+    for shape in shapes.values():
+        made = re.escape("= bf16[%d,%d,%d,%d]{" % shape) \
+            + r"[^}]*\} (copy|transpose)\("
+        assert not [ln for ln in text.splitlines() if re.search(made, ln)]
+    # every layer's K and V pool is updated in the buffer it came in
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    if name == "step":
+        for kernel in ("paged_decode", "moe_grouped_matmul"):
+            assert kernel in text

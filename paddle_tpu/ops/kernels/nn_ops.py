@@ -1064,6 +1064,14 @@ def paged_cached_attention(q, k, v, k_pages, v_pages, block_table, seq_lens,
     holds the window however long its context grows. Masks go by absolute
     position; the kernel visits the window's pages alone. One query token a
     slot: a verify window over a ring is not written.
+
+    The decode kernel (sq == 1; `paged_decode`): one grid step a slot, a
+    loop over that slot's live pages read from seq_lens (an idle slot, whose
+    table is null and whose length the engine keeps at 0, costs one short
+    step), K and V copied from the pool in HBM several pages a fetch with
+    the next fetch in flight under the current products. Its time follows
+    the bytes of the live keys, not the table's width. q goes in cast to the
+    pool's dtype; the softmax and both accumulators are float32.
     """
     slots, sq, hq, d = q.shape
     if window is not None and sq != 1:
@@ -1076,7 +1084,7 @@ def paged_cached_attention(q, k, v, k_pages, v_pages, block_table, seq_lens,
     from .. import pallas as _pallas
     from ..pallas.paged_attention import (
         paged_attention_multi as _paged_multi,
-        paged_attention_tuned as _paged_kernel,
+        paged_attention as _paged_kernel,
         paged_attention_xla as _paged_xla,
         paged_attention_xla_multi as _paged_xla_multi,
         supports as _paged_supports,

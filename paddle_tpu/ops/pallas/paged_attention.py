@@ -9,14 +9,13 @@ computes one decode step of attention STRAIGHT from
 
     q            [slots, q_heads, d]        one query token per slot
     k/v_pages    [num_blocks, kv_heads, block_size, d]   (head-major: one
-                 (page, kv head) is a contiguous [block_size, d] tile, the
-                 shape the TPU lowering needs for the K/V block's last two
-                 dims)
+                 page of all K/V heads is one contiguous run of the pool)
     block_tables [slots, max_blocks]  int32 page ids per slot (0 = null)
     context_lens [slots]              int32 valid tokens incl. current
 
 without materializing contiguous per-sequence caches — the "ragged" part:
-every slot attends over its own length, fully-masked pages are skipped.
+every slot attends over its own length, and a page past it is neither
+fetched nor computed.
 
 The pool's layout is a contract with its writers, stated in one place
 (ops/kernels/nn_ops.py: paged_cached_attention's docstring) and held by
@@ -25,16 +24,33 @@ row-major; every writer (the decode step's append, the engine's scatter,
 batched prefill and copy-on-write admit) indexes leading dimensions only,
 so no program relayouts the pool around these kernels.
 
-Kernel shape: grid (slots, kv_heads, kv_splits, pages_per_split) with the
-block table + context lens as SCALAR-PREFETCH operands, so each grid step's
-BlockSpec index_map picks the next physical page to DMA (data-dependent
-paging — the whole point of scalar prefetch). Online softmax (m, l, acc)
-carried in VMEM scratch across the page loop; the kv_splits dimension is
-flash-decoding-style split-K over the context: each split reduces its page
-range to a partial (acc, m, l) and an XLA epilogue combines splits by
-logsumexp weighting. kv_splits is the block-autotuned knob (core/autotune):
-1 split minimizes combine overhead, more splits expose parallelism when
-slots*kv_heads is small relative to the context length.
+The decode kernel (`paged_decode`): ONE GRID STEP A SLOT, the block table
+and context lens as scalar-prefetch operands (SMEM), the pool left in HBM
+(memory_space ANY). Inside the step a loop over the slot's LIVE pages
+(`live_pages`: ceil(context / block) of them for a full layer, the window's
+blocks wrapped onto the slot's ring for a window layer), `pages_per_fetch`
+pages at a time: each page is one copy of K and one of V
+(pltpu.make_async_copy) into one of two VMEM buffers laid out
+[kv_heads, fetch * block_size, d], and the next fetch (after a slot's last,
+the next slot's first) is in flight under the current one's products. Both
+products are batched over the K/V heads on the MXU: q.k takes q and K in
+the pool's dtype (bf16 x bf16 products are exact in float32, the
+accumulator's type); scores, the online softmax's running max and sum, the
+probabilities and the accumulator are float32, and p.v takes V widened to
+float32. What bounds its time: the bytes of the live keys (at the serve
+cells' shapes and contexts it reads them at 0.67-0.89 of the chip's HBM
+bandwidth, PERF.md section 6, PR 31) and about a microsecond a slot of loop
+set-up, which is what an idle slot (null table, context 1) costs. The
+table's width costs nothing. How many pages a fetch is a constant of the
+shapes the kernel is traced with (block size, heads, d, dtype): some
+hundreds of KiB of K and of V in flight, at most `_FETCH_PAGES` copies. There is no split
+of a context across grid steps: a v5e has one TensorCore and the page loop
+is inside the step (the former kernel's `kv_splits` bought nothing on the
+chip: 4.66 ms a layer at 1, 2, 4 and 8).
+
+The speculative verify kernel (`paged_verify`, `_verify_kernel`) is the
+older design, a grid step a (slot, K/V head, table entry): no configuration
+of the benchmark turns it on (ROADMAP S11).
 
 GQA layout convention matches cached_multihead_attention's jnp.repeat: kv
 head h serves q heads [h*g, (h+1)*g), g = q_heads // kv_heads.
@@ -57,151 +73,207 @@ NEG_INF = -1e30
 
 
 # ------------------------------------------------------------------- kernel
-def _first_block(cl, window, block_size):
-    """Logical block of the oldest key a window layer's query still sees
-    (context cl counts the current token): keys cl - window .. cl - 1."""
-    return jnp.maximum(cl - window, 0) // block_size
+# One fetch of K (and one of V) aims at this many bytes in flight: several
+# pages of all K/V heads, each page one contiguous run of the pool.
+_FETCH_BYTES = 512 * 1024
+# ... and never at more pages than this: a page is a copy to start and to
+# wait for, and the loop over a fetch's pages is unrolled.
+_FETCH_PAGES = 8
 
 
-def _decode_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref,
-                   acc_ref, m_ref, l_ref,
-                   acc_s, m_s, l_s, *, block_size, pages_per_split, scale,
-                   window=None):
-    # scalar prefetch: bt_ref [slots, max_blocks], cl_ref [slots] (SMEM)
-    # blocks: q_ref [g, d]; k_ref/v_ref [block_size, d] (one physical page,
-    # this kv head); outputs are per-split partials.
-    # window: the grid's pages are the LOGICAL blocks from the window's
-    # first on (the index_map wraps them onto the slot's ring of blocks),
-    # and keys older than the window are masked by absolute position.
-    i = pl.program_id(0)           # slot
-    s = pl.program_id(2)           # split
-    j = pl.program_id(3)           # page within split
+def live_pages(context_lens, block_size, window=None):
+    """(first, pages): the logical blocks a slot's decode visits at context
+    `context_lens` (the current token counted): `pages` blocks from `first`
+    on. A full layer visits blocks 0 .. ceil(cl / block) - 1; a window
+    layer the blocks that hold keys cl - window .. cl - 1 (which the ring
+    holds at entries `block % ring`). The kernel's loop bounds (a traced
+    scalar) and the engine's serving_*_keys_total counters (a NumPy array
+    of contexts) are both this arithmetic."""
+    last = (context_lens - 1) // block_size
+    if window is None:
+        return last * 0, last + 1
+    first = (context_lens - window).clip(0) // block_size
+    return first, last - first + 1
 
-    @pl.when(j == 0)
-    def _init():
-        m_s[:] = jnp.full_like(m_s, NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
 
-    cl = cl_ref[i]
-    page_idx = s * pages_per_split + j
-    if window is not None:
-        page_idx = page_idx + _first_block(cl, window, block_size)
+def pages_per_fetch(kv_heads, block_size, d, itemsize, table_width):
+    """Pages of all K/V heads that one fetch brings from the pool: a
+    constant of the shapes the kernel is traced with."""
+    page_bytes = kv_heads * block_size * d * itemsize
+    return max(1, min(_FETCH_BYTES // page_bytes, _FETCH_PAGES, table_width))
 
-    @pl.when(page_idx * block_size < cl)   # ragged skip: page has live tokens
-    def _compute():
-        g = q_ref.shape[0]
-        q = q_ref[:].astype(jnp.float32) * scale
-        k = k_ref[:].astype(jnp.float32)
-        v = v_ref[:].astype(jnp.float32)
+
+def _decode_kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   k_buf, v_buf, sem, parity, *, block_size, fetch, scale,
+                   window):
+    # scalar prefetch: bt_ref [slots, width], cl_ref [slots] (SMEM)
+    # q_ref, o_ref [hkv, g, d] (this slot); k_hbm, v_hbm: the whole pool,
+    # left in HBM; k_buf, v_buf [2, hkv, fetch * block_size, d]: two
+    # buffers of `fetch` pages, head-major so that a head's keys are rows;
+    # sem [2 (K, V), 2 (buffer)]; parity [1] (SMEM): the buffer that holds
+    # this slot's first fetch, which the step before started.
+    i = pl.program_id(0)
+    width = bt_ref.shape[1]
+    bs = block_size
+    hkv, g, d = q_ref.shape
+    keys = fetch * bs
+
+    def span(slot):
+        """(context, first logical block, pages, fetches) of a slot. A
+        context is at least the current token and at most what the slot's
+        table holds, whatever the caller hands in."""
+        cl = jnp.clip(cl_ref[slot], 1, None if window is not None
+                      else width * bs)
+        first, pages = live_pages(cl, bs, window)
+        return cl, first, pages, (pages + fetch - 1) // fetch
+
+    def copies(slot, f, buf):
+        """(is the page live, its K copy, its V copy) for each page of a
+        slot's fetch f. A dead page (past the context) is neither looked
+        up in the table nor fetched; a wait needs the shapes alone."""
+        _, first, pages, _ = span(slot)
+        out = []
+        for p in range(fetch):
+            blk = f * fetch + p
+            live = blk < pages
+            entry = (first + blk) % width if window is not None else blk
+            page = bt_ref[slot, jnp.where(live, entry, 0)]
+            rows = pl.ds(p * bs, bs)
+            out.append((live, *(
+                pltpu.make_async_copy(hbm.at[page], vmem.at[buf, :, rows, :],
+                                      sem.at[j, buf])
+                for j, (hbm, vmem) in enumerate(((k_hbm, k_buf),
+                                                 (v_hbm, v_buf))))))
+        return out
+
+    def start(slot, f, buf):
+        for live, k_copy, v_copy in copies(slot, f, buf):
+            @pl.when(live)
+            def _():
+                k_copy.start()
+                v_copy.start()
+
+    @pl.when(i == 0)
+    def _():
+        parity[0] = 0
+        start(0, 0, 0)
+
+    cl, first, pages, n_fetch = span(i)
+    buf0 = parity[0]
+    q = q_ref[...]
+
+    def body(f, carry):
+        m_prev, l_prev, acc = carry
+        buf = (buf0 + f) % 2
+        last = f == n_fetch - 1
+
+        # the next fetch flies under this one's products: this slot's, or
+        # after its last the next slot's first
+        @pl.when(jnp.logical_not(last))
+        def _():
+            start(i, f + 1, 1 - buf)
+
+        @pl.when(jnp.logical_and(last, i + 1 < pl.num_programs(0)))
+        def _():
+            start(i + 1, 0, 1 - buf)
+
+        for live, k_copy, v_copy in copies(i, f, buf):
+            @pl.when(live)
+            def _():
+                k_copy.wait()
+                v_copy.wait()
+
+        base = (first + f * fetch) * bs
+
+        @pl.when(last)
+        def _():
+            # rows past the context hold what the buffer held before, or
+            # the page's stale tail. p is 0 there, and 0 * NaN is not.
+            row = base + jax.lax.broadcasted_iota(jnp.int32, (keys, d), 0)
+            v_buf[buf] = jnp.where((row < cl)[None], v_buf[buf], 0)
+
         sc = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [g, block_size]
-        pos = page_idx * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (g, block_size), 1)
+            q, k_buf[buf], (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale     # [hkv, g, keys]
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, keys), 2)
         live = pos < cl
         if window is not None:
             live = jnp.logical_and(live, pos >= cl - window)
         sc = jnp.where(live, sc, NEG_INF)
-        m_prev = m_s[:]                       # [g, 1]
-        l_prev = l_s[:]
-        m_cur = jnp.max(sc, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=2, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(live, jnp.exp(sc - m_new), 0.0)
-        m_s[:] = m_new
-        l_s[:] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_s[:] = acc_s[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        # every fetch holds a live key, so m_new is finite and a masked
+        # score's exp is exactly 0
+        p = jnp.exp(sc - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
+        acc = acc * alpha + jax.lax.dot_general(
+            p, v_buf[buf].astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)              # [hkv, g, d]
+        return m_new, l_new, acc
 
-    @pl.when(j == pages_per_split - 1)
-    def _out():
-        acc_ref[:] = acc_s[:]
-        m_ref[:] = m_s[:]
-        l_ref[:] = l_s[:]
+    _, l, acc = jax.lax.fori_loop(
+        0, n_fetch, body,
+        (jnp.full((hkv, g, 1), NEG_INF, jnp.float32),
+         jnp.zeros((hkv, g, 1), jnp.float32),
+         jnp.zeros((hkv, g, d), jnp.float32)))
+    parity[0] = (buf0 + n_fetch) % 2
+    o_ref[...] = (acc / l).astype(o_ref.dtype)
 
 
 def window_pages(window, block_size, table_width):
-    """Pages a window layer's decode visits a slot: the window's blocks and
-    one more for a window that starts inside a block, never more than the
-    slot's ring holds."""
+    """The most pages a window layer's decode visits a slot: the window's
+    blocks and one more for a window that starts inside a block, never more
+    than the slot's ring holds."""
     return min(table_width, -(-window // block_size) + 1)
 
 
-def _paged_pallas(q, k_pages, v_pages, block_tables, context_lens, scale,
-                  kv_splits, interpret, window=None):
+# ------------------------------------------------------- the decode entry
+# jitted so that a model's layers, which call it with the same shapes, are
+# traced and lowered once a program: tracing the kernel is a few tenths of a
+# second, 24 of which were set-up time of every serve run
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "window"))
+def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
+                    scale=None, interpret=False, window=None):
+    """One decode step of ragged paged attention (see module docstring).
+    q: [slots, q_heads, d]; returns [slots, q_heads, d]. `window`: a window
+    layer, whose table row is the slot's ring of blocks."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
     slots, hq, d = q.shape
-    hkv = k_pages.shape[1]
-    bs = k_pages.shape[2]
+    _, hkv, bs, _ = k_pages.shape
     g = hq // hkv
-    max_bps = block_tables.shape[1]
-    if window is None:
-        pad = (-max_bps) % kv_splits
-        if pad:
-            # padded entries point at the null page; context_lens masks them
-            block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
-        nps = (max_bps + pad) // kv_splits
-
-        def page_of(i, s, j, bt, cl):
-            return bt[i, s * nps + j]
-    else:
-        # the table is a ring: logical block b lives in entry b % max_bps
-        nps = -(-window_pages(window, bs, max_bps) // kv_splits)
-
-        def page_of(i, s, j, bt, cl):
-            return bt[i, (_first_block(cl[i], window, bs) + s * nps + j)
-                      % max_bps]
-    qr = q.reshape(slots, hkv, g, d)
-    bt = block_tables.astype(jnp.int32)
-    cl = context_lens.astype(jnp.int32)
-
+    width = block_tables.shape[1]
+    fetch = pages_per_fetch(hkv, bs, d, k_pages.dtype.itemsize,
+                            width if window is None
+                            else window_pages(window, bs, width))
+    # the products take their operands in the pool's dtype
+    qr = q.reshape(slots, hkv, g, d).astype(k_pages.dtype)
+    slot_block = pl.BlockSpec((None, hkv, g, d),
+                              lambda i, bt, cl: (i, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(slots, hkv, kv_splits, nps),
-        in_specs=[
-            pl.BlockSpec((None, None, g, d),
-                         lambda i, h, s, j, bt, cl: (i, h, 0, 0)),
-            pl.BlockSpec((None, None, bs, d),
-                         lambda i, h, s, j, bt, cl:
-                         (page_of(i, s, j, bt, cl), h, 0, 0)),
-            pl.BlockSpec((None, None, bs, d),
-                         lambda i, h, s, j, bt, cl:
-                         (page_of(i, s, j, bt, cl), h, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, None, None, g, d),
-                         lambda i, h, s, j, bt, cl: (i, h, s, 0, 0)),
-            pl.BlockSpec((None, None, None, g, 1),
-                         lambda i, h, s, j, bt, cl: (i, h, s, 0, 0)),
-            pl.BlockSpec((None, None, None, g, 1),
-                         lambda i, h, s, j, bt, cl: (i, h, s, 0, 0)),
-        ],
+        grid=(slots,),
+        in_specs=[slot_block,
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=slot_block,
         scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((2, hkv, fetch * bs, d), k_pages.dtype),
+            pltpu.VMEM((2, hkv, fetch * bs, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
-    acc, m, l = pl.pallas_call(
-        functools.partial(_decode_kernel, block_size=bs,
-                          pages_per_split=nps, scale=scale, window=window),
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, block_size=bs, fetch=fetch,
+                          scale=scale, window=window),
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((slots, hkv, kv_splits, g, d), jnp.float32),
-            jax.ShapeDtypeStruct((slots, hkv, kv_splits, g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((slots, hkv, kv_splits, g, 1), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((slots, hkv, g, d), q.dtype),
         name="paged_decode",
         interpret=interpret,
-    )(bt, cl, qr, k_pages, v_pages)
-
-    # flash-decoding combine: logsumexp-weight the per-split partials
-    m_g = jnp.max(m, axis=2, keepdims=True)
-    w = jnp.exp(m - m_g)                       # empty splits -> weight 0
-    num = jnp.sum(acc * w, axis=2)             # [slots, hkv, g, d]
-    den = jnp.maximum(jnp.sum(l * w, axis=2), 1e-30)
-    return (num / den).astype(q.dtype).reshape(slots, hq, d)
+    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32), qr,
+      k_pages, v_pages)
+    return out.reshape(slots, hq, d)
 
 
 # -------------------------------------------------- multi-query (verify)
@@ -420,43 +492,9 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
     return out.astype(q.dtype).reshape(slots, hq, d)
 
 
-# ---------------------------------------------------------------- public API
-def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
-                    scale=None, kv_splits=1, interpret=False, window=None):
-    """One decode step of ragged paged attention (see module docstring).
-    q: [slots, q_heads, d]; returns [slots, q_heads, d]. `window`: a window
-    layer, whose table row is the slot's ring of blocks."""
-    if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
-    return _paged_pallas(q, k_pages, v_pages, block_tables, context_lens,
-                         scale, kv_splits, interpret, window)
-
-
+# --------------------------------------------------------------- shape gate
 def supports(q_shape, k_pages_shape) -> bool:
     """Shape gate for the kernel path (XLA fallback otherwise)."""
     slots, hq, d = q_shape
     hkv = k_pages_shape[1]
     return d <= 256 and hkv >= 1 and hq % hkv == 0
-
-
-# ---- autotuned entry (split-K over the context is the tunable block) ----
-from ...core.autotune import autotune as _autotune  # noqa: E402
-
-_SPLIT_CANDIDATES = [
-    {"kv_splits": 1},   # default 1st: no combine overhead
-    {"kv_splits": 2},
-    {"kv_splits": 4},
-    {"kv_splits": 8},
-]
-
-
-@_autotune(_SPLIT_CANDIDATES)
-def paged_attention_tuned(q, k_pages, v_pages, block_tables, context_lens,
-                          scale=None, interpret=False, window=None, *,
-                          kv_splits):
-    """paged_attention with the flash-decoding split count chosen by the
-    autotune cache when FLAGS_use_autotune is on; otherwise 1 split."""
-    if block_tables.shape[1] < kv_splits:
-        raise ValueError("more splits than pages")  # tuner skips
-    return paged_attention(q, k_pages, v_pages, block_tables, context_lens,
-                           scale, kv_splits, interpret, window)

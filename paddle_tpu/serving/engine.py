@@ -63,7 +63,11 @@ from .observability import (
     ServingObservability,
     new_engine_id,
 )
-from ..ops.pallas.paged_attention import from_pages, to_pages
+from ..ops.pallas.paged_attention import (
+    from_pages,
+    live_pages,
+    to_pages,
+)
 from .paged import PagedKVPool, PagedLayerCache, write_prefix, write_ring
 from .scheduler import Request, Scheduler
 from .speculative import NgramDrafter, SpecState
@@ -153,6 +157,20 @@ _WINDOW_KEYS = _counter(
     "pages the kernel visits, in tokens) beside the keys of the same "
     "contexts (`context`: what a full layer would fetch), a layer a slot.",
     labelnames=("kind",), always=True)
+
+
+_PAGED_KEYS = _counter(
+    "serving_paged_keys_total",
+    "Keys of full layers a decode step's attention fetched (`fetched`: the "
+    "pages the kernel visits in every slot, idle ones too, in tokens) "
+    "beside the keys of the running contexts (`live`), a layer a slot.",
+    labelnames=("kind",), always=True)
+
+
+def _holds_blocks(block_tables):
+    """[slots] 1 where a slot's table row names a block, 0 for an idle
+    slot (a null row)."""
+    return jnp.any(block_tables != 0, axis=1).astype(jnp.int32)
 
 
 class QueueFullError(RuntimeError):
@@ -252,6 +270,12 @@ class ServingEngine:
             (r, cols[r.window],
              sum(1 for l in spec.layers if l.window == r.window))
             for r in self.window_rings]
+        # (counter, its kinds, window, layers) by cache group, for the tick
+        self._key_counters = [
+            (_PAGED_KEYS, ("fetched", "live"), None,
+             sum(1 for l in spec.layers if l.kind != "window"))] + [
+            (_WINDOW_KEYS, ("read", "context"), r.window, n)
+            for r, _, n in self._ring_cols]
         if windows:
             # what the ring does not serve yet refuses here, by name
             prefix_cache = self._refuse_over_windows(
@@ -497,9 +521,10 @@ class ServingEngine:
                     else:
                         nxt = greedy
                 # sl/seed advance on device so steady-state ticks feed these
-                # outputs straight back in (idle slots drift harmlessly —
-                # they re-upload when the slot is next filled)
-                return nxt, new_pages, sl + 1, seed + 1, counters
+                # outputs straight back in. An idle slot (a null table row)
+                # stays at length 0: the paged kernel fetches by context
+                return nxt, new_pages, sl + _holds_blocks(bt), seed + 1, \
+                    counters
 
             return jax.jit(step, donate_argnums=(3, 5, 7, 8))
 
@@ -525,7 +550,8 @@ class ServingEngine:
                     with jax.named_scope("sample"):
                         lg = logits[:, -1, :].astype(jnp.float32)
                         nxt = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-                    return nxt, new_pages, sl + 1, out.at[i].set(nxt)
+                    return (nxt, new_pages, sl + _holds_blocks(bt),
+                            out.at[i].set(nxt))
 
                 out0 = jnp.zeros((k, tok.shape[0]), jnp.int32)
                 tok, pages, sl, out = jax.lax.fori_loop(
@@ -1356,8 +1382,7 @@ class ServingEngine:
             # matter — a request with an eos_token_id (checked every
             # token), or one whose count reached its length cap this tick.
             self._pending.append((toks, items))
-        if self.window_rings:
-            self._count_window_keys([slot for slot, _ in running])
+        self._count_keys([slot for slot, _ in running], k)
         flush = False
         for slot, req in running:
             req._pending_n += k
@@ -1371,17 +1396,19 @@ class ServingEngine:
             self._flush_pending()
         return len(running) * k
 
-    def _count_window_keys(self, slots) -> None:
-        """serving_window_keys_total for one decode step: the pages of each
-        window layer's ring that the kernel visits (the blocks from the
-        window's first key to the current token), in tokens, beside the
-        context a full layer would read."""
-        ctx = self._lens[slots].astype(np.int64) + 1
+    def _count_keys(self, running, k) -> None:
+        """serving_paged_keys_total and serving_window_keys_total for one
+        decode dispatch of k steps: the pages the kernel fetches (by its own
+        arithmetic, `live_pages`, over every slot: an idle one is handed
+        context 1 and fetches a page), in tokens, beside the running
+        contexts' keys, which a full layer has to read."""
         bs = self.block_size
-        for rings, _, layers in self._ring_cols:
-            pages = (ctx - 1) // bs - np.maximum(ctx - rings.window, 0) // bs + 1
-            _WINDOW_KEYS.inc(int(pages.sum()) * bs * layers, kind="read")
-            _WINDOW_KEYS.inc(int(ctx.sum()) * layers, kind="context")
+        ctx = self._lens.astype(np.int64)[:, None] + 1 + np.arange(k)
+        live = int(ctx[running].sum())
+        for counter, kinds, window, layers in self._key_counters:
+            _, pages = live_pages(ctx, bs, window)
+            counter.inc(int(pages.sum()) * bs * layers, kind=kinds[0])
+            counter.inc(live * layers, kind=kinds[1])
 
     def layer_counters(self) -> dict:
         """{layer index: counters} fetched from the device (one transfer),

@@ -275,7 +275,8 @@ def test_prefill_kernel_skips_outside_the_band_and_matches(offset, window):
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
-@pytest.mark.parametrize("lens", [[3, 30, 77], [8, 24, 25], [1, 40, 41]])
+@pytest.mark.parametrize("lens", [[3, 30, 77], [8, 24, 25], [1, 40, 41],
+                                  [36, 47, 84]])
 def test_paged_decode_over_a_ring_sees_the_window_only(kernel, lens):
     from paddle_tpu.ops.pallas import paged_attention as pa
 
@@ -284,6 +285,14 @@ def test_paged_decode_over_a_ring_sees_the_window_only(kernel, lens):
     rings = WindowRings(slots, window, bs)
     ring = rings.ring_blocks
     assert ring == 4
+    # one fetch of the kernel brings the whole ring, so a window whose
+    # blocks pass the ring's last entry wraps inside a fetch: the last case
+    # is made of those (first entry + pages > ring, in every slot)
+    assert pa.pages_per_fetch(hkv, bs, d, 4, pa.window_pages(
+        window, bs, ring)) == ring
+    first, pages = pa.live_pages(np.asarray(lens), bs, window)
+    if lens[0] == 36:
+        assert ((first % ring + pages) > ring).all()
     tables = np.asarray([rings.reserve(i) for i in range(slots)], np.int32)
     kp = np.zeros((rings.num_blocks, hkv, bs, d), np.float32)
     vp = np.zeros_like(kp)

@@ -226,16 +226,96 @@ class TestPagedAttentionNumerics:
         want = _dense_oracle(q, kp, vp, bt, cl, scale)
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
-    @pytest.mark.parametrize("kv_splits", [1, 3])
-    def test_kernel_interpret_matches_oracle(self, kv_splits):
+    def test_kernel_interpret_matches_oracle(self):
         from paddle_tpu.ops.pallas.paged_attention import paged_attention
 
-        q, kp, vp, bt, cl = _make_case(seed=kv_splits)
+        q, kp, vp, bt, cl = _make_case(seed=1)
         scale = 1.0 / np.sqrt(q.shape[-1])
-        got = np.asarray(paged_attention(q, kp, vp, bt, cl,
-                                         kv_splits=kv_splits, interpret=True))
+        got = np.asarray(paged_attention(q, kp, vp, bt, cl, interpret=True))
         want = _dense_oracle(q, kp, vp, bt, cl, scale)
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+    # the three serve cells' head shapes at small sizes: GPT-3 XL's MHA
+    # (g = 1), Laguna's full layers (g = 6) and window layers' group (g = 9)
+    @pytest.mark.parametrize("contexts", ["edges", "wide_table"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("bs", [16, 128])
+    @pytest.mark.parametrize("hq,hkv", [(4, 4), (12, 2), (18, 2)],
+                             ids=["g1", "g6", "g9"])
+    def test_kernel_follows_each_slots_live_pages(self, hq, hkv, bs, dtype,
+                                                  contexts):
+        """The kernel loops over a slot's live pages, several a fetch:
+        contexts of 1, a block's edge and one past it, one that ends inside
+        a multi-page fetch, a full table, an idle slot (null table) between
+        two live ones; and a table wider than any context."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops.pallas import paged_attention as pa
+
+        d = 128
+        fetch_keys = bs * pa.pages_per_fetch(
+            hkv, bs, d, jnp.dtype(dtype).itemsize, 1 << 30)
+        if contexts == "edges":
+            width = 2 * fetch_keys // bs + 1           # two fetches and a page
+            lens = [1, bs, 0, bs + 1, fetch_keys + bs + 3, width * bs]
+        else:
+            width = 4 * fetch_keys // bs
+            lens = [fetch_keys // 2 + 1, 0, fetch_keys + 1, 2 * bs - 1]
+        assert width * bs > fetch_keys > bs, "no multi-page fetch to test"
+        slots = len(lens)
+        rng = np.random.default_rng(hq * 1000 + bs + len(dtype))
+        nb = 1 + slots * width
+        q = jnp.asarray(rng.standard_normal((slots, hq, d)), dtype)
+        kp = jnp.asarray(rng.standard_normal((nb, hkv, bs, d)), dtype)
+        vp = jnp.asarray(rng.standard_normal((nb, hkv, bs, d)), dtype)
+        bt = rng.permutation(np.arange(1, nb, dtype=np.int32)).reshape(
+            slots, width)
+        idle = [i for i, n in enumerate(lens) if n == 0]
+        bt[idle] = 0                     # the engine hands an idle slot a
+        cl = np.maximum(lens, 1).astype(np.int32)   # null row and context 1
+        got = np.asarray(pa.paged_attention(q, kp, vp, bt, cl,
+                                            interpret=True), np.float32)
+        want = _dense_oracle(np.asarray(q, np.float32),
+                             np.asarray(kp, np.float32),
+                             np.asarray(vp, np.float32), bt, cl,
+                             1.0 / np.sqrt(d))
+        # float32: the oracle's own; a bf16 pool: the output's rounding
+        tol = 1e-5 if dtype == "float32" else 2e-2
+        live = [i for i in range(slots) if i not in idle]
+        np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
+        assert np.isfinite(got).all()
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_kernel_reads_nothing_past_a_context(self, dtype):
+        """NaN in every page past each context and in every row past it in
+        its last page: nothing dead reaches the output."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops.pallas.paged_attention import paged_attention
+
+        slots, hq, hkv, d, bs, width = 4, 8, 4, 128, 16, 40
+        lens = np.array([1, bs + 5, 300, 2 * bs], np.int32)
+        rng = np.random.default_rng(5)
+        nb = 1 + slots * width
+        q = jnp.asarray(rng.standard_normal((slots, hq, d)), dtype)
+        kp = rng.standard_normal((nb, hkv, bs, d)).astype(np.float32)
+        vp = rng.standard_normal((nb, hkv, bs, d)).astype(np.float32)
+        bt = rng.permutation(np.arange(1, nb, dtype=np.int32)).reshape(
+            slots, width)
+        kp_bad, vp_bad = kp.copy(), vp.copy()
+        for s in range(slots):
+            full, rest = divmod(int(lens[s]), bs)
+            for pool in (kp_bad, vp_bad):
+                pool[bt[s, full + (rest > 0):]] = np.nan
+                if rest:
+                    pool[bt[s, full], :, rest:] = np.nan
+        clean = np.asarray(paged_attention(
+            q, jnp.asarray(kp, dtype), jnp.asarray(vp, dtype), bt, lens,
+            interpret=True), np.float32)
+        got = np.asarray(paged_attention(
+            q, jnp.asarray(kp_bad, dtype), jnp.asarray(vp_bad, dtype), bt,
+            lens, interpret=True), np.float32)
+        np.testing.assert_array_equal(got, clean)
 
     def test_gqa_head_mapping(self):
         # hq=6 over hkv=3: kv head h must serve exactly q heads [2h, 2h+1]
@@ -506,6 +586,53 @@ class TestServingEngine:
         out4 = eng4.generate(prompts, max_new_tokens=6)
         assert out1 == out4
         assert all(len(o) == len(p) + 6 for o, p in zip(out4, prompts))
+
+    @pytest.mark.parametrize("fuse_steps", [1, 4])
+    def test_decode_hands_the_kernel_live_contexts_and_counts_them(
+            self, fuse_steps):
+        """The paged kernel fetches by context, so an idle slot must stay at
+        context 1 on the device (it used to drift by one a tick), the host's
+        lengths are the device's, and serving_paged_keys_total is the
+        kernel's own page arithmetic over them."""
+        from paddle_tpu.observability.registry import default_registry
+        from paddle_tpu.ops.pallas.paged_attention import live_pages
+
+        cfg, m = _tiny_gpt()
+        bs = 16
+        eng = ServingEngine(m, max_slots=3, block_size=bs, prefill_chunk=16)
+        eng.fuse_steps = fuse_steps
+        keys = default_registry().get("serving_paged_keys_total")
+        before = {k: keys.value(kind=k) for k in ("fetched", "live")}
+        rng = np.random.default_rng(3)
+        eng.submit(list(rng.integers(0, cfg.vocab_size, 21)),
+                   max_new_tokens=30)
+        handed, decode_step = [], eng._decode_step
+
+        def recording():
+            handed.append((eng._lens.copy(), list(eng.sched.running)))
+            return decode_step()
+
+        eng._decode_step = recording
+        while eng.sched.has_work():
+            eng.step()
+            if eng._dev is not None:
+                np.testing.assert_array_equal(np.asarray(eng._dev[2]),
+                                              eng._lens)
+        fetched = live = 0
+        for lens, running in handed:
+            assert len(running) == 1
+            for step in range(fuse_steps):
+                ctx = lens.astype(np.int64) + 1 + step
+                fetched += int(live_pages(ctx, bs)[1].sum()) * bs
+                live += int(ctx[running].sum())
+        assert live > 0 and (eng._lens == 0).all()
+        layers = cfg.num_layers
+        assert keys.value(kind="live") - before["live"] == live * layers
+        assert (keys.value(kind="fetched") - before["fetched"]
+                == fetched * layers)
+        # one running slot of three: a last page rounded up, and a page for
+        # each idle slot, not the table's width
+        assert live < fetched <= live + 30 * 3 * bs
 
     def test_eos_stops_early_and_reports_reason(self):
         cfg, m = _tiny_gpt()

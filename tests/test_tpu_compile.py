@@ -77,7 +77,7 @@ _SLOTS, _BLOCK, _MAX_BLOCKS, _D = 8, 16, 128, 128
 
 
 def _paged_shapes(hq, hkv, dtype, sq=None):
-    pages = ((_SLOTS * _MAX_BLOCKS + 1, hkv, _BLOCK, _D), dtype)
+    pages = ((_NUM_BLOCKS, hkv, _BLOCK, _D), dtype)     # the serve cells' pool
     q = ((_SLOTS, hq, _D) if sq is None else (_SLOTS, sq, hq, _D), dtype)
     return (q, pages, pages, ((_SLOTS, _MAX_BLOCKS), jnp.int32),
             ((_SLOTS,), jnp.int32))
@@ -86,13 +86,31 @@ def _paged_shapes(hq, hkv, dtype, sq=None):
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "fp32"])
 @pytest.mark.parametrize("hq,hkv", [(16, 16), (32, 8)], ids=["mha", "gqa"])
-@pytest.mark.parametrize("kv_splits", [1, 4])
-def test_paged_attention_decode(one_chip, hq, hkv, dtype, kv_splits):
+def test_paged_attention_decode(one_chip, hq, hkv, dtype):
     from paddle_tpu.ops.pallas.paged_attention import paged_attention
 
-    _compile(lambda q, k, v, bt, cl: paged_attention(
-        q, k, v, bt, cl, kv_splits=kv_splits),
-        one_chip, *_paged_shapes(hq, hkv, dtype))
+    shapes = _paged_shapes(hq, hkv, dtype)       # mha-bf16: the GPT cells'
+    _reads_the_pool_where_it_lies(
+        _compile(paged_attention, one_chip, *shapes), *shapes[1])
+
+
+def _reads_the_pool_where_it_lies(compiled, pool, dtype):
+    """The decode kernel is in the program under its name, takes the pool as
+    it lies in HBM (no copy, no transpose of it), and its scoped VMEM (the
+    two buffers of K and of V and what the compiler adds) fits without a
+    raised limit."""
+    import json
+
+    text = compiled.as_text()
+    assert not _pool_relayouts(text, pool, dtype)
+    call, = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert "%paged_decode" in call
+    config = json.loads(re.search(r"backend_config=(\{.*\})\s*$",
+                                  call).group(1))
+    assert config["scoped_memory_configs"] == []
+    used = sum(int(c["size"]) for c in config["used_scoped_memory_configs"])
+    assert 0 < used < 8 * 2 ** 20
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
@@ -114,9 +132,11 @@ _POOL = (_NUM_BLOCKS, _KV_HEADS, _BLOCK, _D)
 _POOL_BYTES = 2 * _NUM_BLOCKS * _KV_HEADS * _BLOCK * _D
 
 
-def _pool_relayouts(text):
+def _pool_relayouts(text, pool=None, dtype=jnp.bfloat16):
     """The optimized HLO's instructions that copy or transpose a whole pool."""
-    made = re.escape("= bf16[%d,%d,%d,%d]{" % _POOL) + r"[^}]*\} (copy|transpose)\("
+    name = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
+    made = (re.escape("= %s[%d,%d,%d,%d]{" % (name, *(pool or _POOL)))
+            + r"[^}]*\} (copy|transpose)\(")
     return [ln.strip()[:200] for ln in text.splitlines() if re.search(made, ln)]
 
 
@@ -278,7 +298,10 @@ def test_decode_program_is_jit_step_with_a_named_kernel(pool_programs,
     text = fn.lower(*args).compile().as_text()
     assert text.startswith("HloModule jit_step,")
     assert "%paged_decode." in text and "tpu_custom_call" in text
-    assert 'op_name="jit(step)/h1/attn/paged_decode' in text
+    # the kernel's entry is jitted (a program traces it once for all its
+    # layers); each call keeps its layer's scope
+    assert ('op_name="jit(step)/h1/attn/jit(paged_attention)/paged_decode'
+            in text)
     for scope in ("embed", "h0/attn/kv_append", "h1/mlp", "final_norm",
                   "lm_head", "sample"):
         assert f'op_name="jit(step)/{scope}/' in text, scope
@@ -316,10 +339,13 @@ def test_paged_decode_at_lagunas_heads_and_window(one_chip, hq, window, block):
         rings = WindowRings(_L_SLOTS, window, block)
         width, blocks = rings.ring_blocks, rings.num_blocks
     pages = ((blocks, _L_KV, block, _D), jnp.bfloat16)
-    _compile(lambda q, k, v, bt, cl: paged_attention(q, k, v, bt, cl,
-                                                     window=window),
-             one_chip, ((_L_SLOTS, hq, _D), jnp.bfloat16), pages, pages,
-             ((_L_SLOTS, width), jnp.int32), ((_L_SLOTS,), jnp.int32))
+    # at block 128 these are the codegen cell's tables and pools: 48 wide
+    # over 1,281 blocks, and rings of 5 over 161
+    _reads_the_pool_where_it_lies(_compile(
+        lambda q, k, v, bt, cl: paged_attention(q, k, v, bt, cl,
+                                                window=window),
+        one_chip, ((_L_SLOTS, hq, _D), jnp.bfloat16), pages, pages,
+        ((_L_SLOTS, width), jnp.int32), ((_L_SLOTS,), jnp.int32)), *pages)
 
 
 @pytest.mark.parametrize("hq,window", [(48, None), (72, _L_WINDOW)],

@@ -115,7 +115,7 @@ def _require(cond, msg):
 
 # ------------------------------------------------------------------- train
 def _gpt_train_step(sz, seed, mesh=None):
-    """The model, optimizer and TrainStep exactly as bench.py builds them."""
+    """The 355M model, its optimizer and its TrainStep."""
     import numpy as np
 
     import paddle_tpu as paddle

@@ -1,11 +1,10 @@
-"""Lintable model-zoo presets for the CLI and lintbench.
+"""Lintable model-zoo presets for the CLI (`python -m paddle_tpu.analysis`).
 
 Each preset builds a tiny-config model-zoo model + optimizer + TrainStep and
 returns lint targets: (label, thunk -> Report). Everything here is
 trace-only — no device execution — so linting the zoo takes seconds under
 JAX_PLATFORMS=cpu. These presets are the negative corpus: the acceptance
-bar is ZERO findings on all of them, and tools/lintbench.py enforces that
-against a checked-in baseline.
+bar is ZERO findings on all of them (tests/test_static_analysis.py).
 """
 from __future__ import annotations
 

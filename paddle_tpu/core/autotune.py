@@ -28,8 +28,6 @@ from ..observability.registry import counter as _obs_counter
 
 flags.define_flag("use_autotune", False,
                   "Time candidate kernel configs on first use and cache the winner.")
-flags.define_flag("autotune_cache_size", 512,
-                  "Max cached autotune decisions (LRU eviction).")
 flags.define_flag(
     "autotune_cache_dir", "",
     "Directory for the persistent autotune cache. Empty = in-memory only. "
@@ -37,6 +35,7 @@ flags.define_flag(
     "process restarts, so a warm start skips candidate timing entirely.")
 
 _CACHE: "OrderedDict[tuple, dict]" = OrderedDict()
+_CACHE_SIZE = 512   # max cached autotune decisions (LRU eviction)
 _LOCK = threading.Lock()
 
 # persistent layer: key-string -> winner config, lazily loaded per cache dir
@@ -241,8 +240,7 @@ def autotune(candidates: Iterable[dict], key_extra: Callable = None):
                 _STATS["tunes"] += 1
                 _CACHE[key] = best
                 _CACHE.move_to_end(key)
-                limit = flags.get_flag("autotune_cache_size")
-                while limit > 0 and len(_CACHE) > limit:
+                while len(_CACHE) > _CACHE_SIZE:
                     _CACHE.popitem(last=False)
                     _STATS["evictions"] += 1
                 if cache_dir:

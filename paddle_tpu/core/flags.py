@@ -81,10 +81,6 @@ def _sync_debug_nans(on):
 define_flag("check_nan_inf", False,
             "Check op outputs for NaN/Inf — eager per-op AND inside compiled "
             "programs (jax_debug_nans).", on_change=_sync_debug_nans)
-define_flag("eager_op_jit", True, "Compile+cache single-op programs in eager mode.")
-define_flag("low_precision_op_list", False, "Record ops executed in low precision.")
-define_flag("benchmark", False, "Synchronize after every op (timing mode).")
-define_flag("use_donated_buffers", True, "Donate param/opt-state buffers in compiled steps.")
 define_flag("default_seed", 0, "Global RNG seed when none set explicitly.")
 define_flag(
     "use_flash_attention", True,

@@ -28,7 +28,7 @@ thread-rank data-parallel training: each member publishes its shard's
 gradients + metadata per step, collects everyone else's, and a collection
 timeout names exactly which members never arrived (`PeerLostError`) so the
 trainer can distinguish "rank 2 is dead, reform" from "the network is
-slow". Works identically over InProcStore (tests, faultbench) and a native
+slow". Works identically over InProcStore (tests) and a native
 TCPStore (real multi-host).
 
 resilience/elastic.py builds the training loop (mesh reformation,
@@ -45,20 +45,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..core.flags import define_flag, get_flag
 from ..observability.registry import counter as _counter
-
-define_flag("elastic", False,
-            "Enable elastic training: heartbeat/lease liveness on the "
-            "process-group store and mesh reformation at N-1 on rank loss "
-            "(resilience/elastic.py ElasticTrainer).")
-define_flag("elastic_heartbeat_s", 0.25,
-            "Interval between heartbeat-key rewrites for elastic "
-            "membership leases.")
-define_flag("elastic_lease_ttl_s", 1.5,
-            "Lease TTL: a member whose heartbeat key is older than this "
-            "is presumed dead and reformed out of the membership view. "
-            "Keep well above elastic_heartbeat_s (>= 4x).")
 
 _REFORMS = _counter("elastic_membership_changes_total",
                     "Membership views adopted, by kind of change.",
@@ -142,27 +129,26 @@ class MembershipView:
 class ElasticMembership:
     """One member's handle on the shared membership protocol.
 
-    `clock` is injectable so lease-expiry unit tests don't sleep. The
-    background heartbeat thread ONLY heartbeats; view adoption happens in
-    `poll()` on the caller's thread (the training loop), so the view never
-    changes under a step's feet.
+    `heartbeat_s` is the interval between heartbeat-key rewrites; a member
+    whose heartbeat key is older than `lease_ttl_s` is presumed dead and
+    reformed out of the membership view (keep it well above heartbeat_s,
+    >= 4x). `clock` is injectable so lease-expiry unit tests don't sleep.
+    The background heartbeat thread ONLY heartbeats; view adoption happens
+    in `poll()` on the caller's thread (the training loop), so the view
+    never changes under a step's feet.
     """
 
     def __init__(self, store, member_id: int,
                  members: Sequence[int], *,
-                 lease_ttl_s: Optional[float] = None,
-                 heartbeat_s: Optional[float] = None,
+                 lease_ttl_s: float = 1.5,
+                 heartbeat_s: float = 0.25,
                  prefix: str = "/pt/elastic",
                  clock: Callable[[], float] = time.monotonic):
         self.store = store
         self.member_id = int(member_id)
         self.prefix = str(prefix).rstrip("/")
-        self.lease_ttl_s = float(
-            lease_ttl_s if lease_ttl_s is not None
-            else get_flag("elastic_lease_ttl_s"))
-        self.heartbeat_s = float(
-            heartbeat_s if heartbeat_s is not None
-            else get_flag("elastic_heartbeat_s"))
+        self.lease_ttl_s = float(lease_ttl_s)
+        self.heartbeat_s = float(heartbeat_s)
         self._clock = clock
         # observer-side lease state (same scheme as ReplicaRegistry):
         # heartbeat values are opaque change tokens aged on THIS member's
@@ -249,8 +235,8 @@ class ElasticMembership:
 
     def stop(self) -> None:
         """Stop heartbeating WITHOUT a left marker — from the outside this
-        is indistinguishable from a crash (faultbench's rank-kill uses it;
-        graceful departure is leave())."""
+        is indistinguishable from a crash (chaos.kill_rank uses it; graceful
+        departure is leave())."""
         self._stop.set()
         t, self._thread = self._thread, None
         if t is not None:

@@ -142,7 +142,7 @@ def dp_train_step(model, loss_fn, optimizer, strategy=None, mesh=None,
     coalesced pmean buckets that XLA overlaps with the remaining backward
     (distributed/grad_buckets.py); otherwise one coalesced all-reduce runs
     after the full backward (still the explicit shard_map path, so the two
-    are directly comparable — tools/stepbench.py does exactly that).
+    are directly comparable).
     ``dp_comm_configs['overlap']`` picks the reduction schedule: 'bucketed'
     (per-bucket pmean) or 'fine' (decomposed ring reduce interleaved with
     the backward, distributed/overlap.py); None follows FLAGS_dp_overlap.

@@ -176,9 +176,9 @@ class MoELayer(Layer):
         if mode == "auto":
             # dense dispatch burns T*E*C*M ~ cf*k*T^2*M flops in the routing
             # einsums (quadratic in tokens); the scatter/gather path is
-            # O(k*T*M) memory-bound. tools/moebench.py measures the
-            # crossover — dense only wins for small token counts / few
-            # experts where the einsum stays on the MXU's fast path.
+            # O(k*T*M) memory-bound. Dense only wins for small token
+            # counts / few experts where the einsum stays on the MXU's
+            # fast path (the crossover is not measured on the chip).
             mode = "sparse" if (tokens * self.num_experts >= 1 << 15
                                 or self.num_experts >= 16) else "dense"
 

@@ -1,4 +1,4 @@
-"""BERT family (BASELINE configs[2]: BERT-base pretrain, DP + fused attention).
+"""BERT family (BERT-base pretrain, DP + fused attention).
 
 Reference analog: the fleet BERT payloads and fused_attention/
 fused_feedforward ops (paddle/fluid/operators/fused/fused_attention_op.cu) —

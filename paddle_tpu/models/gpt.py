@@ -1,4 +1,4 @@
-"""GPT family — the flagship model (BASELINE configs[3]: GPT-3 1.3B hybrid).
+"""GPT family — the flagship model (GPT-3 1.3B hybrid-parallel).
 
 Reference model zoo analog: the fleetx/gpt models used by Fleet hybrid
 examples (hybrid_parallel_pp_amp.py payloads, fused_multi_transformer ops in

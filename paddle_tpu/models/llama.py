@@ -1,4 +1,4 @@
-"""LLaMA family (BASELINE configs[3-4]: LLaMA-2 70B-class sharding-3).
+"""LLaMA family (LLaMA-2 70B-class, sharding stage 3).
 
 Reference analog: the llama models driven through Paddle's fleet/DistTensor
 examples (semi-auto LLaMA in python/paddle/distributed/auto_parallel docs,

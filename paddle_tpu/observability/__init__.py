@@ -15,7 +15,7 @@ One registry, seven capabilities:
     exceptions / anomalies;
   * cluster aggregation (cluster.py): each rank publishes its step record
     through the process-group store; rank 0 aggregates min/median/max/p95
-    per phase and flags stragglers (FLAGS_straggler_k / FLAGS_straggler_m);
+    per phase and flags stragglers (ClusterTelemetry(k=, m=));
   * anomaly engine (anomaly.py): rolling-window detectors (loss/grad-norm
     spike, step-time regression, throughput collapse, compile-cache
     collapse) that dump the flight recorder on detection (FLAGS_anomaly);
@@ -24,8 +24,7 @@ One registry, seven capabilities:
     /healthz on FLAGS_metrics_port.
 
 Importing this package registers FLAGS_metrics, FLAGS_metrics_dir,
-FLAGS_flight_recorder_steps, FLAGS_anomaly, FLAGS_metrics_port,
-FLAGS_straggler_k, and FLAGS_straggler_m.
+FLAGS_flight_recorder_steps, FLAGS_anomaly and FLAGS_metrics_port.
 """
 from . import (anomaly, cluster, flight_recorder, memory,  # noqa: F401
                registry, serve, sinks, spans, telemetry)

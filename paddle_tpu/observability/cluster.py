@@ -10,8 +10,8 @@ InProcStore when threads simulate ranks), and rank 0 aggregates:
     and one `cluster_step` JSONL event per step;
   * straggler flagging (the T3 observation, arXiv 2401.16677: overlap decay
     is invisible without per-phase, per-rank tracking): a rank whose
-    `compute` or `reduce` phase exceeds FLAGS_straggler_k x the cross-rank
-    median for FLAGS_straggler_m CONSECUTIVE steps is flagged — a
+    `compute` or `reduce` phase exceeds STRAGGLER_K x the cross-rank
+    median for STRAGGLER_M CONSECUTIVE steps is flagged — a
     structured `straggler` event goes to the JSONL/Prometheus sinks and the
     flight recorder's cluster snapshot, so a later crash dump says which
     rank was dragging and since when.
@@ -30,16 +30,13 @@ from typing import Any, Dict, List, Optional, Sequence
 
 from . import flight_recorder, telemetry
 from .registry import counter, gauge
-from ..core.flags import define_flag, get_flag
 
-define_flag(
-    "straggler_k", 2.0,
-    "Cluster straggler threshold: a rank is straggling when its compute or "
-    "reduce phase exceeds k x the cross-rank median of that phase.")
-define_flag(
-    "straggler_m", 3,
-    "Cluster straggler persistence: consecutive over-threshold steps before "
-    "a rank is flagged (debounces one-off scheduler hiccups).")
+# Straggler threshold: a rank is straggling when its compute or reduce phase
+# exceeds k x the cross-rank median of that phase. Persistence: consecutive
+# over-threshold steps before a rank is flagged (debounces one-off scheduler
+# hiccups). resilience/elastic.MicroBatchRebalancer shares both.
+STRAGGLER_K = 2.0
+STRAGGLER_M = 3
 
 # the per-rank fields worth shipping cross-host (keep the value tiny: it
 # crosses the store once per rank per step)
@@ -98,21 +95,20 @@ class ClusterTelemetry:
             must accept the key's eventual arrival; InProcStore and the
             native TCPStore both qualify.
         rank / world_size: this process's coordinates.
-        k / m: straggler threshold and persistence; None reads the
-            FLAGS_straggler_k / FLAGS_straggler_m knobs.
+        k / m: straggler threshold and persistence.
         timeout_s: per-rank record wait during aggregation — a rank silent
             for this long turns into a `cluster_timeout` event, not a hang.
     """
 
     def __init__(self, store, rank: int, world_size: int, *,
-                 k: Optional[float] = None, m: Optional[int] = None,
+                 k: float = STRAGGLER_K, m: int = STRAGGLER_M,
                  prefix: str = "/pt/cluster", timeout_s: float = 60.0,
                  phases: Sequence[str] = telemetry.PHASES):
         self.store = store
         self.rank = int(rank)
         self.world_size = int(world_size)
-        self.k = float(get_flag("straggler_k") if k is None else k)
-        self.m = max(int(get_flag("straggler_m") if m is None else m), 1)
+        self.k = float(k)
+        self.m = max(int(m), 1)
         self.prefix = prefix.rstrip("/")
         self.timeout_s = float(timeout_s)
         self.phases = tuple(phases)

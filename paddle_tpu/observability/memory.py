@@ -19,7 +19,7 @@ live, not post-mortem):
     temp memory shows up as a gauge step BEFORE the OOM, and the telemetry
     event log records which compile did it.
 
-`tools/memwatch.py` renders both into one report.
+`memory_report()` renders both into one report.
 """
 from __future__ import annotations
 
@@ -97,7 +97,7 @@ def device_memory_stats() -> List[Dict[str, Any]]:
 
 def update_memory_gauges() -> Dict[str, Any]:
     """Refresh `device_memory_bytes` / `host_memory_bytes` gauges; returns
-    the summary dict (what memwatch prints). Cheap: one C call per device
+    the summary dict. Cheap: one C call per device
     plus two procfs reads."""
     summary: Dict[str, Any] = {"ts": time.time(), "devices": [], "host": {}}
     for entry in device_memory_stats():
@@ -183,7 +183,7 @@ def note_executable(what: str, compiled) -> Dict[str, Any]:
 
 
 def memory_report() -> Dict[str, Any]:
-    """The full memory picture (tools/memwatch.py): device + host gauges
+    """The full memory picture: device + host gauges
     refreshed now, plus every executable budget currently registered."""
     report = update_memory_gauges()
     exes: Dict[str, Dict[str, float]] = {}

@@ -52,7 +52,7 @@ def metrics_body() -> bytes:
 
 
 def health_snapshot(stale_after_s: float = STALE_AFTER_S) -> Dict[str, Any]:
-    """The /healthz body, also usable directly (obsbench, tests)."""
+    """The /healthz body, also usable directly (tests)."""
     now = time.time()
     tele = telemetry.get_telemetry()
     last = dict(getattr(tele, "_last", {}) or {})

@@ -4,7 +4,7 @@ Two write disciplines, matched to what each consumer needs:
 
   * JsonlEventLog — one JSON object per line, flushed per write. Append-only
     so a crash can only lose the final partial line (readers skip it); the
-    flight recorder and tools/stepbench.py read this file back.
+    flight recorder reads this file back.
   * Prometheus textfile — the node-exporter "textfile collector" contract:
     the WHOLE exposition is rewritten atomically (tmp + os.replace, the same
     discipline as resilience/checkpoint_manager.py) so a scraper never sees

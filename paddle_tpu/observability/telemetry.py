@@ -4,8 +4,7 @@ The runtime itself emits one record per optimizer step — loss, grad
 global-norm, learning rate, throughput (samples/s, tokens/s), estimated
 MFU, per-phase wall times (data / compute / reduce / save), and compile /
 recompile events — so benches and dashboards read phases from the live run
-instead of re-timing them externally (the T3 / Gemma-on-TPU accounting the
-ISSUE cites; tools/stepbench.py consumes this).
+instead of re-timing them externally (the T3 / Gemma-on-TPU accounting).
 
 Assembly protocol (who knows what, when):
 
@@ -75,8 +74,8 @@ def enabled() -> bool:
     return metrics_enabled()
 
 
-# Published per-chip peaks, keyed by jax's `device_kind`. One table: bench.py
-# reads it too. A device that is not here has no MFU, not a default one.
+# Published per-chip peaks, keyed by jax's `device_kind`. A device that is
+# not here has no MFU, not a default one.
 #   "TPU v5 lite": Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
 #   16 GB HBM at 819 GB/s.
 DEVICE_PEAKS = {

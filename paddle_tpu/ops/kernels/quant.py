@@ -26,10 +26,9 @@ def dequantize_weight(qweight, scales, dtype=jnp.float32):
     """Scale-folded dequantization: fp [in, out] table from the int8 weight +
     per-output-channel scales. This is weight_only_matmul's epilogue hoisted
     out of the hot path: on backends with no int8 GEMM (XLA:CPU) the per-call
-    convert MATERIALIZES a full fp copy of the weight every decode step, which
-    measured 1.6-1.7x slower than the fp GEMM it was supposed to beat
-    (DECODEBENCH_r05: int8 299 vs fp 416 tok/s). Dequantizing once and reusing
-    the fp table makes int8 decode run the identical GEMM as fp."""
+    convert MATERIALIZES a full fp copy of the weight every decode step.
+    Dequantizing once and reusing the fp table makes int8 decode run the
+    identical GEMM as fp."""
     return qweight.astype(dtype) * scales.astype(dtype)
 
 

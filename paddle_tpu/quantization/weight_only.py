@@ -15,28 +15,18 @@ from __future__ import annotations
 import types
 from typing import List
 
-from ..core import flags as _flags
 from ..core.tensor import Tensor
 from ..ops import api
 
-_flags.define_flag(
-    "weight_only_dequant_cache", "auto",
-    "Hoist int8 weight-only dequantization out of the decode hot loop by "
-    "caching a scale-folded fp table per quantized layer (registered buffer "
-    "'dequant_weight'). 'auto' enables it on backends with no int8 GEMM "
-    "(everything but TPU), where the per-call convert made int8 decode "
-    "SLOWER than fp (DECODEBENCH_r05); 'on'/'off' force it. The int8 tables "
-    "remain the storage/wire format either way.")
-
 
 def _dequant_cache_enabled() -> bool:
+    """Whether to hoist int8 dequantization out of the decode loop by caching
+    a scale-folded fp table per quantized layer (registered buffer
+    'dequant_weight'): on backends with no int8 GEMM (everything but TPU),
+    where the per-call convert materializes an fp copy of the weight every
+    step. The int8 tables remain the storage/wire format either way."""
     import jax
 
-    v = str(_flags.get_flag("weight_only_dequant_cache")).lower()
-    if v in ("on", "true", "1"):
-        return True
-    if v in ("off", "false", "0"):
-        return False
     return jax.default_backend() != "tpu"
 
 
@@ -58,9 +48,9 @@ def _quantize_linear_like(layer, kind: str) -> None:
     use_cache = _dequant_cache_enabled()
     if use_cache:
         # CPU fast path: one scale-folded dequant pass now, so every decode
-        # step runs the identical fp GEMM the unquantized model runs (the
-        # per-call convert was the DECODEBENCH_r05 regression). Registered
-        # as a buffer so compiled decode programs stream it like any weight.
+        # step runs the identical fp GEMM the unquantized model runs.
+        # Registered as a buffer so compiled decode programs stream it like
+        # any weight.
         layer.register_buffer(
             "dequant_weight",
             Tensor(dequantize_weight(q, s, dtype=compute_dtype)))
@@ -115,10 +105,10 @@ def _quantize_tied_head(model, emb_weight) -> None:
     """Weight-only int8 for the TIED LM head (GPT-style `h @ wte.weight^T`).
 
     The head projection is the single biggest GEMM of a decode step
-    (hidden x vocab) and the tied form runs it TRANSPOSED — which XLA:CPU
-    executes ~5x slower than the straight [in, out] layout (measured at the
-    decodebench head shape). Quantizing the head stores the int8 table (and
-    its scale-folded dequant cache) PRE-TRANSPOSED as [hidden, vocab]: the
+    (hidden x vocab) and the tied form runs it TRANSPOSED, which XLA:CPU
+    executes slower than the straight [in, out] layout. Quantizing the head
+    stores the int8 table (and its scale-folded dequant cache)
+    PRE-TRANSPOSED as [hidden, vocab]: the
     int8 model's head streams 4x fewer HBM bytes on TPU and runs the fast
     GEMM layout everywhere. The embedding lookup keeps the fp table."""
     import jax.numpy as jnp

@@ -12,8 +12,7 @@ Pieces (wired together by ResilientTrainer, each usable alone):
                          DataLoader worker respawn path.
   - chaos              : fault-injection harness (crash points inside
                          checkpoint writes, NaN batch poisoning, worker
-                         kills, fake preemption signals) backing the tests
-                         and tools/faultbench.py.
+                         kills, fake preemption signals) backing the tests.
 """
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """Fault-injection harness ("chaos monkey") for the resilience subsystem.
 
 Production code calls `crash_point("name")` at carefully chosen spots in
-checkpoint writes and file commits; tests and tools/faultbench.py arm those
+checkpoint writes and file commits; tests arm those
 points with `inject_crash(...)` to simulate a process dying mid-save. The
 harness also poisons training batches with NaNs (to exercise the compiled
 NaN step-guard), kills DataLoader worker processes, and delivers fake
@@ -21,10 +21,9 @@ from typing import Dict, Iterable, Optional
 __all__ = [
     "InjectedCrash", "inject_crash", "crash_point", "clear", "armed",
     "poison_steps", "should_poison", "note_poisoned", "kill_worker",
-    "fake_preemption", "stats", "reset_stats", "scope",
+    "fake_preemption", "stats", "scope",
     "kill_rank", "should_kill_rank", "note_rank_killed",
     "slow_rank", "rank_delay",
-    "kill_process", "hang_process", "resume_process", "sigstop_supported",
     "StorePartitionProxy",
 ]
 
@@ -49,16 +48,8 @@ stats = {
     "workers_killed": 0,
     "signals_sent": 0,
     "ranks_killed": 0,
-    "processes_killed": 0,
-    "processes_hung": 0,
-    "processes_resumed": 0,
     "partitions_started": 0,
 }
-
-
-def reset_stats():
-    for k in stats:
-        stats[k] = 0
 
 
 def clear():
@@ -179,45 +170,6 @@ def fake_preemption(sig: int = _signal.SIGTERM):
     PreemptionHandler exactly like a TPU maintenance-event SIGTERM."""
     stats["signals_sent"] += 1
     os.kill(os.getpid(), sig)
-
-
-def _pid_of(proc_or_pid) -> int:
-    return int(getattr(proc_or_pid, "pid", proc_or_pid))
-
-
-def sigstop_supported() -> bool:
-    """Can this platform hard-freeze a process (SIGSTOP/SIGCONT)? The
-    faultbench hang scenarios skip gracefully where it can't."""
-    return (os.name == "posix" and hasattr(_signal, "SIGSTOP")
-            and hasattr(_signal, "SIGCONT"))
-
-
-def kill_process(proc_or_pid):
-    """SIGKILL a real OS process (process replica / elastic rank child):
-    no cleanup handlers run, heartbeats simply stop — the genuine article
-    the thread-level kill_rank/kill() only simulate."""
-    os.kill(_pid_of(proc_or_pid), _signal.SIGKILL)
-    stats["processes_killed"] += 1
-
-
-def hang_process(proc_or_pid):
-    """SIGSTOP a real OS process: still alive by waitpid (no exit code)
-    but silent — heartbeats freeze, so only lease expiry can declare it
-    dead. Pair with resume_process() to wake the zombie and exercise
-    fence-token rejection."""
-    if not sigstop_supported():
-        raise RuntimeError("SIGSTOP/SIGCONT not supported on this platform")
-    os.kill(_pid_of(proc_or_pid), _signal.SIGSTOP)
-    stats["processes_hung"] += 1
-
-
-def resume_process(proc_or_pid):
-    """SIGCONT a hung process — the revived zombie must fence itself out
-    (see serving/fleet_proc.py) rather than serve stale state."""
-    if not sigstop_supported():
-        raise RuntimeError("SIGSTOP/SIGCONT not supported on this platform")
-    os.kill(_pid_of(proc_or_pid), _signal.SIGCONT)
-    stats["processes_resumed"] += 1
 
 
 class StorePartitionProxy:

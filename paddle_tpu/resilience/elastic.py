@@ -41,7 +41,7 @@ running statistics will diverge across members; the elastic path targets
 buffer-free (or frozen-buffer) training. Gradient clipping is not applied
 on this path.
 
-Threads-as-ranks (tests, tools/faultbench.py `elastic`): N threads share
+Threads-as-ranks (tests): N threads share
 one InProcStore, each owning its own model/optimizer/trainer. The same
 code runs one-process-per-rank over a native TCPStore.
 """
@@ -59,15 +59,10 @@ from ..core.flags import define_flag, get_flag
 from ..distributed.checkpoint import split_bounds
 from ..distributed.elastic import (ElasticMembership, MembershipView,
                                    PeerLostError, StoreReducer)
-from ..observability import cluster as _cluster  # noqa: F401 — straggler flags
+from ..observability.cluster import STRAGGLER_K, STRAGGLER_M
 from ..observability import flight_recorder as _flight
 from ..observability.registry import counter as _counter
 
-define_flag("elastic_rebalance_skew", 0.0,
-            "Bound on straggler-aware micro-batch rebalancing: a detected "
-            "straggler's batch share can shrink to at most (1 - skew) of "
-            "its equal share, the slack spread over the others. 0 disables "
-            "rebalancing (always equal split).")
 define_flag("elastic_eject_patience", 0,
             "Auto-eject chronically slow ranks: when the rebalancer has "
             "pinned a member at the (1 - skew) share clamp for this many "
@@ -97,20 +92,19 @@ class MicroBatchRebalancer:
     Fed the per-member wall times every member saw in the SAME allreduce
     records, so every member computes identical shares — replication of
     the parameter state never depends on who computed what. Straggler
-    detection reuses the r10 thresholds: a member whose smoothed wall time
-    exceeds `FLAGS_straggler_k` x median for `FLAGS_straggler_m`
-    consecutive steps gets its share scaled by median/ema, floored at
-    (1 - skew) of equal. The weighted gradient average keeps the update
-    math exact under ANY share split, so rebalancing never perturbs the
-    loss trajectory — only who computes how much of it."""
+    detection reuses ClusterTelemetry's thresholds: a member whose smoothed
+    wall time exceeds `k` x median for `m` consecutive steps gets its share
+    scaled by median/ema, floored at (1 - skew) of its equal share, the
+    slack spread over the others; skew 0 disables rebalancing (always
+    equal split). The weighted gradient average keeps the update math
+    exact under ANY share split, so rebalancing never perturbs the loss
+    trajectory — only who computes how much of it."""
 
-    def __init__(self, *, skew: Optional[float] = None,
-                 k: Optional[float] = None, m: Optional[int] = None,
-                 ema_alpha: float = 0.5):
-        self.skew = float(skew if skew is not None
-                          else get_flag("elastic_rebalance_skew"))
-        self.k = float(k if k is not None else get_flag("straggler_k"))
-        self.m = int(m if m is not None else get_flag("straggler_m"))
+    def __init__(self, *, skew: float = 0.0, k: float = STRAGGLER_K,
+                 m: int = STRAGGLER_M, ema_alpha: float = 0.5):
+        self.skew = float(skew)
+        self.k = float(k)
+        self.m = int(m)
         self.ema_alpha = float(ema_alpha)
         self._ema: Dict[int, float] = {}
         self._streak: Dict[int, int] = {}
@@ -215,11 +209,10 @@ class ElasticTrainer:
             order within the current view).
         members: the initial membership.
         save_every: sharded-checkpoint cadence in global steps.
-        heartbeat_s / lease_ttl_s: liveness knobs (default: flags).
+        heartbeat_s / lease_ttl_s: liveness knobs (ElasticMembership's).
         allreduce_timeout_s: how long collect() waits before naming the
             missing members (default: a few lease TTLs).
-        rebalance_skew: bound for straggler rebalancing (default: flag;
-            0 disables).
+        rebalance_skew: bound for straggler rebalancing (0 disables).
         eject_patience: consecutive windows a member may sit pinned at
             the rebalance clamp before it is auto-ejected (default:
             FLAGS_elastic_eject_patience; 0 disables).
@@ -229,11 +222,11 @@ class ElasticTrainer:
     def __init__(self, model, loss_fn, optimizer, root: str, *,
                  store, member_id: int, members: Sequence[int],
                  save_every: int = 5, keep_last_n: int = 3,
-                 heartbeat_s: Optional[float] = None,
-                 lease_ttl_s: Optional[float] = None,
+                 heartbeat_s: float = 0.25,
+                 lease_ttl_s: float = 1.5,
                  allreduce_timeout_s: Optional[float] = None,
                  sync_timeout_s: float = 20.0,
-                 rebalance_skew: Optional[float] = None,
+                 rebalance_skew: float = 0.0,
                  eject_patience: Optional[int] = None,
                  clock: Callable[[], float] = time.monotonic):
         from ..jit.trainer import TrainStep

@@ -692,7 +692,7 @@ class BlockAllocator:
 
     def occupancy_report(self) -> dict:
         """Pool shape + occupancy/fragmentation, the dict the metrics
-        gauges mirror (and servebench embeds in its report)."""
+        gauges mirror."""
         allocatable = self.num_blocks - 1
         used = self.used_blocks
         tokens = self._tokens

@@ -72,52 +72,22 @@ from .paged import PagedKVPool, PagedLayerCache, write_prefix, write_ring
 from .scheduler import Request, Scheduler
 from .speculative import NgramDrafter, SpecState
 
-_flags.define_flag("serving_block_size", 16,
-                   "KV-cache block size (tokens per page) for the serving "
-                   "engine's paged pool.")
-_flags.define_flag("serving_slots", 4,
-                   "Decode batch slots: max sequences decoding concurrently.")
-_flags.define_flag("serving_kv_blocks", 0,
-                   "KV pool size in blocks. 0 = auto: enough for every slot "
-                   "at max_model_len (no admission ever blocks on KV).")
-_flags.define_flag("serving_prefill_chunk", 32,
-                   "Prompt tokens prefilled per engine tick (must be a "
-                   "multiple of serving_block_size); bounds how long a "
-                   "prompt can stall the running decode batch.")
+# What ServingEngine's arguments mean when left out. prefill_chunk bounds how
+# long a prompt can stall the running decode batch; prefill_bucket is the
+# length bucket of the batched multi-prompt prefill program (0 = per-prompt
+# chunked prefill only); prefix_cache content-addresses full KV blocks so
+# prompts sharing a prefix skip its prefill. The last two stay `None` in the
+# signature because over window layers "left out" means off and "asked for"
+# refuses (_refuse_over_windows).
+DEFAULT_PREFIX_CACHE = True
+DEFAULT_PREFILL_BUCKET = 16
+
 _flags.define_flag("serving_fuse_steps", 1,
                    "Greedy decode steps fused into one compiled dispatch. "
                    "1 (default) disables fusion: on CPU the fused loop's "
                    "carried KV pool costs more than the dispatches it "
                    "saves; worth >1 where dispatch latency dominates. "
                    "Sampled batches never fuse.")
-_flags.define_flag("serving_max_model_len", 0,
-                   "Serving context cap (prompt + generated). 0 = the "
-                   "model's max_position_embeddings.")
-_flags.define_flag("serving_prefix_cache", True,
-                   "Automatic prefix caching: content-address full KV "
-                   "blocks so prompts sharing a prefix skip its prefill "
-                   "and share the blocks (copy-on-write on full-prompt "
-                   "hits).")
-_flags.define_flag("serving_spec_k", 0,
-                   "Self-speculative decoding: max draft tokens verified "
-                   "per tick. Drafts are n-gram / prompt-lookup matches "
-                   "from the request's OWN token history; ONE multi-token "
-                   "dispatch scores draft + bonus positions and the "
-                   "longest matching prefix commits. 0 (default) disables "
-                   "speculation. Greedy requests only (temperature > 0 "
-                   "rows fall back to single-token decode in the same "
-                   "batch); mutually exclusive with serving_fuse_steps > "
-                   "1.")
-_flags.define_flag("serving_spec_ngram", 3,
-                   "Longest n-gram the self-speculation drafter matches "
-                   "against the request's history (tries n down to 2).")
-_flags.define_flag("serving_spec_pause", 32,
-                   "Adaptive-k throttle: after 4 consecutive fruitless "
-                   "speculation ticks a request pauses drafting for this "
-                   "many engine ticks before probing again, so "
-                   "non-repetitive traffic degrades to plain one-token "
-                   "decode instead of paying verify windows that never "
-                   "accept.")
 _flags.define_flag("serving_max_queue", 0,
                    "Admission control: maximum requests waiting in the "
                    "scheduler queue. A submit() past this depth raises "
@@ -133,12 +103,6 @@ _flags.define_flag("serving_retry_after_jitter", 0.5,
                    "uniform[base, base * (1 + jitter)] seconds, so a burst "
                    "shed together does not retry in lockstep against a "
                    "recovering fleet. 0 disables jitter.")
-_flags.define_flag("serving_prefill_bucket", 16,
-                   "Length bucket (tokens) for the batched multi-prompt "
-                   "prefill program: a burst's unmatched suffixes pad to "
-                   "one bucketed [n_prompts, max_suffix] dispatch instead "
-                   "of one program per prompt. 0 disables batching "
-                   "(per-prompt chunked prefill only).")
 
 
 _MOE_PAIRS = _counter(
@@ -215,40 +179,44 @@ class ServingEngine:
     (GPTForCausalLM / LlamaForCausalLM), int8-quantized or not.
 
     Quantize BEFORE constructing the engine: compiled programs capture the
-    model's parameter/buffer lists at first use."""
+    model's parameter/buffer lists at first use.
 
-    def __init__(self, model, *, max_slots: Optional[int] = None,
-                 block_size: Optional[int] = None,
-                 num_blocks: Optional[int] = None,
-                 prefill_chunk: Optional[int] = None,
+    max_slots: sequences decoding concurrently. block_size: tokens per KV
+    page. num_blocks: KV pool size in blocks; None or 0 = enough for every
+    slot at max_model_len (no admission ever blocks on KV). prefill_chunk:
+    prompt tokens prefilled per tick, a multiple of block_size.
+    max_model_len: context cap (prompt + generated); None or 0 = the
+    model's max positions. prefix_cache, prefill_bucket: None = the
+    DEFAULT_* above (off over window layers). spec_k: self-speculative
+    decoding, max draft tokens verified per tick from the request's own
+    n-gram history (greedy requests only; 0 = off; mutually exclusive with
+    FLAGS_serving_fuse_steps > 1). spec_ngram: longest n-gram the drafter
+    matches (tries n down to 2). spec_pause: ticks a request stops drafting
+    after 4 consecutive fruitless verify windows."""
+
+    def __init__(self, model, *, max_slots: int = 4, block_size: int = 16,
+                 num_blocks: Optional[int] = None, prefill_chunk: int = 32,
                  max_model_len: Optional[int] = None,
                  prefix_cache: Optional[bool] = None,
                  prefill_bucket: Optional[int] = None,
-                 spec_k: Optional[int] = None,
-                 spec_ngram: Optional[int] = None,
-                 spec_pause: Optional[int] = None):
+                 spec_k: int = 0, spec_ngram: int = 3, spec_pause: int = 32):
         self.model = model
         model.eval()
         # the one cache contract: per layer, what it keeps (models/
         # generation.LayerCacheSpec)
         spec = self._spec = model.cache_spec()
         max_pos = spec.max_positions
-        self.block_size = int(block_size or
-                              _flags.get_flag("serving_block_size"))
-        self.max_slots = int(max_slots or _flags.get_flag("serving_slots"))
-        self.prefill_chunk = int(prefill_chunk or
-                                 _flags.get_flag("serving_prefill_chunk"))
-        flag_len = int(_flags.get_flag("serving_max_model_len"))
-        self.max_model_len = int(max_model_len or flag_len or max_pos)
-        self.max_model_len = min(self.max_model_len, int(max_pos))
+        self.block_size = int(block_size)
+        self.max_slots = int(max_slots)
+        self.prefill_chunk = int(prefill_chunk)
+        self.max_model_len = min(int(max_model_len or max_pos), int(max_pos))
         if self.prefill_chunk % self.block_size:
-            raise ValueError("serving_prefill_chunk must be a multiple of "
-                             "serving_block_size")
+            raise ValueError(
+                f"ServingEngine(prefill_chunk={self.prefill_chunk}) must be "
+                f"a multiple of block_size={self.block_size}")
         self.max_blocks_per_seq = -(-self.max_model_len // self.block_size)
         auto_blocks = self.max_slots * self.max_blocks_per_seq + 1
-        self.num_blocks = int(num_blocks or
-                              _flags.get_flag("serving_kv_blocks") or
-                              auto_blocks)
+        self.num_blocks = int(num_blocks or auto_blocks)
         self._dtype = model._cache_dtype()
         # cache groups: the layers of one kind share a block table, which is
         # a range of columns of a slot's table row. The full group's is the
@@ -286,11 +254,11 @@ class ServingEngine:
                 "prefill_bucket", prefill_bucket, 0,
                 "the batched prefill program writes whole prompts back "
                 "through one block table")
-        self.prefix_cache = (bool(_flags.get_flag("serving_prefix_cache"))
-                             if prefix_cache is None else bool(prefix_cache))
-        self.prefill_bucket = int(
-            _flags.get_flag("serving_prefill_bucket")
-            if prefill_bucket is None else prefill_bucket)
+        self.prefix_cache = bool(DEFAULT_PREFIX_CACHE if prefix_cache is None
+                                 else prefix_cache)
+        self.prefill_bucket = int(DEFAULT_PREFILL_BUCKET
+                                  if prefill_bucket is None
+                                  else prefill_bucket)
         by_window = {r.window: r.num_blocks for r in self.window_rings}
         self.pool = PagedKVPool(
             [(by_window.get(l.window, self.num_blocks), l.kv_heads,
@@ -327,12 +295,9 @@ class ServingEngine:
         self.fuse_steps = int(_flags.get_flag("serving_fuse_steps"))
         # self-speculative decoding (speculative.py): drafts verified in
         # one multi-token dispatch; 0 = off
-        self.spec_k = int(_flags.get_flag("serving_spec_k")
-                          if spec_k is None else spec_k)
-        self.spec_ngram = int(_flags.get_flag("serving_spec_ngram")
-                              if spec_ngram is None else spec_ngram)
-        self.spec_pause = int(_flags.get_flag("serving_spec_pause")
-                              if spec_pause is None else spec_pause)
+        self.spec_k = int(spec_k)
+        self.spec_ngram = int(spec_ngram)
+        self.spec_pause = int(spec_pause)
         if windows:
             self._refuse_over_windows(
                 "spec_k", self.spec_k, 0,
@@ -344,7 +309,7 @@ class ServingEngine:
         if self.spec_k > 0 and self.fuse_steps > 1:
             raise ValueError(
                 "FLAGS_serving_fuse_steps > 1 and speculative decoding "
-                "(serving_spec_k > 0) are mutually exclusive decode "
+                "(ServingEngine(spec_k > 0)) are mutually exclusive decode "
                 "shapes: the fused loop carries a fixed one-token-per-"
                 "step schedule that a variable-width verify window would "
                 "miscompile. Disable one of them.")
@@ -360,8 +325,8 @@ class ServingEngine:
         # prefill + speculation accounting now lives on the metrics
         # registry (serving_engine_events_total, labeled per engine
         # instance — see observability.EngineStats); the properties below
-        # keep the historical int-attribute reads (servebench deltas,
-        # tests) and stats() keeps its JSON shape
+        # keep the int-attribute reads (the benchmark's deltas, tests) and
+        # stats() keeps its JSON shape
         self._stats = EngineStats(new_engine_id())
         # lifecycle hooks: request traces, SLO histograms, per-tick
         # gauges, serving anomaly detectors + flight arm

@@ -30,7 +30,7 @@ over a TCPStore unchanged):
   * load shedding: when every healthy replica's queue is full the router
     raises QueueFullError with a jittered Retry-After, so the shed wave
     does not come back in lockstep.
-  * disaggregated prefill/decode: FLAGS_fleet_roles splits the fleet
+  * disaggregated prefill/decode: FleetRouter(roles=...) splits the fleet
     into prefill-heavy and decode-packed replicas. A request first runs
     prefill-only on a prefill replica; its finished FULL KV blocks
     stream to the best decode replica over the /kv wire (chain-hash
@@ -54,7 +54,6 @@ import threading
 import time
 from typing import Dict, List, Optional
 
-from ..core import flags as _flags
 from ..distributed.env import InProcStore, ReplicaRegistry
 from ..observability import spans as _spans
 from ..observability.registry import counter as _counter
@@ -63,56 +62,6 @@ from ..observability.registry import histogram as _histogram
 from . import fleet_observability as _fobs
 from .engine import EngineDrainingError, QueueFullError, ServingEngine
 from .observability import RequestTrace
-
-_flags.define_flag("fleet_replicas", 2,
-                   "Serving replicas a fleet front end builds when not "
-                   "given explicit engines (tools/servebench.py fleet "
-                   "mode; FleetServer).")
-_flags.define_flag("fleet_hedge_ttft_ms", 0.0,
-                   "Hedged-retry TTFT deadline in milliseconds: a request "
-                   "with no first token past this age is duplicated onto "
-                   "a second healthy replica; first token wins and the "
-                   "loser is cancelled (slot + KV reservation freed). "
-                   "0 (default) disables hedging.")
-_flags.define_flag("fleet_breaker_errors", 3,
-                   "Consecutive submission/tick errors that open a "
-                   "replica's circuit breaker (replica leaves the routing "
-                   "set until a half-open probe succeeds).")
-_flags.define_flag("fleet_breaker_cooldown_s", 2.0,
-                   "Seconds an open circuit breaker waits before allowing "
-                   "one half-open probe request through.")
-_flags.define_flag("fleet_roles", "symmetric",
-                   "Replica role layout for disaggregated serving: "
-                   "'symmetric' (default — every replica both prefils and "
-                   "decodes, exactly the pre-disagg behavior) or a "
-                   "'role:count,...' spec like 'prefill:1,decode:3' "
-                   "assigned to replicas in construction order. Prefill "
-                   "replicas only run prefill-only attempts and stream "
-                   "their finished KV blocks; decode replicas only host "
-                   "decode attempts.")
-_flags.define_flag("fleet_drain_migrate", False,
-                   "When on, drain(rid) also live-migrates in-flight "
-                   "sessions: their resident prompt KV blocks stream to a "
-                   "survivor and the attempts re-place there instead of "
-                   "finishing on the draining replica. Off keeps the r18 "
-                   "drain semantics (in-flight work completes in place).")
-_flags.define_flag("fleet_scale_min", 1,
-                   "FleetAutoscaler floor: scalable replicas are never "
-                   "drained below this count.")
-_flags.define_flag("fleet_scale_max", 8,
-                   "FleetAutoscaler ceiling: never spawn past this many "
-                   "scalable replicas.")
-_flags.define_flag("fleet_scale_hi", 0.85,
-                   "Scale-up threshold: utilization (offered load / fleet "
-                   "slot capacity) at or above this spawns a replica once "
-                   "the cooldown allows.")
-_flags.define_flag("fleet_scale_lo", 0.25,
-                   "Scale-down threshold: utilization at or below this "
-                   "drains (migration-assisted) and retires the least "
-                   "loaded scalable replica.")
-_flags.define_flag("fleet_scale_cooldown_s", 5.0,
-                   "Minimum seconds between autoscaler actions, so a "
-                   "bursty curve cannot flap the fleet.")
 
 # fleet-level SLO + routing telemetry: always-on like the engine's tier
 # histograms. The engine-level serving_* histograms are registry-global,
@@ -164,7 +113,7 @@ _ROLES = ("prefill", "decode", "any")
 
 
 def parse_fleet_roles(spec: Optional[str], n_replicas: int) -> List[str]:
-    """Expand a FLAGS_fleet_roles spec to one role per replica, in
+    """Expand a FleetRouter(roles=...) spec to one role per replica, in
     construction order. 'symmetric' / empty -> all 'any' (the pre-disagg
     behavior); otherwise 'role:count,...' must cover every replica."""
     spec = (spec or "symmetric").strip().lower()
@@ -179,7 +128,7 @@ def parse_fleet_roles(spec: Optional[str], n_replicas: int) -> List[str]:
                              f"(want one of {_ROLES})")
         roles.extend([name] * int(count or 1))
     if len(roles) != n_replicas:
-        raise ValueError(f"fleet_roles covers {len(roles)} replicas, "
+        raise ValueError(f"roles covers {len(roles)} replicas, "
                          f"fleet has {n_replicas}")
     return roles
 
@@ -455,15 +404,26 @@ class FleetRouter:
     """Routes requests across replicas; detects failures via store
     heartbeat leases + circuit breakers; re-dispatches, hedges, drains
     and sheds. Replica engine loops and the monitor are daemon threads
-    owned by the router (start()/stop())."""
+    owned by the router (start()/stop()).
+
+    roles: 'symmetric' (every replica both prefills and decodes) or a
+    'role:count,...' spec like 'prefill:1,decode:3' assigned to replicas
+    in construction order: prefill replicas only run prefill-only attempts
+    and stream their finished KV blocks, decode replicas only host decode
+    attempts. hedge_ttft_ms: a request with no first token past this age
+    is duplicated onto a second healthy replica; first token wins and the
+    loser is cancelled (0 = no hedging). breaker_errors: consecutive
+    submission/tick errors that open a replica's circuit breaker.
+    breaker_cooldown_s: seconds an open breaker waits before letting one
+    half-open probe through."""
 
     def __init__(self, engines: Optional[List[ServingEngine]] = None, *,
                  replica_specs: Optional[List] = None,
                  store=None, prefix: str = "/pt/fleet",
-                 roles: Optional[str] = None,
-                 hedge_ttft_ms: Optional[float] = None,
-                 breaker_errors: Optional[int] = None,
-                 breaker_cooldown_s: Optional[float] = None,
+                 roles: str = "symmetric",
+                 hedge_ttft_ms: float = 0.0,
+                 breaker_errors: int = 3,
+                 breaker_cooldown_s: float = 2.0,
                  heartbeat_s: float = 0.05, lease_ttl_s: float = 0.5,
                  poll_interval_s: float = 0.02,
                  idle_sleep_s: float = 0.002, clock=time.monotonic):
@@ -477,14 +437,9 @@ class FleetRouter:
         self.poll_interval_s = float(poll_interval_s)
         self._heartbeat_s = float(heartbeat_s)
         self._idle_sleep_s = float(idle_sleep_s)
-        self.hedge_ttft_s = float(
-            _flags.get_flag("fleet_hedge_ttft_ms")
-            if hedge_ttft_ms is None else hedge_ttft_ms) / 1000.0
-        max_errors = int(_flags.get_flag("fleet_breaker_errors")
-                         if breaker_errors is None else breaker_errors)
-        cooldown = float(_flags.get_flag("fleet_breaker_cooldown_s")
-                         if breaker_cooldown_s is None else
-                         breaker_cooldown_s)
+        self.hedge_ttft_s = float(hedge_ttft_ms) / 1000.0
+        max_errors = int(breaker_errors)
+        cooldown = float(breaker_cooldown_s)
         self._breaker_cfg = (max_errors, cooldown)
         self.registry = ReplicaRegistry(store if store is not None
                                         else InProcStore(),
@@ -512,11 +467,8 @@ class FleetRouter:
                              clock=clock, idle_sleep_s=idle_sleep_s)
             self.replicas[rid] = rep
             self.registry.register(rid, meta={"kind": "process"})
-        role_spec = (str(_flags.get_flag("fleet_roles"))
-                     if roles is None else roles)
         for rep, role in zip(self.replicas.values(),
-                             parse_fleet_roles(role_spec,
-                                               len(self.replicas))):
+                             parse_fleet_roles(roles, len(self.replicas))):
             rep.role = role
         self._next_rid = len(self.replicas)
         self._started = False
@@ -984,10 +936,10 @@ class FleetRouter:
             _HEDGED.inc()
 
     # -- drain / chaos -----------------------------------------------------
-    def drain(self, rid: str, migrate: Optional[bool] = None):
+    def drain(self, rid: str, migrate: bool = False):
         """Rolling-restart drain: stop routing to `rid`, stop its engine
-        admitting. With `migrate` (default FLAGS_fleet_drain_migrate,
-        off) in-flight sessions live-migrate to a survivor — their
+        admitting. With `migrate` in-flight sessions live-migrate to a
+        survivor — their
         resident prompt KV blocks stream over the chain-hash wire and
         the attempts re-place there, so the survivor re-decodes (greedy:
         bitwise identical) without re-prefilling any already-full block.
@@ -996,8 +948,7 @@ class FleetRouter:
             rep = self.replicas[rid]
             rep.draining = True
             rep.engine.drain()
-        if (bool(_flags.get_flag("fleet_drain_migrate"))
-                if migrate is None else bool(migrate)):
+        if migrate:
             self.migrate_from(rid)
 
     def migrate_from(self, rid: str) -> int:
@@ -1058,7 +1009,7 @@ class FleetRouter:
         return self.replicas[rid].engine.drained()
 
     def kill_replica(self, rid: str):
-        """Chaos hook (tests / servebench): crash one replica."""
+        """Chaos hook (tests): crash one replica."""
         self.replicas[rid].kill()
 
     # -- elastic fleet membership ------------------------------------------
@@ -1188,28 +1139,21 @@ class FleetAutoscaler:
     unroutable until healthy), crossing `lo` retires the least-loaded
     one through a migration-assisted drain followed by remove_replica
     once it runs dry. One action per cooldown window; floor/ceiling
-    bound the pool. All timing runs on the router's clock, so
-    virtual-time benches drive it deterministically."""
+    bound the pool. All timing runs on the router's clock, so a test's
+    fake clock drives it deterministically."""
 
     def __init__(self, router: FleetRouter, spawn, *, role: str = "any",
-                 min_replicas: Optional[int] = None,
-                 max_replicas: Optional[int] = None,
-                 hi: Optional[float] = None, lo: Optional[float] = None,
-                 cooldown_s: Optional[float] = None,
-                 slots_per_replica: int = 8):
+                 min_replicas: int = 1, max_replicas: int = 8,
+                 hi: float = 0.85, lo: float = 0.25,
+                 cooldown_s: float = 5.0, slots_per_replica: int = 8):
         self.router = router
         self.spawn = spawn
         self.role = str(role)
-        self.min_replicas = int(_flags.get_flag("fleet_scale_min")
-                                if min_replicas is None else min_replicas)
-        self.max_replicas = int(_flags.get_flag("fleet_scale_max")
-                                if max_replicas is None else max_replicas)
-        self.hi = float(_flags.get_flag("fleet_scale_hi")
-                        if hi is None else hi)
-        self.lo = float(_flags.get_flag("fleet_scale_lo")
-                        if lo is None else lo)
-        self.cooldown_s = float(_flags.get_flag("fleet_scale_cooldown_s")
-                                if cooldown_s is None else cooldown_s)
+        self.min_replicas = int(min_replicas)
+        self.max_replicas = int(max_replicas)
+        self.hi = float(hi)
+        self.lo = float(lo)
+        self.cooldown_s = float(cooldown_s)
         if not (0.0 <= self.lo < self.hi):
             raise ValueError(f"need 0 <= lo < hi, got lo={self.lo} "
                              f"hi={self.hi}")
@@ -1282,15 +1226,13 @@ class FleetAutoscaler:
         return None
 
 
-def build_fleet(model_factory, n_replicas: Optional[int] = None, *,
+def build_fleet(model_factory, n_replicas: int = 2, *,
                 router_kwargs: Optional[dict] = None,
                 **engine_kwargs) -> FleetRouter:
     """Build N independent replicas (each its OWN model instance from
     `model_factory` — no shared mutable state between replica threads;
     seed the factory identically for bitwise-interchangeable replicas)
     and a router over them."""
-    n = int(_flags.get_flag("fleet_replicas")
-            if n_replicas is None else n_replicas)
     engines = [ServingEngine(model_factory(), **engine_kwargs)
-               for _ in range(n)]
+               for _ in range(int(n_replicas))]
     return FleetRouter(engines, **(router_kwargs or {}))

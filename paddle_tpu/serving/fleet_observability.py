@@ -59,21 +59,11 @@ from ..observability.registry import (
 )
 from .observability import chrome_trace_events
 
-define_flag("fleet_flight_requests", 64,
-            "Fleet flight-recorder arm: how many settled fleet-request "
-            "records (attempt summaries + merged cross-replica traces) "
-            "ride along in a fleet anomaly dump, and how far back "
-            "GET /trace?id= can answer for finished requests.")
 define_flag("fleet_anomaly", "auto",
             "Fleet anomaly detectors (hedge-rate spike, re-dispatch "
             "storm, breaker flap, replica p95-TTFT skew) over per-poll "
             "router records: 'auto' follows FLAGS_anomaly, 'on'/'off' "
             "override it. Needs FLAGS_metrics=on either way.")
-define_flag("fleet_detector_window", 16,
-            "Rolling window, in router polls, for the fleet anomaly "
-            "detectors — breaker transitions are counted per replica "
-            "inside this window, and the rate fields feed detectors "
-            "bounded by this history.")
 
 _TRUE = ("1", "on", "true", "yes")
 
@@ -158,6 +148,15 @@ class FleetObservability:
     hooks from its routing/supervision paths; ``tick`` runs once per
     poll and feeds the fleet anomaly detectors."""
 
+    #: flight-recorder arm: how many settled fleet-request records
+    #: (attempt summaries + merged cross-replica traces) ride along in a
+    #: fleet anomaly dump, and how far back GET /trace?id= can answer for
+    #: finished requests
+    FLIGHT_REQUESTS = 64
+    #: rolling window, in router polls, of the fleet anomaly detectors:
+    #: breaker transitions are counted per replica inside it, and the rate
+    #: fields feed detectors bounded by this history
+    DETECTOR_WINDOW = 16
     #: per-replica TTFT samples kept for the skew signal
     TTFT_WINDOW = 64
     #: replicas need this many samples before their p95 enters the skew
@@ -168,10 +167,10 @@ class FleetObservability:
         self.router = router
         self.dump = bool(dump)
         self.dump_cooldown_ticks = int(dump_cooldown_ticks)
-        self.window = max(int(get_flag("fleet_detector_window")), 1)
-        n = max(int(get_flag("fleet_flight_requests")), 1)
+        self.window = self.DETECTOR_WINDOW
         self._lock = threading.Lock()
-        self._settled: deque = deque(maxlen=n)   # finished fleet records
+        # finished fleet records
+        self._settled: deque = deque(maxlen=self.FLIGHT_REQUESTS)
         self._breaker_log: deque = deque(maxlen=256)
         self._scale_log: deque = deque(maxlen=256)   # membership changes
         self._ttft: Dict[str, deque] = {}        # rid -> recent TTFTs
@@ -389,7 +388,7 @@ class FleetObservability:
 
     def observe_record(self, rec: Dict[str, Any]) -> List[Dict[str, Any]]:
         """Feed one fleet record through the detectors; dump on
-        detection. Public seam — tests and obsbench inject synthetic
+        detection. Public seam — tests inject synthetic
         records through the same path tick() uses."""
         engine = self._anomaly_engine()
         if engine is None:
@@ -628,8 +627,8 @@ def export_fleet_trace(router, request_id: str, path: str) -> str:
 
 def coverage_of(events: List[Dict[str, Any]]) -> float:
     """Fraction of a merged trace's wall window (first span begin ->
-    last span end) covered by the union of its span intervals — the
-    obsbench completeness gate ('no invisible time')."""
+    last span end) covered by the union of its span intervals: the
+    completeness check ('no invisible time') of tests/test_fleet.py."""
     ivals = sorted((e["ts"], e["ts"] + e["dur"]) for e in events
                    if e.get("ph") == "X")
     if not ivals:

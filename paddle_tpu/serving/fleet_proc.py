@@ -23,7 +23,7 @@ real subprocess and supervises it the way an agent supervises a pod:
     heals before the respawn deadline revives the incarnation with NO
     respawn and NO fence bump.
   * Respawn uses resilience/retry.RetryPolicy pacing (capped exponential
-    backoff + deterministic jitter, FLAGS_fleet_respawn_max attempts)
+    backoff + deterministic jitter, ProcessReplicaSpec.respawn_max attempts)
     and gates routing on a warm-up probe: the new incarnation is
     ``warming`` (unroutable, not dead) until /healthz says ok.
   * Every incarnation is stamped with a monotonically increasing fence
@@ -51,25 +51,10 @@ import urllib.error
 import urllib.request
 from typing import List, Optional, Tuple
 
-from ..core import flags as _flags
 from ..observability.registry import counter as _counter
 from ..resilience.retry import RetryPolicy
 from .engine import EngineDrainingError, QueueFullError
 from .fleet import Replica
-
-_flags.define_flag("fleet_respawn_max", 3,
-                   "Respawn attempts per process replica before the "
-                   "supervisor gives up and leaves it dead (the initial "
-                   "spawn is not counted).")
-_flags.define_flag("fleet_respawn_backoff_s", 0.5,
-                   "Base respawn backoff in seconds; actual delays follow "
-                   "the shared RetryPolicy schedule (exponential, capped "
-                   "at 8x base, jittered). Doubles as the heal-grace "
-                   "window for a silent-but-alive child.")
-_flags.define_flag("fleet_warmup_timeout_s", 60.0,
-                   "Seconds a spawned replica incarnation gets to print "
-                   "its ready line AND pass the /healthz warm-up probe "
-                   "before the supervisor kills it and tries again.")
 
 _RESPAWNS = _counter("fleet_replica_respawns_total",
                      "Process-replica incarnations respawned by the "
@@ -431,16 +416,25 @@ class ProcessReplicaSpec:
     environment plus ``extra_env`` and uses whatever platform jax finds
     there, so on a TPU host it claims the chip — and fails or hangs if the
     supervisor (or another replica) already holds it. Pass
-    ``extra_env={"JAX_PLATFORMS": "cpu"}`` for CPU replicas."""
+    ``extra_env={"JAX_PLATFORMS": "cpu"}`` for CPU replicas.
+
+    ``warmup_timeout_s``: seconds a spawned incarnation gets to print its
+    ready line AND pass the /healthz warm-up probe before the supervisor
+    kills it and tries again. ``respawn_max``: respawn attempts before the
+    supervisor gives up and leaves the replica dead (the initial spawn is
+    not counted). ``respawn_backoff_s``: base respawn backoff; actual
+    delays follow the shared RetryPolicy schedule (exponential, capped at
+    8x base, jittered). Doubles as the heal-grace window for a
+    silent-but-alive child."""
 
     def __init__(self, store_addr: Tuple[str, int], *,
                  factory: str = "paddle_tpu.serving.fleet_proc:demo_model",
                  engine_kwargs: Optional[dict] = None,
                  child_store_addr: Optional[Tuple[str, int]] = None,
                  child_heartbeat_s: float = 0.2,
-                 warmup_timeout_s: Optional[float] = None,
-                 respawn_max: Optional[int] = None,
-                 respawn_backoff_s: Optional[float] = None,
+                 warmup_timeout_s: float = 60.0,
+                 respawn_max: int = 3,
+                 respawn_backoff_s: float = 0.5,
                  python: str = sys.executable,
                  extra_env: Optional[dict] = None):
         self.store_addr = (str(store_addr[0]), int(store_addr[1]))
@@ -449,14 +443,9 @@ class ProcessReplicaSpec:
         self.factory = str(factory)
         self.engine_kwargs = dict(engine_kwargs or {})
         self.child_heartbeat_s = float(child_heartbeat_s)
-        self.warmup_timeout_s = float(
-            _flags.get_flag("fleet_warmup_timeout_s")
-            if warmup_timeout_s is None else warmup_timeout_s)
-        self.respawn_max = int(_flags.get_flag("fleet_respawn_max")
-                               if respawn_max is None else respawn_max)
-        self.respawn_backoff_s = float(
-            _flags.get_flag("fleet_respawn_backoff_s")
-            if respawn_backoff_s is None else respawn_backoff_s)
+        self.warmup_timeout_s = float(warmup_timeout_s)
+        self.respawn_max = int(respawn_max)
+        self.respawn_backoff_s = float(respawn_backoff_s)
         self.python = str(python)
         self.extra_env = dict(extra_env or {})
 
@@ -574,8 +563,7 @@ class ProcessReplica(Replica):
 
     def pause(self):  # pragma: no cover — chaos uses SIGSTOP directly
         raise NotImplementedError(
-            "use resilience.chaos.hang_process(replica.pid) for process "
-            "replicas")
+            "SIGSTOP replica.pid to hang a process replica")
 
     def dead(self, lease_ttl_s: float) -> bool:
         if self._killed or self._stopped or self._exhausted:

@@ -67,10 +67,6 @@ define_flag("serving_metrics_port", 0,
             "(observability/serve.py machinery); 0 disables. The "
             "ServingServer's own port always answers GET /metrics and "
             "/healthz regardless.")
-define_flag("serving_flight_requests", 64,
-            "Serving flight-recorder arm: how many finished request "
-            "records (telemetry + trace) and engine tick snapshots ride "
-            "along in an anomaly dump.")
 define_flag("serving_anomaly", "auto",
             "Serving anomaly detectors (TTFT regression, goodput collapse, "
             "cache-hit collapse, KV conservation breach) over per-tick "
@@ -341,14 +337,17 @@ class ServingObservability:
     SLO histogram observes (always-on by contract) were already paid by
     the pre-r16 engine."""
 
+    #: flight-recorder arm: how many finished request records (telemetry
+    #: + trace) and engine tick snapshots ride along in an anomaly dump
+    FLIGHT_REQUESTS = 64
     #: samples in the rolling goodput window
     GOODPUT_WINDOW = 16
     #: recent admissions in the rolling prefix-hit-rate window
     ADMIT_WINDOW = 64
     #: gauge/record sampling stride: the tick hot path only accumulates
     #: decoded-token counts; gauges, the tick snapshot, and the anomaly
-    #: detectors run every TICK_SAMPLE-th engine step (the <=3% servebench
-    #: overhead budget rules out per-tick dict/registry work)
+    #: detectors run every TICK_SAMPLE-th engine step (per-tick
+    #: dict/registry work is host time on the decode path)
     TICK_SAMPLE = 4
 
     def __init__(self, engine, *, dump: bool = True,
@@ -356,7 +355,7 @@ class ServingObservability:
         self.engine = engine
         self.dump = bool(dump)
         self.dump_cooldown_steps = int(dump_cooldown_steps)
-        n = max(int(get_flag("serving_flight_requests")), 1)
+        n = self.FLIGHT_REQUESTS
         self._records: deque = deque(maxlen=n)   # finished request records
         self._ticks: deque = deque(maxlen=n)     # engine tick snapshots
         self._tok_window: deque = deque(maxlen=self.GOODPUT_WINDOW)
@@ -529,7 +528,7 @@ class ServingObservability:
 
     def observe_record(self, rec: Dict[str, Any]) -> List[Dict[str, Any]]:
         """Feed one tick record through the serving anomaly detectors;
-        dumps the flight arm on detection. Public seam (tests/servebench
+        dumps the flight arm on detection. Public seam (tests
         inject synthetic records through the same path on_tick uses)."""
         engine = self._anomaly_engine()
         if engine is None:

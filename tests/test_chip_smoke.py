@@ -1,4 +1,4 @@
-"""chip_smoke.py / bench.py off the chip, the compile cache's placement, and
+"""chip_smoke.py off the chip, the compile cache's placement, and
 the places that used to fall back in silence (Place, peak FLOP/s, analyzer).
 
 The chip itself is reached only through the chip tool; what can be held
@@ -51,13 +51,6 @@ def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
     assert res.returncode != 0
     last = json.loads(res.stdout.strip().splitlines()[-1])
     assert last["ok"] is False and "paddle_tpu" in last["error"]
-
-
-def test_bench_fails_without_a_tpu():
-    res = _python(os.path.join(ROOT, "bench.py"))
-    assert res.returncode != 0
-    assert "cpu" in res.stderr
-    assert "tokens_per_sec" not in res.stdout      # no metric under a chip's name
 
 
 def test_smoke_reference_check_can_fail():

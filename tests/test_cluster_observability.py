@@ -191,6 +191,9 @@ class TestAnomaly:
         assert payload["anomaly"]["kind"] == "loss_spike"
         assert payload["anomaly"]["step"] == 20
         assert payload["anomalies"]  # the ring rides along
+        # atomic write: no torn temp file left beside the dump
+        assert not glob.glob(os.path.join(os.path.dirname(eng.dumps[0]),
+                                          "*.tmp"))
 
     def test_grad_norm_spike(self, metrics_dir):
         eng = anomaly.AnomalyEngine(dump=False)

@@ -85,8 +85,8 @@ class TestRegisterCustomOp:
 
     def test_pallas_backed_op(self):
         """A Pallas kernel registered through the public API only (interpret
-        mode: tests run on CPU; the TPU lowering path is covered by
-        tools/tpu_smoke.py)."""
+        mode: tests run on CPU; the repo's own kernels are held to the TPU
+        compiler by tests/test_tpu_compile.py)."""
         from jax.experimental import pallas as pl
 
         opname = _unique("pallas_axpy")

@@ -11,6 +11,7 @@ so failure detection, re-dispatch and hedging are fully deterministic
 real threads — that is the surface they exist to cover.
 """
 import json
+import os
 import time
 import urllib.error
 import urllib.request
@@ -454,6 +455,9 @@ class TestDisaggregatedFleet:
             return ServingEngine(m, max_slots=3, block_size=16,
                                  prefill_chunk=16)
 
+        scaled = registry.REGISTRY.get("fleet_scale_events_total")
+        ups0 = scaled.value(direction="up")
+        downs0 = scaled.value(direction="down")
         scaler = FleetAutoscaler(router, spawn, min_replicas=1,
                                  max_replicas=3, hi=0.75, lo=0.25,
                                  cooldown_s=1.0)
@@ -491,6 +495,9 @@ class TestDisaggregatedFleet:
         assert len(router.replicas) == 1
         assert sum(e["dir"] == "down" for e in scaler.events) == 2
         assert len(router.obs.scale_log()) >= 4   # 2 up + 2 down
+        scaled = registry.REGISTRY.get("fleet_scale_events_total")
+        assert scaled.value(direction="up") - ups0 == 2
+        assert scaled.value(direction="down") - downs0 == 2
 
 
 # ---------------------------------------------------------------- HTTP API
@@ -754,6 +761,49 @@ class TestFleetTracing:
         assert ("replica-0", "closed", "open") in states
         assert ("replica-0", "open", "half_open") in states
         assert ("replica-0", "half_open", "closed") in states
+
+    @pytest.mark.parametrize("kind,field,value,ticks", [
+        ("hedge_rate_spike", "hedge_rate", 0.5, 1),
+        ("redispatch_storm", "redispatch_rate", 0.5, 1),
+        ("breaker_flap", "breaker_flaps", 4.0, 1),
+        ("replica_skew", "ttft_skew", 5.0, 3),    # sustained: patience 3
+    ])
+    def test_fleet_detector_fires_on_its_signal_and_dumps_router_state(
+            self, tmp_path, kind, field, value, ticks):
+        """Each fleet detector stays silent on a clean fleet, fires on its
+        own signal through the seam tick() feeds, and the flight dump it
+        triggers embeds the router's state and the settled requests'
+        merged traces, written atomically."""
+        import glob
+
+        _flags.set_flags({"fleet_anomaly": "on",
+                          "metrics_dir": str(tmp_path)})
+        try:
+            cfg, router = _fleet(2, clock=lambda: 0.0, lease_ttl_s=1000.0)
+            freq = router.submit([1, 2, 3, 4], max_new_tokens=3)
+            _drive(router, [freq])          # a settled trace for the dump
+            clean = {"kind": "fleet_tick", "hedge_rate": 0.0,
+                     "redispatch_rate": 0.0, "breaker_flaps": 0.0,
+                     "ttft_skew": 1.0}
+            fired = []
+            for s in range(8):
+                fired += router.obs.observe_record(dict(clean, step=s))
+            assert fired == [] and router.obs.dumps == []
+            for s in range(8, 8 + ticks):
+                fired += router.obs.observe_record(
+                    dict(clean, step=s, **{field: value}))
+            assert [e["kind"] for e in fired] == [kind]
+            (path,) = router.obs.dumps
+            with open(path) as f:
+                payload = json.load(f)
+            assert payload["anomaly"]["kind"] == kind
+            state = next(iter(payload["router"]["replicas"].values()))
+            assert {"breaker", "load", "lease_age_s"} <= set(state)
+            assert any(r.get("trace") for r in payload["fleet_requests"])
+            assert not glob.glob(os.path.join(os.path.dirname(path),
+                                              "*.tmp"))
+        finally:
+            _flags.set_flags({"fleet_anomaly": "auto", "metrics_dir": ""})
 
     def test_fleet_server_trace_endpoint(self):
         cfg, router = _fleet(2)

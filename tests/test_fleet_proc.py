@@ -238,3 +238,47 @@ class TestProcessFleet:
             == fenced0 + 1
         # the replacement incarnation is healthy and still bitwise
         assert self._oracle(router) == oracle
+
+
+@needs_native
+def test_store_partition_heals_without_respawn(tmp_path):
+    """A replica whose store traffic stalls past the lease is declared
+    dead; when the partition heals inside the grace window the SAME
+    incarnation revives: no respawn, no fence bump, the same bits."""
+    from paddle_tpu.core import flags
+    from paddle_tpu.resilience import chaos
+
+    prev = flags.get_flag("metrics_dir")
+    flags.set_flags({"metrics_dir": str(tmp_path)})
+    store = native.TCPStore("127.0.0.1", 0, is_master=True, world_size=1)
+    proxy = chaos.StorePartitionProxy("127.0.0.1", store.port)
+    # the child reaches the store THROUGH the proxy, the supervisor directly;
+    # a 20 s backoff base keeps the heal-grace window well past the stall
+    router = build_process_fleet(
+        1, store=store, store_addr=(proxy.host, proxy.port),
+        spec_kwargs=dict(engine_kwargs=ENGINE_KW, child_heartbeat_s=0.2,
+                         respawn_backoff_s=20.0, respawn_max=3),
+        router_kwargs=dict(heartbeat_s=0.05, lease_ttl_s=1.0,
+                           prefix="/t/fleetpart"))
+    router.start()
+    try:
+        assert wait_fleet_ready(router, 120), "process fleet never warmed up"
+        rep = router.replicas["replica-0"]
+        inc0, respawns0, pid0 = rep.incarnation, rep.respawns, rep.pid
+        first = router.submit(PROMPT, max_new_tokens=16)
+        assert first.wait(60)
+        oracle = list(first.output_tokens)
+        proxy.partition(duration_s=2.5, mode="stall")
+        assert _wait_for(lambda: rep.dead(router.lease_ttl_s), 10)
+        assert _wait_for(lambda: (not rep.dead(router.lease_ttl_s)
+                                  and not rep.warming()), 30)
+        assert not proxy.partitioned
+        assert (rep.incarnation, rep.respawns, rep.pid) == \
+            (inc0, respawns0, pid0)
+        again = router.submit(PROMPT, max_new_tokens=16)
+        assert again.wait(60) and list(again.output_tokens) == oracle
+    finally:
+        router.stop()
+        store.close()
+        proxy.close()
+        flags.set_flags({"metrics_dir": prev})

@@ -1,6 +1,8 @@
 """spawn, multiprocessing tensor sharing, TensorArray, SelectedRows
 (reference: distributed/spawn.py:428, incubate/multiprocessing/reductions.py,
 python/paddle/tensor/array.py, phi selected_rows)."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -521,3 +523,179 @@ class TestEnforceAndNanCheck:
         finally:
             flags.set_flags({"check_nan_inf": False})
             assert not jax.config.jax_debug_nans
+
+
+# --------------------------------------------------------------------------
+# one spelling for every setting: what is left a flag is read somewhere, and
+# what became a constructor default kept the value its flag had
+# --------------------------------------------------------------------------
+
+def _package_sources():
+    import glob
+    import os
+
+    root = os.path.dirname(os.path.abspath(paddle.__file__))
+    return sorted(glob.glob(os.path.join(root, "**", "*.py"), recursive=True))
+
+
+@functools.lru_cache(maxsize=None)
+def _flag_definitions_and_string_reads():
+    """({flag: defining file}, {every string constant outside a define_flag
+    call}) over the package's sources."""
+    import ast
+
+    defined, strings = {}, set()
+
+    def walk(node, path):
+        f = getattr(node, "func", None)
+        callee = getattr(f, "id", getattr(f, "attr", None))
+        if isinstance(node, ast.Call) and callee == "define_flag":
+            defined[node.args[0].value] = path
+            return                      # a flag's own definition is no read
+        if isinstance(node, ast.Constant) and isinstance(node.value, str):
+            strings.add(node.value)
+        for child in ast.iter_child_nodes(node):
+            walk(child, path)
+
+    for path in _package_sources():
+        with open(path) as f:
+            walk(ast.parse(f.read()), path)
+    return defined, strings
+
+
+# the flags that nothing set, by the constructor argument or constant that
+# holds each one's value now
+_REMOVED_FLAGS = (
+    "eager_op_jit", "low_precision_op_list", "use_donated_buffers",
+    "benchmark", "elastic",
+    "serving_block_size", "serving_slots", "serving_kv_blocks",
+    "serving_prefill_chunk", "serving_max_model_len", "serving_prefix_cache",
+    "serving_prefill_bucket", "serving_spec_k", "serving_spec_ngram",
+    "serving_spec_pause", "fleet_replicas", "fleet_hedge_ttft_ms",
+    "fleet_breaker_errors", "fleet_breaker_cooldown_s", "fleet_roles",
+    "fleet_drain_migrate", "fleet_scale_min", "fleet_scale_max",
+    "fleet_scale_hi", "fleet_scale_lo", "fleet_scale_cooldown_s",
+    "fleet_respawn_max", "fleet_respawn_backoff_s", "fleet_warmup_timeout_s",
+    "elastic_heartbeat_s", "elastic_lease_ttl_s", "elastic_rebalance_skew",
+    "straggler_k", "straggler_m", "serving_flight_requests",
+    "fleet_flight_requests", "fleet_detector_window", "autotune_cache_size",
+    "weight_only_dequant_cache")
+
+
+def _default_of(fn, arg):
+    import inspect
+
+    return inspect.signature(fn).parameters[arg].default
+
+
+def _built():
+    """Everything the moved defaults live on, built with no arguments."""
+    from paddle_tpu.distributed.elastic import ElasticMembership
+    from paddle_tpu.distributed.env import InProcStore
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.observability.cluster import ClusterTelemetry
+    from paddle_tpu.resilience.elastic import MicroBatchRebalancer
+    from paddle_tpu.serving import FleetAutoscaler, FleetRouter, ServingEngine
+    from paddle_tpu.serving.fleet_proc import ProcessReplicaSpec
+
+    model = GPTForCausalLM(GPTConfig.tiny())
+    engine = ServingEngine(model)
+    router = FleetRouter([engine])
+    return {
+        "engine": engine, "router": router,
+        "scaler": FleetAutoscaler(router, spawn=lambda: None),
+        "spec": ProcessReplicaSpec(("127.0.0.1", 1)),
+        "membership": ElasticMembership(InProcStore(), 0, [0]),
+        "rebalancer": MicroBatchRebalancer(),
+        "cluster": ClusterTelemetry(InProcStore(), 0, 1),
+    }
+
+
+def _moved_defaults():
+    from paddle_tpu.core import autotune
+    from paddle_tpu.quantization import weight_only
+    from paddle_tpu.serving import FleetRouter, build_fleet
+    from paddle_tpu.serving.fleet_observability import FleetObservability
+    from paddle_tpu.serving.observability import ServingObservability
+
+    return [
+        # (the flag it was, how to read it off what _built() made, value)
+        ("serving_slots", lambda b: b["engine"].max_slots, 4),
+        ("serving_block_size", lambda b: b["engine"].block_size, 16),
+        ("serving_prefill_chunk", lambda b: b["engine"].prefill_chunk, 32),
+        # 0 = the model's positions (256), and a pool that holds every slot
+        # at that length: 4 * 256 / 16 + the null block
+        ("serving_max_model_len", lambda b: b["engine"].max_model_len, 256),
+        ("serving_kv_blocks", lambda b: b["engine"].num_blocks, 65),
+        ("serving_prefix_cache", lambda b: b["engine"].prefix_cache, True),
+        ("serving_prefill_bucket", lambda b: b["engine"].prefill_bucket, 16),
+        ("serving_spec_k", lambda b: b["engine"].spec_k, 0),
+        ("serving_spec_ngram", lambda b: b["engine"].spec_ngram, 3),
+        ("serving_spec_pause", lambda b: b["engine"].spec_pause, 32),
+        ("fleet_replicas", lambda b: _default_of(build_fleet, "n_replicas"), 2),
+        ("fleet_hedge_ttft_ms", lambda b: b["router"].hedge_ttft_s, 0.0),
+        ("fleet_breaker_errors", lambda b: b["router"]._breaker_cfg[0], 3),
+        ("fleet_breaker_cooldown_s",
+         lambda b: b["router"]._breaker_cfg[1], 2.0),
+        ("fleet_roles",
+         lambda b: [r.role for r in b["router"].replicas.values()], ["any"]),
+        ("fleet_drain_migrate",
+         lambda b: _default_of(FleetRouter.drain, "migrate"), False),
+        ("fleet_scale_min", lambda b: b["scaler"].min_replicas, 1),
+        ("fleet_scale_max", lambda b: b["scaler"].max_replicas, 8),
+        ("fleet_scale_hi", lambda b: b["scaler"].hi, 0.85),
+        ("fleet_scale_lo", lambda b: b["scaler"].lo, 0.25),
+        ("fleet_scale_cooldown_s", lambda b: b["scaler"].cooldown_s, 5.0),
+        ("fleet_respawn_max", lambda b: b["spec"].respawn_max, 3),
+        ("fleet_respawn_backoff_s",
+         lambda b: b["spec"].respawn_backoff_s, 0.5),
+        ("fleet_warmup_timeout_s",
+         lambda b: b["spec"].warmup_timeout_s, 60.0),
+        ("elastic_heartbeat_s", lambda b: b["membership"].heartbeat_s, 0.25),
+        ("elastic_lease_ttl_s", lambda b: b["membership"].lease_ttl_s, 1.5),
+        ("elastic_rebalance_skew", lambda b: b["rebalancer"].skew, 0.0),
+        ("straggler_k",
+         lambda b: (b["cluster"].k, b["rebalancer"].k), (2.0, 2.0)),
+        ("straggler_m", lambda b: (b["cluster"].m, b["rebalancer"].m), (3, 3)),
+        ("serving_flight_requests",
+         lambda b: (ServingObservability.FLIGHT_REQUESTS,
+                    b["engine"].obs._records.maxlen), (64, 64)),
+        ("fleet_flight_requests",
+         lambda b: (FleetObservability.FLIGHT_REQUESTS,
+                    b["router"].obs._settled.maxlen), (64, 64)),
+        ("fleet_detector_window",
+         lambda b: (FleetObservability.DETECTOR_WINDOW,
+                    b["router"].obs.window), (16, 16)),
+        ("autotune_cache_size", lambda b: autotune._CACHE_SIZE, 512),
+        # "auto": on wherever there is no int8 GEMM, which the CPU is
+        ("weight_only_dequant_cache",
+         lambda b: weight_only._dequant_cache_enabled(), True),
+    ]
+
+
+class TestFlagsAndDefaults:
+    @pytest.fixture(scope="class")
+    def built(self):
+        return _built()
+
+    def test_every_flag_is_read(self):
+        """A flag that nothing in the package reads is a setting that sets
+        nothing: its name has to appear, as a string of its own, somewhere
+        outside its definition."""
+        defined, strings = _flag_definitions_and_string_reads()
+        assert len(defined) >= 30          # the walk found the registry
+        dead = sorted(n for n in defined if n not in strings)
+        assert not dead, f"flags defined and never read: {dead}"
+
+    def test_no_removed_flag_is_defined(self):
+        defined, _ = _flag_definitions_and_string_reads()
+        assert not set(_REMOVED_FLAGS) & set(defined)
+        assert len(defined) <= 35
+        moved = {name for name, _, _ in _moved_defaults()}
+        # every removed setting that had a reader is held by a case below
+        assert moved == set(_REMOVED_FLAGS[5:])
+
+    @pytest.mark.parametrize("flag", _REMOVED_FLAGS[5:])
+    def test_default_kept_the_flags_value(self, built, flag):
+        (read, want), = [(r, w) for n, r, w in _moved_defaults() if n == flag]
+        assert read(built) == want

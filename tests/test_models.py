@@ -9,7 +9,7 @@ from paddle_tpu.vision.models import LeNet, resnet18
 
 
 def test_lenet_mnist_converges():
-    """The M0-M2 e2e slice (BASELINE configs[0])."""
+    """The M0-M2 e2e slice (LeNet on MNIST)."""
     from paddle_tpu.vision.datasets import MNIST
 
     paddle.seed(0)

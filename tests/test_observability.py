@@ -320,6 +320,8 @@ class TestFlightRecorder:
             fr.dump("test_bound", directory=d)
             payload = json.load(open(glob.glob(os.path.join(d, "*.json"))[0]))
             assert [s["step"] for s in payload["steps"]] == [6, 7, 8, 9]
+            # atomic write: no torn temp file left beside the dump
+            assert not glob.glob(os.path.join(d, "*.tmp"))
         finally:
             flags.set_flags({"flight_recorder_steps": 64})
 

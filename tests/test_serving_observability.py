@@ -486,6 +486,18 @@ class TestMetricsOffNoop:
         # health snapshot still works without metrics
         assert obs.health_snapshot()["ok"] is True
 
+    def test_outputs_identical_with_metrics_on_and_off(self, tmp_path):
+        """Recording spans, traces and gauges changes no token."""
+        prompts = [list(range(1, 9)), [7, 3, 5], list(range(20, 40))]
+        paddle.seed(5)
+        off = _engine().generate(prompts, max_new_tokens=6)
+        flags.set_flags({"metrics": "on",
+                         "metrics_dir": str(tmp_path / "metrics")})
+        paddle.seed(5)                         # the same weights again
+        on = _engine().generate(prompts, max_new_tokens=6)
+        assert spans.tail(10)                  # it really recorded
+        assert on == off
+
     def test_tick_begin_is_cheap_noop(self):
         eng = _engine()
         obs = eng.obs

@@ -43,6 +43,24 @@ def test_collective_axis_negative():
     assert not _hits(r, "collective-axis")
 
 
+def test_collective_axis_positive_partial_ppermute():
+    # a perm that is no bijection over the axis: the devices it leaves out
+    # receive zeros, a wrong result in silence
+    r = analyze(lambda x: jax.lax.ppermute(x, "dp", [(0, 1)]),
+                np.ones((4,), np.float32), axis_env={"dp": 8})
+    assert _hits(r, "collective-axis")
+
+
+def test_collective_axis_negative_ring_all_reduce():
+    # a decomposed ring all-reduce is 2*(world-1) full-cycle ppermutes over
+    # a bound axis: real communication, neither no-op nor zero-fill
+    from paddle_tpu.distributed import overlap
+
+    r = analyze(lambda x: overlap.ring_all_reduce(x, "dp", world=8),
+                np.ones((64,), np.float32), axis_env={"dp": 8})
+    assert not _hits(r, "collective-axis")
+
+
 # --------------------------------------------------------------------------
 # rule 2: dtype-promotion
 # --------------------------------------------------------------------------
@@ -281,10 +299,11 @@ def test_prefetch_effects_negative_collective_not_flagged():
 # e2e: model zoo lints clean
 # --------------------------------------------------------------------------
 
-def test_gpt_preset_is_clean():
+@pytest.mark.parametrize("preset", ["gpt", "llama", "bert"])
+def test_model_preset_is_clean(preset):
     from paddle_tpu.analysis.presets import lint_presets
 
-    for label, report in lint_presets(["gpt"]):
+    for label, report in lint_presets([preset]):
         assert not report.findings, f"{label}: {report}"
 
 

@@ -62,6 +62,30 @@ def test_flash_attention(one_chip, heads, head_dim, grad):
     _compile(fn, one_chip, qkv, qkv, qkv)
 
 
+@pytest.mark.parametrize("variant", ["segmented", "with_lse"])
+def test_flash_attention_variants(one_chip, variant):
+    """The packed-document (segment ids) and ring-attention (lse as an
+    output with its own cotangent) forms of the flash kernel, fwd + bwd."""
+    from paddle_tpu.ops.pallas.flash_attention import (
+        flash_attention_segmented, flash_attention_with_lse)
+
+    def seg_loss(q, k, v, seg):
+        o = flash_attention_segmented(q, k, v, seg, causal=True)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    def lse_loss(q, k, v):
+        o, lse = flash_attention_with_lse(q, k, v, causal=True)
+        return jnp.sum(o.astype(jnp.float32) ** 2) + jnp.sum(lse * 0.1)
+
+    qkv = ((2, 2048, 8, 128), jnp.bfloat16)
+    if variant == "segmented":
+        _compile(jax.grad(seg_loss, argnums=(0, 1, 2)), one_chip,
+                 qkv, qkv, qkv, ((2, 2048), jnp.int32))
+    else:
+        _compile(jax.grad(lse_loss, argnums=(0, 1, 2)), one_chip,
+                 qkv, qkv, qkv)
+
+
 def test_fused_adamw_flat(one_chip):
     from paddle_tpu.ops.pallas.fused_adamw import fused_adamw_update
 
@@ -236,12 +260,17 @@ def test_fused_rms_norm(one_chip, hidden, dtype, grad):
     _compile(fn, one_chip, ((8, 1024, hidden), dtype), ((hidden,), dtype))
 
 
-def test_fused_rope(one_chip):
-    from paddle_tpu.ops.pallas.rope import fused_rope
+@pytest.mark.parametrize("packed", [False, True], ids=["plain", "packed"])
+def test_fused_rope(one_chip, packed):
+    from paddle_tpu.ops.pallas.rope import fused_rope, fused_rope_packed
 
     x = ((8, 1024, 16, 128), jnp.bfloat16)
     tab = ((1024, 128), jnp.float32)
-    _compile(fused_rope, one_chip, x, x, tab, tab)
+    if packed:      # per-token positions: packed documents restart at 0
+        _compile(fused_rope_packed, one_chip, x, x, tab, tab,
+                 ((8, 1024), jnp.int32))
+    else:
+        _compile(fused_rope, one_chip, x, x, tab, tab)
 
 
 # ---- the names a device trace shows: XLA modules after the jitted function,

@@ -84,7 +84,7 @@ def _flash_chunk_supported(sq, d):
 
     bq, bk = _RING_BLOCK(sq)
     return (_flags.get_flag("use_flash_attention") and _pallas.pallas_enabled()
-            and sq % bq == 0 and sq % bk == 0 and d <= 256)
+            and (bq is None or (sq % bq == 0 and sq % bk == 0)) and d <= 256)
 
 
 def ring_attention(q, k, v, axis_name, causal=False, scale=None, rank=None):

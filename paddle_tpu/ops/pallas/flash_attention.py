@@ -9,23 +9,45 @@ softmax carried in fp32, logsumexp residual saved for a recompute backward.
 Layout: inputs are [batch, seq, heads, head_dim] (the reference layout); the
 kernel internally processes one (batch*head) slice per grid row.
 
-TPU lowering constraints shape two choices here:
-  * the logsumexp residual is stored 3-D as [bh, sq, 1] — Pallas TPU requires
-    the last two block dims to be (8,128)-aligned or equal to the full array
-    dim, so a 1-D [bh, sq] residual cannot be blocked along sq, but a size-1
-    minor dim (full) with block_q rows (8-aligned) can;
-  * delta = rowsum(dO * O) is precomputed once (an XLA fused reduce) and
-    passed to the backward kernels in the same [bh, sq, 1] layout as lse.
+The three training kernels (flash_fwd, flash_bwd_dq, flash_bwd_dkv):
+  * multiply in the dtype they are given: every MXU operand (q, k, v, dO, and
+    p and dS as operands of their products) has the dtype of the inputs,
+    every product sums in float32, and m, l, lse, delta, the accumulators
+    and every exponent are float32. bf16 inputs (amp O1) so take one MXU
+    pass a product; float32 inputs keep float32 products. The softmax scale
+    goes once on the [tile, d] q (or k) tile and once on dq / dk, never on a
+    score tile;
+  * build the causal mask only for the score tiles the diagonal crosses:
+    tiles wholly under it run a body with no iota / compare / select, tiles
+    wholly over it are not visited;
+  * walk a grid step in score tiles of block_q x block_k. `_plan` chooses
+    the tile and the step for each kernel from the shapes it is traced with
+    (sequences, head size, dtype) when the caller names no tile: where one
+    head's tiles are few, a grid step is the head's whole sequence and the
+    tile loops are unrolled into straight-line code (static bounds, which
+    the scheduler overlaps across tiles); longer sequences take larger tiles
+    in rolled loops whose bounds follow the step's place. No run tunes
+    anything.
+
+The per-row statistics (lse, delta) are stored [bh, 1, sq], rows along the
+lanes: a [bh, sq, 1] float32 array is tiled (8, 128) in HBM and so padded
+128 times (64 MiB a layer at [8, 1024, 16, .] where 512 KiB are data). The
+forward and dq kernels, whose score tiles have queries as rows, turn a
+[1, block_q] slice into a column once a q tile; the dkv kernel computes its
+score tiles transposed ([block_k, block_q] = k.qT), so that the statistics
+are rows as stored and pT.dO and dST.q are plain products with no transposed
+operand. delta = rowsum(dO * O) is precomputed once (an XLA fused reduce).
 
 Algorithm (standard online softmax):
-  fwd:  for each q block, stream k/v blocks, carry (m, l, acc); save
+  fwd:  for each q tile, stream k/v tiles, carry (m, l, acc); save
         lse = m + log(l) per row.
-  bwd:  two kernels — dQ streams K/V per q block, dK/dV streams Q/dO per
-        k block — both recompute P from Q,K,lse.
+  bwd:  two kernels — dQ streams K/V per q tile, dK/dV streams Q/dO per
+        k tile — both recompute P from Q,K,lse.
 """
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 
 import jax
@@ -37,6 +59,10 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
+_LANES = 128
+
+_NT = (((1,), (1,)), ((), ()))   # a[m, c] . b[n, c]T
+_NN = (((1,), (0,)), ((), ()))   # a[m, c] . b[c, n]
 
 
 def _sds(shape, dtype, like):
@@ -49,52 +75,155 @@ def _sds(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _to_row(col):
+    """[n, 1] -> [1, n] (through a lane-wide tile: the TPU transposes
+    those)."""
+    return jnp.broadcast_to(col, (col.shape[0], _LANES)).T[:1, :]
+
+
+def _to_col(row):
+    """[1, n] -> [n, 1]."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T[:, :1]
+
+
+def _seen(q0, k0, shape, q_axis):
+    """Causal visibility of a score tile whose first query is q0 and first
+    key k0; queries run along `q_axis` of the tile."""
+    q_ids = q0 + lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_ids = k0 + lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return q_ids >= k_ids
+
+
+def _scaled(x, scale, dtype):
+    return (x.astype(jnp.float32) * scale).astype(dtype)
+
+
+def _static(*xs):
+    return all(isinstance(x, int) for x in xs)
+
+
+def _div(a, b):
+    return a // b if _static(a) else lax.div(a, b)
+
+
+def _min(a, b):
+    return min(a, b) if _static(a, b) else jnp.minimum(a, b)
+
+
+def _k_tile_ranges(q0, block_q, block_k, nk, causal):
+    """(n_clear, n_live) for the q tile starting at row q0: k tiles
+    [0, n_clear) lie wholly under the diagonal and need no mask, tiles
+    [n_clear, n_live) are crossed by it, the rest are never seen."""
+    if not causal:
+        return nk, nk
+    n_live = _min(_div(q0 + block_q + block_k - 1, block_k), nk)
+    return _min(_div(q0 + 1, block_k), nk), n_live
+
+
+def _tile_loops(lo, mid, hi, body, masked_first, init):
+    """body(j, carry, masked) over [lo, hi), masked on one side of mid.
+    Bounds that are Python ints (an unrolled grid step, `_plan`) give
+    straight-line code, which the TPU's scheduler overlaps across tiles: the
+    same tiles in a rolled loop take three times as long (PERF.md, PR 33)."""
+    first = functools.partial(body, masked=masked_first)
+    second = functools.partial(body, masked=not masked_first)
+    if not _static(lo, mid, hi):
+        return lax.fori_loop(mid, hi, second,
+                             lax.fori_loop(lo, mid, first, init))
+    carry = init
+    for j in range(lo, hi):
+        carry = (first if j < mid else second)(j, carry)
+    return carry
+
+
+# Score tiles (block_q, block_k), in order of preference, read off chip runs
+# of each kernel alone at [8, 1024, 16, 64] and [8, 1024, 16, 128] bf16
+# (PERF.md section 6, PR 33): all three kernels want the same. Constants, so
+# nothing is timed at warm-up.
+_TILES_UNROLLED = ((256, 256), (512, 512), (128, 128))
+_TILES_ROLLED = ((512, 512), (256, 256), (128, 128))
+_UNROLLED_TILES = 64          # most score tiles a kernel unrolls for one head
+_WHOLE_BYTES = 512 * 1024     # a [s, d] operand an unrolled step holds whole
+_STEP_BYTES = 256 * 1024      # one [rows, d] operand of a rolled grid step
+
+
+def _plan(kernel, sq, sk, d, dtype, block_q=None, block_k=None):
+    """(block_q, block_k, rows, unrolled) for `kernel` ("fwd", "dq", "dkv")
+    at these shapes. block_q x block_k is the score tile (the caller's, or
+    chosen here); `rows` what a grid step covers of the sequence the kernel's
+    grid runs over (queries; keys for dkv). Where one head's tiles are few
+    and its operands small, a grid step is the whole sequence and its tile
+    loops are unrolled; else it is as many tiles as _STEP_BYTES holds, in
+    rolled loops whose bounds follow the step's place in the sequence."""
+    size = jnp.dtype(dtype).itemsize * d
+
+    def unrolls(bq, bk):
+        # tiles narrower than a lane tile (short ring chunks) have only this
+        # form on the chip: a step's statistics are sliced along the lanes,
+        # which a rolled loop can do only at multiples of 128
+        few = (sq // bq) * (sk // bk) <= _UNROLLED_TILES
+        return ((few or bq % _LANES or bk % _LANES)
+                and max(sq, sk) * size <= _WHOLE_BYTES)
+
+    def divides(tile):
+        return sq % tile[0] == 0 and sk % tile[1] == 0
+
+    if block_q is None:
+        block_q, block_k = next(
+            itertools.chain(
+                (t for t in _TILES_UNROLLED if divides(t) and unrolls(*t)),
+                (t for t in _TILES_ROLLED if divides(t))),
+            (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K))  # fails the shape gate
+    s, block = (sk, block_k) if kernel == "dkv" else (sq, block_q)
+    if unrolls(block_q, block_k):
+        return block_q, block_k, s, True
+    if block % _LANES:
+        return block_q, block_k, block, False   # interpret mode only
+    n = s // block
+    most = max(1, _STEP_BYTES // (block * size))
+    tiles = max(c for c in range(1, n + 1) if n % c == 0 and c <= most)
+    return block_q, block_k, block * tiles, False
+
+
 # ------------------------------------------------------------------- forward
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k, sk):
-    # q_ref: [block_q, d]; k_ref/v_ref: [sk, d]; o_ref: [block_q, d];
-    # lse_ref: [block_q, 1]
-    qi = pl.program_id(1)
-    block_q = q_ref.shape[0]
-    d = q_ref.shape[1]
-    q = q_ref[:].astype(jnp.float32) * scale
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
+                block_q, block_k, unrolled):
+    # q_ref/o_ref: [rows, d], the grid step's rows of one head; k_ref/v_ref:
+    # [sk, d]; lse_ref: [1, rows]
+    rows, d = q_ref.shape
+    nk = k_ref.shape[0] // block_k
+    op = q_ref.dtype
+    first = 0 if unrolled else pl.program_id(1) * rows
+    for i in range(rows // block_q):
+        q0 = first + i * block_q
+        tile = pl.ds(i * block_q, block_q)
+        q = _scaled(q_ref[tile, :], scale, op)
 
-    nk = sk // block_k
-    if causal:
-        # only k blocks whose start is <= this q block's end participate
-        q_end = (qi + 1) * block_q
-        nk_live = jax.lax.div(q_end + block_k - 1, block_k)
-        nk_live = jnp.minimum(nk_live, nk)
-    else:
-        nk_live = nk
+        def body(j, carry, masked):
+            m_prev, l_prev, acc = carry
+            k = k_ref[pl.ds(j * block_k, block_k), :]
+            v = v_ref[pl.ds(j * block_k, block_k), :]
+            s = _dot(q, k, _NT)  # [block_q, block_k]
+            if masked:
+                s = jnp.where(_seen(q0, j * block_k, s.shape, 0), s, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+            return m_new, l_new, acc * alpha + _dot(p.astype(op), v, _NN)
 
-    def body(j, carry):
-        m_prev, l_prev, acc = carry
-        k = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [block_q, block_k]
-        if causal:
-            q_ids = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_ids = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_ids >= k_ids, s, NEG_INF)
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_new = l_prev * alpha + jnp.sum(p, axis=1)
-        acc = acc * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        return m_new, l_new, acc
-
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, nk_live, body, (m0, l0, acc0))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[:] = (acc / l[:, None]).astype(o_ref.dtype)
-    lse_ref[:] = (m + jnp.log(l))[:, None]
+        n_clear, n_live = _k_tile_ranges(q0, block_q, block_k, nk, causal)
+        init = (jnp.full((block_q, 1), NEG_INF, jnp.float32),
+                jnp.zeros((block_q, 1), jnp.float32),
+                jnp.zeros((block_q, d), jnp.float32))
+        m, l, acc = _tile_loops(0, n_clear, n_live, body, False, init)
+        l = jnp.maximum(l, 1e-30)
+        o_ref[tile, :] = (acc * (1.0 / l)).astype(o_ref.dtype)
+        lse_ref[:, tile] = _to_row(m + jnp.log(l))
 
 
 def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
@@ -103,29 +232,30 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
         scale = 1.0 / math.sqrt(d)
     sk = k.shape[1]
     bh = b * h
+    block_q, block_k, rows, unrolled = _plan(
+        "fwd", sq, sk, d, q.dtype, block_q, block_k)
     # [b, s, h, d] -> [b*h, s, d]
     qr = q.transpose(0, 2, 1, 3).reshape(bh, sq, d)
     kr = k.transpose(0, 2, 1, 3).reshape(bh, sk, d)
     vr = v.transpose(0, 2, 1, 3).reshape(bh, sk, d)
 
-    grid = (bh, sq // block_q)
     out, lse = pl.pallas_call(
-        functools.partial(
-            _fwd_kernel, scale=scale, causal=causal, block_k=block_k, sk=sk
-        ),
-        grid=grid,
+        functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k,
+                          unrolled=unrolled),
+        grid=(bh, sq // rows),
         in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, rows, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, rows, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, 1, rows), lambda i, j: (i, 0, j)),
         ],
         out_shape=[
             _sds((bh, sq, d), q.dtype, qr),
-            _sds((bh, sq, 1), jnp.float32, qr),
+            _sds((bh, 1, sq), jnp.float32, qr),
         ],
         name="flash_fwd",
         interpret=interpret,
@@ -136,96 +266,79 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
 
 # ------------------------------------------------------------------ backward
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               *, scale, causal, block_k, sk):
-    qi = pl.program_id(1)
-    block_q = q_ref.shape[0]
-    d = q_ref.shape[1]
-    q = q_ref[:].astype(jnp.float32) * scale
-    do = do_ref[:].astype(jnp.float32)
-    lse = lse_ref[:]                   # [block_q, 1]
-    delta = delta_ref[:]               # [block_q, 1]
+               *, scale, causal, block_q, block_k, unrolled):
+    rows, d = q_ref.shape
+    nk = k_ref.shape[0] // block_k
+    op = q_ref.dtype
+    first = 0 if unrolled else pl.program_id(1) * rows
+    for i in range(rows // block_q):
+        q0 = first + i * block_q
+        tile = pl.ds(i * block_q, block_q)
+        q = _scaled(q_ref[tile, :], scale, op)
+        do = do_ref[tile, :]
+        lse = _to_col(lse_ref[:, tile])      # [block_q, 1]
+        delta = _to_col(delta_ref[:, tile])
 
-    nk = sk // block_k
-    if causal:
-        q_end = (qi + 1) * block_q
-        nk_live = jnp.minimum(jax.lax.div(q_end + block_k - 1, block_k), nk)
-    else:
-        nk_live = nk
+        def body(j, dq, masked):
+            k = k_ref[pl.ds(j * block_k, block_k), :]
+            v = v_ref[pl.ds(j * block_k, block_k), :]
+            s = _dot(q, k, _NT)
+            if masked:
+                s = jnp.where(_seen(q0, j * block_k, s.shape, 0), s, NEG_INF)
+            p = jnp.exp(s - lse)
+            ds = p * (_dot(do, v, _NT) - delta)
+            return dq + _dot(ds.astype(op), k, _NN)
 
-    def body(j, dq):
-        k = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        if causal:
-            q_ids = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_ids = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_ids >= k_ids, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta)
-        return dq + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    dq = jax.lax.fori_loop(0, nk_live, body, jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[:] = (dq * scale).astype(dq_ref.dtype)
+        n_clear, n_live = _k_tile_ranges(q0, block_q, block_k, nk, causal)
+        dq = _tile_loops(0, n_clear, n_live, body, False,
+                         jnp.zeros((block_q, d), jnp.float32))
+        dq_ref[tile, :] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                *, scale, causal, block_q, sq):
-    ki = pl.program_id(1)
-    block_k = k_ref.shape[0]
-    d = k_ref.shape[1]
-    k = k_ref[:].astype(jnp.float32)
-    v = v_ref[:].astype(jnp.float32)
+                *, scale, causal, block_q, block_k, unrolled):
+    # k_ref/v_ref/dk_ref/dv_ref: [rows, d], the grid step's keys of one head;
+    # q_ref/do_ref: [sq, d]; lse_ref/delta_ref: [1, sq]. Score tiles are
+    # transposed: keys are rows, queries run along the lanes.
+    rows, d = k_ref.shape
+    nq = q_ref.shape[0] // block_q
+    op = q_ref.dtype
+    first = 0 if unrolled else pl.program_id(1) * rows
+    for i in range(rows // block_k):
+        k0 = first + i * block_k
+        tile = pl.ds(i * block_k, block_k)
+        k = _scaled(k_ref[tile, :], scale, op)
+        v = v_ref[tile, :]
 
-    nq = sq // block_q
-    if causal:
-        # only q blocks whose end is past this k block's start participate
-        k_start = ki * block_k
-        j0 = jax.lax.div(k_start, block_q)
-    else:
-        j0 = 0
+        def body(j, carry, masked):
+            dk, dv = carry
+            at = pl.ds(j * block_q if _static(j)
+                       else pl.multiple_of(j * block_q, block_q), block_q)
+            q = q_ref[at, :]
+            do = do_ref[at, :]
+            s = _dot(k, q, _NT)  # [block_k, block_q]
+            if masked:
+                s = jnp.where(_seen(j * block_q, k0, s.shape, 1), s, NEG_INF)
+            p = jnp.exp(s - lse_ref[:, at])
+            dv = dv + _dot(p.astype(op), do, _NN)
+            ds = p * (_dot(v, do, _NT) - delta_ref[:, at])
+            return dk + _dot(ds.astype(op), q, _NN), dv
 
-    def body(j, carry):
-        dk, dv = carry
-        q = q_ref[pl.ds(j * block_q, block_q), :].astype(jnp.float32) * scale
-        do = do_ref[pl.ds(j * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[pl.ds(j * block_q, block_q), :]
-        delta = delta_ref[pl.ds(j * block_q, block_q), :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [block_q, block_k]
         if causal:
-            q_ids = j * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_ids = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_ids >= k_ids, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dv = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta)
-        dk = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        return dk, dv
-
-    dk0 = jnp.zeros((block_k, d), jnp.float32)
-    dv0 = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(j0, nq, body, (dk0, dv0))
-    dk_ref[:] = dk.astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
+            # q tiles [j0, j_clear) are crossed by the diagonal, tiles from
+            # j_clear on lie wholly under it, tiles before j0 see no key here
+            j0 = _div(k0, block_q)
+            j_clear = _min(_div(k0 + block_k + block_q - 2, block_q), nq)
+        else:
+            j0 = j_clear = 0
+        zero = jnp.zeros((block_k, d), jnp.float32)
+        dk, dv = _tile_loops(j0, j_clear, nq, body, True, (zero, zero))
+        dk_ref[tile, :] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[tile, :] = dv.astype(dv_ref.dtype)
 
 
 def _bwd(scale, causal, block_q, block_k, interpret, res, g, dlse=None):
-    """Backward. When `dlse` ([bh, sq, 1] fp32 cotangent of the logsumexp
+    """Backward. When `dlse` ([bh, 1, sq] fp32 cotangent of the logsumexp
     output) is given, it folds into the delta term: the score gradient is
     ds = p*(dp - delta + dlse) and d(lse)/ds = p, so passing
     delta' = delta - dlse to the unchanged kernels yields the exact joint
@@ -237,47 +350,48 @@ def _bwd(scale, causal, block_q, block_k, interpret, res, g, dlse=None):
         scale = 1.0 / math.sqrt(d)
     sk = kr.shape[1]
     do = g.transpose(0, 2, 1, 3).reshape(bh, sq, d)
-    # delta = rowsum(dO * O), fp32, same [bh, sq, 1] layout as lse
+    # delta = rowsum(dO * O), fp32, same [bh, 1, sq] layout as lse
     delta = jnp.sum(do.astype(jnp.float32) * outr.astype(jnp.float32),
-                    axis=-1, keepdims=True)
+                    axis=-1)[:, None, :]
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)
 
+    bq, bk, rows, unrolled = _plan("dq", sq, sk, d, qr.dtype, block_q, block_k)
     dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, scale=scale, causal=causal, block_k=block_k, sk=sk
-        ),
-        grid=(bh, sq // block_q),
+        functools.partial(_dq_kernel, scale=scale, causal=causal,
+                          block_q=bq, block_k=bk, unrolled=unrolled),
+        grid=(bh, sq // rows),
         in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, rows, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, rows, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, 1, rows), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((None, 1, rows), lambda i, j: (i, 0, j)),
         ],
-        out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
+        out_specs=pl.BlockSpec((None, rows, d), lambda i, j: (i, j, 0)),
         out_shape=_sds((bh, sq, d), qr.dtype, qr),
         name="flash_bwd_dq",
         interpret=interpret,
     )(qr, kr, vr, do, lse, delta)
 
+    bq, bk, rows, unrolled = _plan("dkv", sq, sk, d, qr.dtype, block_q,
+                                   block_k)
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, scale=scale, causal=causal, block_q=block_q, sq=sq
-        ),
-        grid=(bh, sk // block_k),
+        functools.partial(_dkv_kernel, scale=scale, causal=causal,
+                          block_q=bq, block_k=bk, unrolled=unrolled),
+        grid=(bh, sk // rows),
         in_specs=[
             pl.BlockSpec((None, sq, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, rows, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, rows, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, sq, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, sq, 1), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, sq, 1), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, 1, sq), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, 1, sq), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, rows, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, rows, d), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
             _sds((bh, sk, d), kr.dtype, qr),
@@ -297,10 +411,12 @@ def _bwd(scale, causal, block_q, block_k, interpret, res, g, dlse=None):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(
     q, k, v, scale=None, causal=False,
-    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K, interpret=False,
+    block_q=None, block_k=None, interpret=False,
 ):
     """Flash attention on [b, s, h, d] inputs. Differentiable (custom VJP with
-    Pallas backward). Requires seq lengths divisible by the block sizes."""
+    Pallas backward). block_q x block_k is the score tile of all three
+    kernels; left None, each kernel takes its own from the shapes (`_plan`).
+    Requires seq lengths divisible by the tile sides."""
     o, _ = _fwd(q, k, v, scale, causal, block_q, block_k, interpret)
     return o
 
@@ -320,7 +436,7 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # ------------------------------------------- platform-deferred entry point
 def _dense_fwd(q, k, v, scale, causal):
     """XLA forward producing residuals in the SAME kernel layout as _fwd
-    ((qr, kr, vr, out, lse) with [bh, s, d] / [bh, sq, 1] fp32 lse), so a
+    ((qr, kr, vr, out, lse) with [bh, s, d] / [bh, 1, sq] fp32 lse), so a
     lax.platform_dependent can pick pallas-vs-XLA per lowering target."""
     b, sq, h, d = q.shape
     sc = 1.0 / math.sqrt(d) if scale is None else scale
@@ -337,7 +453,7 @@ def _dense_fwd(q, k, v, scale, causal):
     p = jnp.exp(s - lse)
     out = jnp.einsum("bqk,bkd->bqd", p, vr.astype(jnp.float32)).astype(q.dtype)
     o = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-    return o, (qr, kr, vr, out, lse)
+    return o, (qr, kr, vr, out, lse.reshape(bh, 1, sq))
 
 
 def _dense_bwd(scale, causal, res, g, dlse=None):
@@ -352,12 +468,12 @@ def _dense_bwd(scale, causal, res, g, dlse=None):
                    kr.astype(jnp.float32)) * sc
     if causal:
         s = jnp.where(jnp.tril(jnp.ones((sq, sk), bool))[None], s, -1e30)
-    p = jnp.exp(s - lse)
+    p = jnp.exp(s - lse.reshape(bh, sq, 1))
     dv = jnp.einsum("bqk,bqd->bkd", p, do)
     dp = jnp.einsum("bqd,bkd->bqk", do, vr.astype(jnp.float32))
     delta = jnp.sum(do * outr.astype(jnp.float32), -1, keepdims=True)
     if dlse is not None:
-        delta = delta - dlse.astype(jnp.float32)
+        delta = delta - dlse.astype(jnp.float32).reshape(bh, sq, 1)
     ds = p * (dp - delta)
     dq = jnp.einsum("bqk,bkd->bqd", ds, kr.astype(jnp.float32)) * sc
     dk = jnp.einsum("bqk,bqd->bkd", ds, qr.astype(jnp.float32)) * sc
@@ -369,7 +485,7 @@ def _dense_bwd(scale, causal, res, g, dlse=None):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention_platform(q, k, v, scale=None, causal=False,
-                             block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
+                             block_q=None, block_k=None):
     """flash_attention whose pallas-vs-XLA choice happens at LOWERING time
     (lax.platform_dependent): a program exported for 'tpu' from any host
     embeds the Mosaic kernel, while the same trace stays runnable on CPU.
@@ -708,7 +824,7 @@ flash_attention_segmented.defvjp(_seg_fwd_rule, _seg_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention_with_lse(
     q, k, v, scale=None, causal=False,
-    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K, interpret=False,
+    block_q=None, block_k=None, interpret=False,
 ):
     """Flash attention that ALSO returns the per-row logsumexp as a
     first-class differentiable output: (o [b,sq,h,d], lse [b,h,sq] fp32).
@@ -735,7 +851,7 @@ def _flash_lse_bwd_rule(scale, causal, block_q, block_k, interpret, res, g):
     do, dlse = g
     bh, sq, _ = res[0].shape
     return _bwd(scale, causal, block_q, block_k, interpret, res, do,
-                dlse=dlse.reshape(bh, sq, 1))
+                dlse=dlse.reshape(bh, 1, sq))
 
 
 flash_attention_with_lse.defvjp(_flash_lse_fwd_rule, _flash_lse_bwd_rule)
@@ -837,7 +953,9 @@ def flash_attention_prefill(q, k, v, q_offset, *, scale=None, window=None,
 
 def supports(q_shape, k_shape, attn_mask, dropout_p, is_causal=False,
              block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K) -> bool:
-    """Shape gate: fall back to the XLA composition otherwise.
+    """Shape gate: fall back to the XLA composition otherwise. The default
+    blocks are the smallest tiles `_plan` falls back to, so every sequence
+    they divide has a tile.
 
     Causal with sq != sk is rejected: this kernel's mask is top-left aligned
     (absolute q_id >= k_id) while the sdpa fallback is bottom-right aligned
@@ -858,10 +976,13 @@ def supports(q_shape, k_shape, attn_mask, dropout_p, is_causal=False,
 
 
 def _RING_BLOCK(s_local):
-    """Block sizes for ring-chunk flash: the TPU-native (128, 128) when the
-    local shard is big enough, else the largest 8-aligned divisor so small
-    CPU-mesh parity tests still route through the kernel (interpret mode)."""
-    for b in (DEFAULT_BLOCK_Q, 64, 32, 16, 8):
+    """Block sizes for ring-chunk flash: the kernels' own choice when the
+    local shard is a multiple of the TPU-native 128, else the largest
+    8-aligned divisor so small CPU-mesh parity tests still route through the
+    kernel (interpret mode)."""
+    if s_local % DEFAULT_BLOCK_Q == 0 and s_local >= DEFAULT_BLOCK_Q:
+        return None, None  # each kernel's own tile, from the shapes
+    for b in (64, 32, 16, 8):
         if s_local % b == 0 and s_local >= b:
             return b, b
     return DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K  # will fail the divisibility gate
@@ -870,8 +991,13 @@ def _RING_BLOCK(s_local):
 # ---- autotuned entry (reference: phi autotune cache + switch_autotune) ----
 from ...core.autotune import autotune as _autotune  # noqa: E402
 
+# The first candidate is what runs unless FLAGS_use_autotune is on and the call
+# is eager: None leaves each of the three kernels the tile `_plan` derives
+# from its shapes. The others are explicit tiles, the same for all three, for
+# a tuning run to time against it.
 _BLOCK_CANDIDATES = [
-    {"block_q": DEFAULT_BLOCK_Q, "block_k": DEFAULT_BLOCK_K},  # default 1st
+    {"block_q": None, "block_k": None},
+    {"block_q": 128, "block_k": 128},
     {"block_q": 256, "block_k": 256},
     {"block_q": 512, "block_k": 256},
     {"block_q": 256, "block_k": 512},
@@ -887,7 +1013,7 @@ def flash_attention_tuned(q, k, v, scale=None, causal=False, interpret=False,
     """flash_attention with block sizes chosen by the autotune cache when
     FLAGS_use_autotune is on (invalid candidates — seq not divisible by the
     block — are skipped by the tuner); otherwise the hand-picked defaults."""
-    if q.shape[1] % block_q or k.shape[1] % block_k:
+    if block_q and (q.shape[1] % block_q or k.shape[1] % block_k):
         raise ValueError("block does not divide sequence")  # tuner skips
     return flash_attention(q, k, v, scale, causal, block_q, block_k, interpret)
 
@@ -899,6 +1025,6 @@ def flash_attention_platform_tuned(q, k, v, scale=None, causal=False,
                                    *, block_q, block_k):
     """flash_attention_platform (lowering-time pallas/XLA choice) with the
     same autotuned block-size selection as flash_attention_tuned."""
-    if q.shape[1] % block_q or k.shape[1] % block_k:
+    if block_q and (q.shape[1] % block_q or k.shape[1] % block_k):
         raise ValueError("block does not divide sequence")  # tuner skips
     return flash_attention_platform(q, k, v, scale, causal, block_q, block_k)

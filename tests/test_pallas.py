@@ -4,6 +4,9 @@ Reference test model: the flash_attn op tests in test/legacy_test/ compare the
 fused kernel against the unfused composition for fwd values and analytic
 grads; same structure here (SURVEY.md §4).
 """
+import functools
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +16,9 @@ from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.fused_adamw import fused_adamw_update
 from paddle_tpu.ops.pallas.fused_norm import fused_rms_norm
 from paddle_tpu.ops.pallas.rope import fused_rope
+
+# the package exports the function under the module's name
+_fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 
 B, S, H, D = 2, 256, 4, 64
 
@@ -55,6 +61,141 @@ def test_flash_attention_grads(causal):
     g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(a, b, atol=0.15, rtol=5e-2)
+
+
+# ---- the train kernels' inner loop: operands in the dtype of the inputs,
+# ---- the causal mask only on crossed tiles, a grid step of several tiles.
+# At 512 positions tiles of 128 and 256 give, per kernel, tiles wholly under
+# the diagonal, tiles it crosses corner to corner and (unequal sides) tiles it
+# crosses only in part. The reference is float32 on the same (rounded) inputs.
+# float32 inputs: the tolerances of the segmented kernel's float32 test. bf16:
+# twice what the kernels before PR 33 (float32 casts of every operand) read on
+# these cases in interpret mode, output 7.3e-3 and gradients 1.2e-2; lse they
+# read to 1e-6, and so does this one at head size 64, where the scale is a
+# power of two and q.kT exact; at 128 the scaled q tile is rounded to bf16
+# once (1.8e-3 read).
+_S = 512
+_TOL = {"float32": {"o": 2e-5, "grad": 2e-4, "lse": 2e-5},
+        "bfloat16": {"o": 1.5e-2, "grad": 2.5e-2, "lse": 2e-5}}
+_LSE_TOL_ROUNDED_SCALE = 4e-3
+
+
+@functools.lru_cache(maxsize=None)
+def _inner_loop_case(dtype, d, causal, blocks, lse_weight, s=_S):
+    rng = np.random.default_rng(7)
+    mk = lambda: jnp.asarray(rng.standard_normal((1, s, 2, d)),
+                             jnp.float32).astype(dtype)
+    q, k, v = mk(), mk(), mk()
+    w = jnp.asarray(rng.standard_normal((1, s, 2, d)), jnp.float32)
+    u = lse_weight * jnp.asarray(rng.standard_normal((1, 2, s)), jnp.float32)
+    f32 = lambda x: x.astype(jnp.float32)
+
+    def ref(q, k, v):
+        sc = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        precision="highest") / np.sqrt(d)
+        if causal:
+            sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc, -1e30)
+        lse = jax.nn.logsumexp(sc, -1)
+        o = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(sc - lse[..., None]), v,
+                       precision="highest")
+        return o, lse
+
+    def loss(fn):
+        def f(q, k, v):
+            o, lse = fn(q, k, v)
+            return jnp.sum(f32(o) * w) + jnp.sum(lse * u), (o, lse)
+        return jax.value_and_grad(f, (0, 1, 2), has_aux=True)
+
+    (_, (o, lse)), g = loss(lambda q, k, v: _fa.flash_attention_with_lse(
+        q, k, v, None, causal, *blocks, True))(q, k, v)
+    (_, (o_r, lse_r)), g_r = loss(ref)(f32(q), f32(k), f32(v))
+    assert o.dtype == q.dtype and all(x.dtype == q.dtype for x in g)
+    assert lse.dtype == jnp.float32
+    return (f32(o), lse, [f32(x) for x in g]), (o_r, lse_r, g_r)
+
+
+@pytest.mark.parametrize("what", ["forward", "grads"])
+@pytest.mark.parametrize("blocks", [(128, 128), (128, 256), (256, 128),
+                                    (None, None)],
+                         ids=["128x128", "128x256", "256x128", "derived"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_flash_inner_loop_parity(dtype, d, causal, blocks, what):
+    _assert_parity(_inner_loop_case(dtype, d, causal, blocks, 0.0),
+                   dtype, d, what)
+
+
+def _assert_parity(case, dtype, d, what):
+    (o, lse, g), (o_r, lse_r, g_r) = case
+    tol = _TOL[dtype]
+    if what == "forward":
+        np.testing.assert_allclose(o, o_r, atol=tol["o"], rtol=tol["o"])
+        lse_tol = _LSE_TOL_ROUNDED_SCALE if (dtype, d) == ("bfloat16", 128) \
+            else tol["lse"]
+        np.testing.assert_allclose(lse, lse_r, atol=lse_tol, rtol=lse_tol)
+    else:
+        for a, b in zip(g, g_r):
+            np.testing.assert_allclose(a, b, atol=tol["grad"],
+                                       rtol=tol["grad"])
+
+
+@pytest.mark.parametrize("what", ["forward", "grads"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("dtype,d,blocks", [
+    ("float32", 128, (128, 384)), ("float32", 128, (384, 128)),
+    ("bfloat16", 64, (128, 128))], ids=["f32-128x384", "f32-384x128",
+                                        "bf16-128x128"])
+def test_flash_rolled_steps_parity(dtype, d, causal, blocks, what):
+    """The same inner loop where a head is not unrolled (too many tiles, or
+    operands too large to hold whole): several grid steps a head, several
+    tiles a step, loop bounds that follow the step's place."""
+    fa = _fa
+    s = 1152
+    for kernel in ("fwd", "dq", "dkv"):
+        assert not fa._plan(kernel, s, s, d, dtype, *blocks)[3]
+    if blocks == (128, 384):     # three steps a head, three tiles a step
+        assert fa._plan("fwd", s, s, d, dtype, *blocks)[2] == 384
+    _assert_parity(_inner_loop_case(dtype, d, causal, blocks, 0.0, s),
+                   dtype, d, what)
+
+
+@pytest.mark.parametrize("blocks", [(128, 256), (None, None)],
+                         ids=["128x256", "derived"])
+@pytest.mark.parametrize("d", [64, 128])
+def test_flash_with_lse_cotangent_bf16(d, blocks):
+    """Ring attention's chunk kernel: a non-zero cotangent of the lse output
+    folds into delta (float32) and reaches dq and dk."""
+    (_, _, g), (_, _, g_r) = _inner_loop_case("bfloat16", d, True, blocks, 1.0)
+    (_, _, g0), _ = _inner_loop_case("bfloat16", d, True, blocks, 0.0)
+    tol = _TOL["bfloat16"]["grad"]
+    for a, b in zip(g, g_r):
+        np.testing.assert_allclose(a, b, atol=tol, rtol=tol)
+    assert float(jnp.max(jnp.abs(g[0] - g0[0]))) > 10 * tol  # dlse did reach dq
+
+
+def test_flash_tiles_are_derived_from_shapes_and_divide_them():
+    """No tuning run: each kernel's tile and grid step are constants of the
+    shapes, every sequence the gate admits has them, and a grid step holds
+    whole tiles."""
+    fa = _fa
+    for s in (128, 384, 640, 1024, 2048, 4096, 8192):
+        assert fa.supports((2, s, 4, 64), (2, s, 4, 64), None, 0.0, True)
+        for kernel in ("fwd", "dq", "dkv"):
+            for d, dt in ((64, jnp.bfloat16), (128, jnp.bfloat16),
+                          (128, jnp.float32)):
+                bq, bk, rows, unrolled = fa._plan(kernel, s, s, d, dt)
+                assert s % bq == 0 and s % bk == 0 and s % rows == 0
+                assert rows % (bk if kernel == "dkv" else bq) == 0
+                assert unrolled == (rows == s and (s // bq) * (s // bk) <= 64)
+    # the train cell and the published 2,048 positions: a head a grid step
+    assert fa._plan("fwd", 1024, 1024, 64, jnp.bfloat16)[2:] == (1024, True)
+    assert fa._plan("dkv", 2048, 2048, 128, jnp.bfloat16)[2:] == (2048, True)
+    # a caller's tile is kept; ring chunks narrower than a lane tile compile
+    # for the chip only unrolled
+    assert fa._plan("dq", 1024, 1024, 64, jnp.float32, 128, 256)[:2] == (128, 256)
+    assert fa._plan("fwd", 192, 192, 64, jnp.float32, 64, 64) == (64, 64, 192, True)
+    assert fa._BLOCK_CANDIDATES[0] == {"block_q": None, "block_k": None}
 
 
 class TestSegmentedFlash:

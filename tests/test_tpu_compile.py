@@ -62,6 +62,100 @@ def test_flash_attention(one_chip, heads, head_dim, grad):
     _compile(fn, one_chip, qkv, qkv, qkv)
 
 
+_TRAIN_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+def _mosaic_modules(lowered_text):
+    """{kernel name: its Mosaic module as text} of a lowered program: each
+    tpu_custom_call carries its module as bytecode, ops under the
+    `stable_mosaic.` prefix of the serialized form."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    out = {}
+    with ctx:
+        for body in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                               lowered_text):
+            text = str(ir.Module.parse(base64.b64decode(body)))
+            out[re.match(r"module @(\w+)", text).group(1)] = text
+    return out
+
+
+def _matmul_operands(module_text):
+    """[(lhs type, rhs type, the op's line)] of a Mosaic module's matmuls."""
+    ops = [ln for ln in module_text.splitlines()
+           if '"stable_mosaic.tpu.matmul"' in ln]
+    return [re.search(r": \((vector<[^>]+>), (vector<[^>]+>), ", ln).groups()
+            + (ln,) for ln in ops]
+
+
+def _flash_grad_lowered(one_chip, shape, dtype):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=True)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x)
+
+
+@pytest.mark.parametrize("shape", [
+    (8, 1024, 16, 64), (8, 1024, 16, 128), (8, 2048, 16, 64),
+    (8, 2048, 16, 128), (2, 4096, 8, 128), (1, 8192, 8, 64)],
+    ids=lambda s: "x".join(map(str, s)))
+def test_train_flash_kernels_multiply_in_bf16(one_chip, shape):
+    """bf16 in (amp O1: the train cell's [8, 1024, 16, 64], and head size 128,
+    at the cell's 1,024 positions and the published 2,048, a head a grid step
+    and unrolled; 4,096 and 8,192 positions in rolled loops): every product
+    of the three train kernels takes bf16 operands into a float32 sum, none
+    has a transposed left operand, and at the tiles the kernels choose for
+    these shapes they compile under the scoped-VMEM limit."""
+    lowered = _flash_grad_lowered(one_chip, shape, jnp.bfloat16)
+    modules = _mosaic_modules(lowered.as_text())
+    assert sorted(modules) == sorted(_TRAIN_KERNELS)
+    for name, text in modules.items():
+        matmuls = _matmul_operands(text)
+        assert matmuls, name
+        for lhs, rhs, ln in matmuls:
+            assert lhs.endswith("xbf16>") and rhs.endswith("xbf16>"), (name, ln)
+            assert ln.rstrip().endswith("xf32>"), (name, ln)
+            assert "transpose_lhs = false" in ln, (name, ln)
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def test_train_flash_kernels_keep_float32_products(one_chip):
+    """float32 in: the same kernels, the dtype read off the refs, multiply
+    in float32 (the eager tests and float32 ring chunks)."""
+    lowered = _flash_grad_lowered(one_chip, (2, 1024, 4, 64), jnp.float32)
+    modules = _mosaic_modules(lowered.as_text())
+    assert sorted(modules) == sorted(_TRAIN_KERNELS)
+    for name, text in modules.items():
+        for lhs, rhs, ln in _matmul_operands(text):
+            assert lhs.endswith("xf32>") and rhs.endswith("xf32>"), (name, ln)
+    lowered.compile()
+
+
+@pytest.mark.parametrize("seq,block", [(192, 64), (96, 32), (64, 64)])
+def test_ring_chunks_narrower_than_a_lane_tile(one_chip, seq, block):
+    """Ring attention's local shards need not be multiples of 128
+    (`_RING_BLOCK` then names a tile of 64, 32, 16 or 8): the chunk kernel
+    with its lse cotangent still compiles for the chip, its statistics
+    sliced along the lanes at static places."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_with_lse
+
+    def loss(q, k, v):
+        o, lse = flash_attention_with_lse(q, k, v, None, True, block, block)
+        return jnp.sum(o.astype(jnp.float32) ** 2) + jnp.sum(lse * 0.1)
+
+    x = ((2, seq, 4, 64), jnp.bfloat16)
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, x, x, x)
+
+
 @pytest.mark.parametrize("variant", ["segmented", "with_lse"])
 def test_flash_attention_variants(one_chip, variant):
     """The packed-document (segment ids) and ring-attention (lse as an

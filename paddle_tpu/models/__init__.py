@@ -4,6 +4,12 @@ from . import llama  # noqa: F401
 from .llama import LlamaConfig, LlamaForCausalLM, LlamaModel  # noqa: F401
 from . import laguna  # noqa: F401
 from .laguna import LagunaConfig, LagunaForCausalLM, LagunaModel  # noqa: F401
+from . import glm_moe_lite  # noqa: F401
+from .glm_moe_lite import (  # noqa: F401
+    GlmMoeLiteConfig,
+    GlmMoeLiteForCausalLM,
+    GlmMoeLiteModel,
+)
 from . import bert  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig,

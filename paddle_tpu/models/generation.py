@@ -37,7 +37,10 @@ class LayerCacheSpec:
     model and whatever holds its cache (generate() below, the serving
     engine). `kind` "full": keys and values of every earlier position;
     "window": of the last `window` positions only, so a holder may keep
-    no more. `counters`: int32 counters the layer adds up beside its cache
+    no more; "latent": ONE array of every earlier position, `head_dim` wide
+    under one head (a compressed key-value latent and its rotary key, from
+    which the layer's attention makes keys and values: no V is kept).
+    `counters`: int32 counters the layer adds up beside its cache
     (a sparse layer's pairs by expert); the serving engine keeps them on the
     device and hands them to the layer as `cache.counters`."""
 
@@ -48,16 +51,19 @@ class LayerCacheSpec:
     counters: int = 0
 
     def __post_init__(self):
-        if self.kind not in ("full", "window"):
-            raise ValueError(f"cache kind {self.kind!r}: full or window")
+        if self.kind not in ("full", "window", "latent"):
+            raise ValueError(f"cache kind {self.kind!r}: full, window or "
+                             f"latent")
         if (self.kind == "window") != (self.window > 0):
             raise ValueError("a window layer states its window, a full "
                              "layer none")
+        if self.kind == "latent" and self.kv_heads != 1:
+            raise ValueError("a latent layer keeps one array under one head")
 
     @property
-    def group(self):
-        """Layers that share a block table: same kind, same window."""
-        return (self.kind, self.window)
+    def arrays(self) -> int:
+        """Arrays a holder keeps for the layer: K and V, or the one latent."""
+        return 1 if self.kind == "latent" else 2
 
 
 @dataclass(frozen=True)
@@ -75,12 +81,13 @@ def uniform_cache_spec(num_layers: int, num_kv_heads: int, head_dim: int,
 
 def init_kv_cache(batch: int, max_len: int, spec: CacheSpec,
                   dtype=jnp.float32):
-    """Allocate the per-layer static KV buffers [batch, max_len, kv_heads,
-    head_dim]: list of (k, v) arrays. A window layer's is as long as the
-    others: its attention masks what lies before the window."""
+    """Allocate the per-layer static buffers [batch, max_len, kv_heads,
+    head_dim]: a list of (k, v) arrays, or (latent,) for a latent layer. A
+    window layer's is as long as the others: its attention masks what lies
+    before the window."""
     return [
-        (jnp.zeros((batch, max_len, l.kv_heads, l.head_dim), dtype),
-         jnp.zeros((batch, max_len, l.kv_heads, l.head_dim), dtype))
+        tuple(jnp.zeros((batch, max_len, l.kv_heads, l.head_dim), dtype)
+              for _ in range(l.arrays))
         for l in spec.layers
     ]
 
@@ -117,8 +124,9 @@ class GenerationMixin:
 
     Subclass contract (GPTForCausalLM / LlamaForCausalLM /
     LagunaForCausalLM):
-      * `cache_spec() -> CacheSpec`: per layer, what it keeps (full or
-        window, K/V heads, head size), and the positions the model allows
+      * `cache_spec() -> CacheSpec`: per layer, what it keeps (full,
+        window or latent; K/V heads, head size), and the positions the
+        model allows
       * forward threading as above with static-shape caches.
     """
 
@@ -141,11 +149,11 @@ class GenerationMixin:
                     p.stop_gradient = True
                 for b, v in zip(buffers, buffer_vals):
                     b._value = v
-                caches_t = [(Tensor(k), Tensor(v)) for k, v in caches]
+                caches_t = [tuple(Tensor(a) for a in c) for c in caches]
                 logits, new_caches = self.forward(
                     Tensor(ids), caches=caches_t, pos=Tensor(pos))
                 return logits._value, [
-                    (k._value, v._value) for k, v in new_caches]
+                    tuple(a._value for a in c) for c in new_caches]
             finally:
                 for p, (v, sg) in zip(params, saved_p):
                     p._value, p.stop_gradient = v, sg

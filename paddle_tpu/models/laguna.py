@@ -341,9 +341,10 @@ class LagunaModel(nn.Layer):
             pv = pos._value if isinstance(pos, Tensor) else jnp.asarray(pos)
             if pv.ndim:
                 raise NotImplementedError(
-                    "LagunaModel: a prefill batch with an offset a row "
-                    "(the engine's batched prefill) is not written; the "
-                    "engine turns it off for a model with window layers")
+                    f"{type(self).__name__}: a prefill batch with an offset "
+                    f"a row (the engine's batched prefill) is not written; "
+                    f"the engine turns it off for a model with window or "
+                    f"latent layers")
             start = jnp.broadcast_to(pv.astype(jnp.int32), (b, 1))
             pos = Tensor(pv.astype(jnp.int32))
         positions = Tensor(start + ar)

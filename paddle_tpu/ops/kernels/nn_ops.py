@@ -872,7 +872,7 @@ def rotary_from_positions(q, k, positions, inv_freq, factor=1.0):
 
 # ------------------------------------------------ sparse experts (dropless)
 def moe_experts(x, router_w, w13, w2, expert_lo=0, top_k=1, scale=1.0,
-                norm_topk=True):
+                norm_topk=True, scoring="softmax", select_bias=None):
     """The routed experts of a dropless top-k mixture-of-experts layer, for
     the experts whose weights are HERE: a chip's share under expert
     parallelism, or all of them.
@@ -881,8 +881,12 @@ def moe_experts(x, router_w, w13, w2, expert_lo=0, top_k=1, scale=1.0,
     [held, hidden, 2 * width] (an expert's gate and up projections, gate
     columns first); w2 [held, width, hidden]. The held experts are ids
     expert_lo .. expert_lo + held - 1 of the router's. Routing is over ALL
-    the router's experts: softmax in float32, the top_k largest, normalised
-    to sum 1 (norm_topk) and times `scale`. Every (token, expert) pair whose
+    the router's experts: scores in float32 (`scoring`: "softmax" over the
+    experts, or "sigmoid" of each logit), the top_k largest, their scores
+    normalised to sum 1 (norm_topk) and times `scale`. `select_bias`
+    [experts published] is added to the scores for the CHOICE alone: the
+    weights are the chosen experts' scores without it (the selection bias of
+    auxiliary-loss-free balancing). Every (token, expert) pair whose
     expert is held is computed, none is dropped; a pair whose expert lives
     elsewhere adds nothing here (on one chip there is no exchange, and no
     code stands in for one).
@@ -901,7 +905,19 @@ def moe_experts(x, router_w, w13, w2, expert_lo=0, top_k=1, scale=1.0,
     held, width = w2.shape[0], w2.shape[1]
     with jax.named_scope("router"):
         logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
-        top, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        if scoring == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+        elif scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        else:
+            raise ValueError(f"moe_experts: scoring {scoring!r}: softmax "
+                             f"or sigmoid")
+        if select_bias is None:
+            top, idx = lax.top_k(scores, top_k)
+        else:
+            _, idx = lax.top_k(
+                scores + select_bias.astype(jnp.float32)[None, :], top_k)
+            top = jnp.take_along_axis(scores, idx, axis=-1)
         if norm_topk:
             top = top / jnp.sum(top, axis=-1, keepdims=True)
         top = top * scale
@@ -1136,6 +1152,97 @@ def paged_cached_attention(q, k, v, k_pages, v_pages, block_table, seq_lens,
         out = _paged_xla_multi(q, k_pages, v_pages, block_table, seq_lens,
                                scale)
     return out, k_pages, v_pages
+
+
+# --------------------------------------------------- latent attention (MLA)
+def _latent_scores_xla(q, latent, live, scale, v_dim):
+    """q [b, sq, h, w] over latent [b, sk, w] under `live` [b, sq, sk]:
+    softmax in float32 of q . latent, then the sum over the first `v_dim`
+    values of the same rows. The masked XLA composition."""
+    lat = latent.astype(jnp.float32)
+    sc = jnp.einsum("bqhw,bkw->bhqk", q.astype(jnp.float32), lat) * scale
+    p = jax.nn.softmax(jnp.where(live[:, None], sc, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkv->bqhv", p, lat[..., :v_dim]).astype(q.dtype)
+
+
+def latent_cached_attention(q, new, cache, pos, v_dim, scale):
+    """Attention in the latent space over a contiguous latent cache: the
+    absorbed form of multi-head latent attention, a chunk of queries at a
+    scalar offset (prefill through a workspace, generate()).
+
+    q [b, sq, heads, w]: absorbed queries, a head's [q_nope W_uk, q_rope];
+    new [b, sq, w]: the chunk's latents [c, r], after the norm and the
+    rotation; cache [b, max_len, 1, w]; pos: scalar int32, tokens already in
+    the cache. Writes `new` at [pos, pos + sq), then query i sees keys
+    <= pos + i: score q . cache row over all w values, the weighted sum over
+    the first v_dim values of the same rows (every head reads the one
+    latent). Returns (out [b, sq, heads, v_dim], cache). On the TPU, for
+    chunks and caches in whole tiles, the kernel of
+    ops/pallas/flash_attention.py (`latent_prefill`); else the masked XLA
+    composition."""
+    b, sq, h, w = q.shape
+    pos = jnp.asarray(pos, jnp.int32).reshape(())
+    with jax.named_scope("kv_append"):
+        cache = jax.lax.dynamic_update_slice(
+            cache, new[:, :, None, :].astype(cache.dtype), (0, pos, 0, 0))
+    from .. import pallas as _pallas
+    from ..pallas.flash_attention import (
+        latent_prefill,
+        latent_prefill_supports as prefill_supports,
+    )
+
+    if prefill_supports(q.shape, cache.shape, v_dim) and (
+            _pallas.interpret_mode() or jax.default_backend() == "tpu"):
+        out = latent_prefill(q, cache[:, :, 0], pos, v_dim=v_dim,
+                             scale=scale, interpret=_pallas.interpret_mode())
+        return out, cache
+    rows = pos + jnp.arange(sq)[:, None]
+    live = jnp.arange(cache.shape[1])[None, :] <= rows          # [sq, sk]
+    out = _latent_scores_xla(q, cache[:, :, 0], live[None], scale, v_dim)
+    return out, cache
+
+
+def latent_paged_attention(q, new, pages, block_table, seq_lens, v_dim,
+                           scale):
+    """One decode step of absorbed latent attention over PAGED latents (the
+    serving engine's per-step op for a layer of cache kind "latent").
+
+    q [slots, 1, heads, w]; new [slots, 1, w]; pages [num_blocks, 1,
+    block_size, w] (the layout and the append of paged_cached_attention,
+    one array and one head); block_table [slots, max_blocks]; seq_lens
+    [slots]. Appends `new` at each slot's next position, then each slot's
+    query attends over its own context: a page is read once, for the scores
+    (all w values) and for the weighted sum (its first v_dim). Returns
+    (out [slots, 1, heads, v_dim], pages). The `latent_decode` kernel on
+    the TPU and in interpret mode, the XLA gather composition elsewhere."""
+    slots, sq, h, w = q.shape
+    if sq != 1:
+        raise NotImplementedError(
+            "latent_paged_attention: a multi-token verify window over "
+            "latent pages is not written")
+    bs = pages.shape[2]
+    seq_lens = jnp.asarray(seq_lens, jnp.int32).reshape(slots)
+    bt = block_table.astype(jnp.int32)
+    with jax.named_scope("kv_append"):
+        page_idx = seq_lens // bs
+        page = jnp.where(
+            page_idx < bt.shape[1],
+            jnp.take_along_axis(
+                bt, jnp.minimum(page_idx, bt.shape[1] - 1)[:, None],
+                axis=1)[:, 0], 0)                   # overflow -> null page
+        pages = pages.at[page, 0, seq_lens % bs].set(
+            new[:, 0].astype(pages.dtype))
+    ctx = seq_lens + 1
+    from .. import pallas as _pallas
+    from ..pallas.paged_attention import from_pages, latent_decode
+
+    if _pallas.interpret_mode() or jax.default_backend() == "tpu":
+        out = latent_decode(q[:, 0], pages, bt, ctx, v_dim=v_dim,
+                            scale=scale, interpret=_pallas.interpret_mode())
+        return out[:, None], pages
+    lat = from_pages(pages[bt])[:, :, 0]             # [slots, max_ctx, w]
+    live = jnp.arange(lat.shape[1])[None, :] < ctx[:, None]
+    return _latent_scores_xla(q, lat, live[:, None], scale, v_dim), pages
 
 
 def softsign(x):

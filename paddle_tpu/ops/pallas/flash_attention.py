@@ -951,6 +951,125 @@ def flash_attention_prefill(q, k, v, q_offset, *, scale=None, window=None,
     return out.reshape(b, hq, sq, d).transpose(0, 2, 1, 3)
 
 
+# ------------------------------------- serving prefill over a latent cache
+LATENT_BLOCK_Q = 128
+LATENT_BLOCK_K = 512
+
+
+def _latent_prefill_kernel(off_ref, q_ref, c_ref, o_ref, m_s, l_s, acc_s, *,
+                           scale, block_q, block_k, v_dim):
+    # One (query block, key block) of a chunk's absorbed latent attention.
+    # off_ref [1] (scalar prefetch): the absolute position of the chunk's
+    # first query. q_ref [heads * block_q, w]: every head's queries of the
+    # block, head-major (row r is head r // block_q, token r % block_q), so
+    # the one latent block c_ref [block_k, w] is read once for all heads.
+    # Scores over all w values, the weighted sum over the first v_dim of
+    # the same rows. m_s, l_s [rows, 1], acc_s [rows, v_dim]: the online
+    # softmax across the key blocks, which are the grid's last axis. Key
+    # blocks after the diagonal are not computed (nor fetched: the index
+    # map holds on to the last block that is).
+    qi, kj = pl.program_id(0), pl.program_id(1)
+    q_lo = off_ref[0] + qi * block_q
+    hi = jax.lax.div(q_lo + block_q - 1, block_k)
+
+    @pl.when(kj == 0)
+    def _():
+        m_s[...] = jnp.full_like(m_s, NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    @pl.when(kj <= hi)
+    def _():
+        c = c_ref[...]
+        s = jax.lax.dot_general(q_ref[...], c, _NT,
+                                preferred_element_type=jnp.float32) * scale
+        rows = s.shape[0]
+        q_ids = q_lo + jnp.bitwise_and(
+            jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), 0),
+            block_q - 1)
+        k_ids = kj * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block_k), 1)
+        s = jnp.where(q_ids >= k_ids, s, NEG_INF)
+        m_prev = m_s[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # key 0 is seen by every query, so m_new is finite from the first
+        # block on and a masked score's exp is exactly 0
+        p = jnp.exp(s - m_new)
+        m_s[...] = m_new
+        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
+            p.astype(c.dtype), c[:, :v_dim], _NN,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(kj == pl.num_programs(1) - 1)
+    def _():
+        o_ref[...] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
+
+
+def latent_prefill_supports(q_shape, cache_shape, v_dim,
+                            block_q=LATENT_BLOCK_Q,
+                            block_k=LATENT_BLOCK_K) -> bool:
+    b, sq, _, w = q_shape
+    return (b == 1 and sq % block_q == 0 and cache_shape[1] % block_k == 0
+            and v_dim % _LANES == 0 and v_dim <= w)
+
+
+@functools.partial(jax.jit, static_argnames=("v_dim", "scale", "block_q",
+                                             "block_k", "interpret"))
+def latent_prefill(q, latent, q_offset, *, v_dim, scale,
+                   block_q=LATENT_BLOCK_Q, block_k=LATENT_BLOCK_K,
+                   interpret=False):
+    """Absorbed latent attention of a chunk of queries over a contiguous
+    latent cache that already holds their rows (serving prefill; forward
+    only). q [1, sq, heads, w]: a head's absorbed query; latent [1, sk, w]:
+    a token's compressed latent and rotary key in one row, read ONCE a
+    query block for all heads; q_offset: scalar int32, the absolute position
+    of q[:, 0]. Query at position p sees keys <= p: score over all w values,
+    weighted sum over the first v_dim of the same rows. Returns
+    [1, sq, heads, v_dim]. Its time follows the keys before the diagonal:
+    later key blocks cost a grid step each (~0.35 us) and no product."""
+    _, sq, heads, w = q.shape
+    sk = latent.shape[1]
+    nq, nk = sq // block_q, sk // block_k
+    assert block_q & (block_q - 1) == 0, "block_q: a power of two"
+    rows = heads * block_q
+    # [nq, heads * block_q, w]: a query block's heads stacked as rows
+    qr = (q[0].reshape(nq, block_q, heads, w).transpose(0, 2, 1, 3)
+          .reshape(nq, rows, w).astype(latent.dtype))
+    off = jnp.asarray(q_offset, jnp.int32).reshape(1)
+
+    def last_seen(qi, off):
+        return jax.lax.div(off[0] + (qi + 1) * block_q - 1, block_k)
+
+    out = pl.pallas_call(
+        functools.partial(_latent_prefill_kernel, scale=scale,
+                          block_q=block_q, block_k=block_k, v_dim=v_dim),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nq, nk),
+            in_specs=[
+                pl.BlockSpec((None, rows, w), lambda i, j, off: (i, 0, 0)),
+                pl.BlockSpec((None, block_k, w), lambda i, j, off: (
+                    0, jnp.minimum(j, last_seen(i, off)), 0)),
+            ],
+            out_specs=pl.BlockSpec((None, rows, v_dim),
+                                   lambda i, j, off: (i, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, v_dim), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((nq, rows, v_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=96 << 20),
+        name="latent_prefill",
+        interpret=interpret,
+    )(off, qr, latent)
+    return (out.reshape(nq, heads, block_q, v_dim).transpose(0, 2, 1, 3)
+            .reshape(1, sq, heads, v_dim))
+
+
 def supports(q_shape, k_shape, attn_mask, dropout_p, is_causal=False,
              block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K) -> bool:
     """Shape gate: fall back to the XLA composition otherwise. The default

@@ -48,6 +48,13 @@ of a context across grid steps: a v5e has one TensorCore and the page loop
 is inside the step (the former kernel's `kv_splits` bought nothing on the
 chip: 4.66 ms a layer at 1, 2, 4 and 8).
 
+A latent layer (`latent_decode`) is the same kernel over ONE pool: a page
+row is a token's compressed latent and rotary key, every query head reads
+it (one K/V "head"), scores go over the whole row and the weighted sum over
+its first `v_dim` values, so a page is fetched once. p goes into p.v in the
+pool's dtype there: 20 heads share each key, and a float32 product would
+bound the step where the bytes should.
+
 The speculative verify kernel (`paged_verify`, `_verify_kernel`) is the
 older design, a grid step a (slot, K/V head, table entry): no configuration
 of the benchmark turns it on (ROADMAP S11).
@@ -103,15 +110,24 @@ def pages_per_fetch(kv_heads, block_size, d, itemsize, table_width):
     return max(1, min(_FETCH_BYTES // page_bytes, _FETCH_PAGES, table_width))
 
 
-def _decode_kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref,
-                   k_buf, v_buf, sem, parity, *, block_size, fetch, scale,
-                   window):
+def _decode_kernel(bt_ref, cl_ref, q_ref, k_hbm, *rest, block_size, fetch,
+                   scale, window, v_dim=None):
     # scalar prefetch: bt_ref [slots, width], cl_ref [slots] (SMEM)
     # q_ref, o_ref [hkv, g, d] (this slot); k_hbm, v_hbm: the whole pool,
     # left in HBM; k_buf, v_buf [2, hkv, fetch * block_size, d]: two
     # buffers of `fetch` pages, head-major so that a head's keys are rows;
     # sem [2 (K, V), 2 (buffer)]; parity [1] (SMEM): the buffer that holds
     # this slot's first fetch, which the step before started.
+    # A latent layer (`v_dim`): one pool and one buffer, no v_hbm and no
+    # v_buf; the values are the first v_dim columns of the keys' rows, so a
+    # page is fetched once, and o_ref is [1, g, v_dim].
+    if v_dim is None:
+        v_hbm, o_ref, k_buf, v_buf, sem, parity = rest
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+    else:
+        o_ref, k_buf, sem, parity = rest
+        v_buf = k_buf
+        pools = ((k_hbm, k_buf),)
     i = pl.program_id(0)
     width = bt_ref.shape[1]
     bs = block_size
@@ -128,9 +144,10 @@ def _decode_kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref,
         return cl, first, pages, (pages + fetch - 1) // fetch
 
     def copies(slot, f, buf):
-        """(is the page live, its K copy, its V copy) for each page of a
-        slot's fetch f. A dead page (past the context) is neither looked
-        up in the table nor fetched; a wait needs the shapes alone."""
+        """(is the page live, its copy from each pool: K and V, or the one
+        latent) for each page of a slot's fetch f. A dead page (past the
+        context) is neither looked up in the table nor fetched; a wait needs
+        the shapes alone."""
         _, first, pages, _ = span(slot)
         out = []
         for p in range(fetch):
@@ -139,19 +156,18 @@ def _decode_kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref,
             entry = (first + blk) % width if window is not None else blk
             page = bt_ref[slot, jnp.where(live, entry, 0)]
             rows = pl.ds(p * bs, bs)
-            out.append((live, *(
+            out.append((live, [
                 pltpu.make_async_copy(hbm.at[page], vmem.at[buf, :, rows, :],
                                       sem.at[j, buf])
-                for j, (hbm, vmem) in enumerate(((k_hbm, k_buf),
-                                                 (v_hbm, v_buf))))))
+                for j, (hbm, vmem) in enumerate(pools)]))
         return out
 
     def start(slot, f, buf):
-        for live, k_copy, v_copy in copies(slot, f, buf):
+        for live, page_copies in copies(slot, f, buf):
             @pl.when(live)
             def _():
-                k_copy.start()
-                v_copy.start()
+                for c in page_copies:
+                    c.start()
 
     @pl.when(i == 0)
     def _():
@@ -177,11 +193,11 @@ def _decode_kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref,
         def _():
             start(i + 1, 0, 1 - buf)
 
-        for live, k_copy, v_copy in copies(i, f, buf):
+        for live, page_copies in copies(i, f, buf):
             @pl.when(live)
             def _():
-                k_copy.wait()
-                v_copy.wait()
+                for c in page_copies:
+                    c.wait()
 
         base = (first + f * fetch) * bs
 
@@ -206,8 +222,15 @@ def _decode_kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref,
         # score's exp is exactly 0
         p = jnp.exp(sc - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
+        if v_dim is None:
+            v = v_buf[buf].astype(jnp.float32)
+        else:
+            # 20 heads a key: the products, not the bytes, would bound a
+            # float32 p.v, so p goes to the pool's dtype (sums in float32)
+            v = v_buf[buf][:, :, :v_dim]
+            p = p.astype(v.dtype)
         acc = acc * alpha + jax.lax.dot_general(
-            p, v_buf[buf].astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+            p, v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)              # [hkv, g, d]
         return m_new, l_new, acc
 
@@ -215,7 +238,7 @@ def _decode_kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref,
         0, n_fetch, body,
         (jnp.full((hkv, g, 1), NEG_INF, jnp.float32),
          jnp.zeros((hkv, g, 1), jnp.float32),
-         jnp.zeros((hkv, g, d), jnp.float32)))
+         jnp.zeros((hkv, g, v_dim or d), jnp.float32)))
     parity[0] = (buf0 + n_fetch) % 2
     o_ref[...] = (acc / l).astype(o_ref.dtype)
 
@@ -274,6 +297,45 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32), qr,
       k_pages, v_pages)
     return out.reshape(slots, hq, d)
+
+
+@functools.partial(jax.jit, static_argnames=("v_dim", "scale", "interpret"))
+def latent_decode(q, pages, block_tables, context_lens, *, v_dim, scale,
+                  interpret=False):
+    """One decode step of absorbed latent attention over paged latents: the
+    decode kernel above with one pool. q [slots, heads, w]: a head's
+    absorbed query; pages [num_blocks, 1, block_size, w]: a token's latent
+    and rotary key in one row. Every head reads the one latent: scores over
+    all w values, the weighted sum over the first v_dim of the same rows,
+    which are fetched once. Returns [slots, heads, v_dim]."""
+    slots, heads, w = q.shape
+    _, _, bs, _ = pages.shape
+    fetch = pages_per_fetch(1, bs, w, pages.dtype.itemsize,
+                            block_tables.shape[1])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(slots,),
+        in_specs=[pl.BlockSpec((None, 1, heads, w),
+                               lambda i, bt, cl: (i, 0, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, 1, heads, v_dim),
+                               lambda i, bt, cl: (i, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, 1, fetch * bs, w), pages.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, block_size=bs, fetch=fetch,
+                          scale=scale, window=None, v_dim=v_dim),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((slots, 1, heads, v_dim), q.dtype),
+        name="latent_decode",
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+      q[:, None].astype(pages.dtype), pages)
+    return out[:, 0]
 
 
 # -------------------------------------------------- multi-query (verify)
@@ -494,7 +556,10 @@ def paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
 
 # --------------------------------------------------------------- shape gate
 def supports(q_shape, k_pages_shape) -> bool:
-    """Shape gate for the kernel path (XLA fallback otherwise)."""
+    """Shape gate for the K/V kernels (`paged_decode`, `paged_verify`): a
+    head size over 256 or query heads that K/V heads do not divide fall to
+    the XLA gather composition. A latent layer does not come here: its one
+    576-wide array goes through `latent_decode`, whatever its width."""
     slots, hq, d = q_shape
     hkv = k_pages_shape[1]
     return d <= 256 and hkv >= 1 and hq % hkv == 0

@@ -26,14 +26,18 @@ and sampled-token fetches are deferred and batched until a token's VALUE
 can matter (eos check, length cap) — so a steady-state tick is a single
 dispatch with no host round-trip.
 
-The cache is the model's to state (`model.cache_spec()`: per layer, full or
-window, K/V heads, head size): layers of one kind share a block table, a
-range of columns of a slot's table row, and each layer has a pool of its
-group's size. The full group's table is the worst-case reservation; a window
-group's is a fixed ring of blocks a slot (blocks.WindowRings), so a window
-layer holds the window however long the context. What the rings do not serve
-yet (prefix-cache hits, speculation, fused steps, the KV wire) refuses by
-name for a model with window layers.
+The cache is the model's to state (`model.cache_spec()`: per layer, full,
+window or latent, K/V heads, head size): layers of one kind share a block
+table, a range of columns of a slot's table row, and each layer has a pool of
+its group's size: a pair of arrays (K, V), or the one array of a latent
+layer, which shares the full group's table. The full group's table is the
+worst-case reservation; a window group's is a fixed ring of blocks a slot
+(blocks.WindowRings), so a window layer holds the window however long the
+context. What the rings do not serve yet (prefix-cache hits, speculation,
+fused steps, the KV wire) refuses by name for a model with window layers,
+and what is not written over latent pages (speculation, fused steps, the KV
+wire, batched prefill) for a model with latent layers; the prefix cache
+serves latent pages as it serves K/V pages.
 
 Compiled-program keys are shape-stable: one decode program per engine, one
 prefill/admit program per chunk bucket, one scatter per (workspace, block
@@ -77,8 +81,8 @@ from .speculative import NgramDrafter, SpecState
 # length bucket of the batched multi-prompt prefill program (0 = per-prompt
 # chunked prefill only); prefix_cache content-addresses full KV blocks so
 # prompts sharing a prefix skip its prefill. The last two stay `None` in the
-# signature because over window layers "left out" means off and "asked for"
-# refuses (_refuse_over_windows).
+# signature because over window or latent layers "left out" means off and
+# "asked for" refuses (_refuse_over).
 DEFAULT_PREFIX_CACHE = True
 DEFAULT_PREFILL_BUCKET = 16
 
@@ -123,6 +127,12 @@ _WINDOW_KEYS = _counter(
     labelnames=("kind",), always=True)
 
 
+_LATENT_KEYS = _counter(
+    "serving_latent_keys_total",
+    "Keys of latent layers a decode step's attention fetched (`fetched`: "
+    "the pages the kernel visits in every slot, idle ones too, in tokens) "
+    "beside the keys of the running contexts (`live`), a layer a slot.",
+    labelnames=("kind",), always=True)
 _PAGED_KEYS = _counter(
     "serving_paged_keys_total",
     "Keys of full layers a decode step's attention fetched (`fetched`: the "
@@ -239,21 +249,30 @@ class ServingEngine:
              sum(1 for l in spec.layers if l.window == r.window))
             for r in self.window_rings]
         # (counter, its kinds, window, layers) by cache group, for the tick
+        kinds = [l.kind for l in spec.layers]
         self._key_counters = [
-            (_PAGED_KEYS, ("fetched", "live"), None,
-             sum(1 for l in spec.layers if l.kind != "window"))] + [
+            (counter, ("fetched", "live"), None, kinds.count(kind))
+            for counter, kind in ((_PAGED_KEYS, "full"),
+                                  (_LATENT_KEYS, "latent"))
+            if kind in kinds] + [
             (_WINDOW_KEYS, ("read", "context"), r.window, n)
             for r, _, n in self._ring_cols]
+        # the kinds of layer that do not serve every feature yet: what they
+        # lack refuses below, by name
+        self._unserved = \
+            [f"window layers (windows {windows})"] * bool(windows) \
+            + ["latent layers"] * ("latent" in kinds)
         if windows:
-            # what the ring does not serve yet refuses here, by name
-            prefix_cache = self._refuse_over_windows(
+            prefix_cache = self._refuse_over(
                 "prefix_cache", prefix_cache, False,
                 "a prefix hit would have to find the window layers' keys, "
                 "which a ring keeps for the last window only")
-            prefill_bucket = self._refuse_over_windows(
+        if self._unserved:
+            prefill_bucket = self._refuse_over(
                 "prefill_bucket", prefill_bucket, 0,
                 "the batched prefill program writes whole prompts back "
-                "through one block table")
+                "through one block table, and runs the model at an offset "
+                "a row, which latent attention does not take")
         self.prefix_cache = bool(DEFAULT_PREFIX_CACHE if prefix_cache is None
                                  else prefix_cache)
         self.prefill_bucket = int(DEFAULT_PREFILL_BUCKET
@@ -262,7 +281,7 @@ class ServingEngine:
         by_window = {r.window: r.num_blocks for r in self.window_rings}
         self.pool = PagedKVPool(
             [(by_window.get(l.window, self.num_blocks), l.kv_heads,
-              l.head_dim) for l in spec.layers],
+              l.head_dim, l.arrays) for l in spec.layers],
             self.block_size, self._dtype)
         self.allocator = BlockAllocator(self.num_blocks, self.block_size,
                                         prefix_cache=self.prefix_cache)
@@ -298,14 +317,15 @@ class ServingEngine:
         self.spec_k = int(spec_k)
         self.spec_ngram = int(spec_ngram)
         self.spec_pause = int(spec_pause)
-        if windows:
-            self._refuse_over_windows(
+        if self._unserved:
+            self._refuse_over(
                 "spec_k", self.spec_k, 0,
                 "a verify window of several tokens over a ring of blocks "
-                "is not written, nor its rollback")
-            self._refuse_over_windows(
+                "or over latent pages is not written, nor its rollback")
+            self._refuse_over(
                 "FLAGS_serving_fuse_steps", self.fuse_steps, 1,
-                "the fused loop does not thread the window layers' state")
+                "the fused loop threads neither the window layers' state "
+                "nor a layer of one array")
         if self.spec_k > 0 and self.fuse_steps > 1:
             raise ValueError(
                 "FLAGS_serving_fuse_steps > 1 and speculative decoding "
@@ -332,15 +352,14 @@ class ServingEngine:
         # gauges, serving anomaly detectors + flight arm
         self.obs = ServingObservability(self)
 
-    def _refuse_over_windows(self, name, asked, allowed, why):
-        """A feature the window layers' rings do not serve yet: left at
-        its default it is off; asked for, it refuses by name."""
+    def _refuse_over(self, name, asked, allowed, why):
+        """A feature that window rings or latent pages do not serve yet:
+        left at its default it is off; asked for, it refuses by name."""
         if asked is None or asked == allowed:
             return allowed
         raise ValueError(
             f"ServingEngine: {name}={asked!r} cannot serve a model whose "
-            f"cache spec has window layers (windows "
-            f"{[r.window for r in self.window_rings]}): {why}. "
+            f"cache spec has {' and '.join(self._unserved)}: {why}. "
             f"Leave it at {allowed!r}.")
 
     # -- registry-backed counter views (historical int attributes) --------
@@ -414,18 +433,24 @@ class ServingEngine:
                         b._value = v
                     mine = iter(counters)
                     caches_t = []
-                    for i, ((k, v), (a, b)) in enumerate(zip(pages, cols)):
+                    for i, (arrays, (a, b)) in enumerate(zip(pages, cols)):
                         # the layer's group's columns of the table row
                         t = bt if (a, b) == (0, n_cols) else bt[:, a:b]
                         c = Tensor(next(mine)) \
                             if counters and i in counted else None
+                        # (k, v), or a latent layer's one array and no V
+                        k, v = (*map(Tensor, arrays), None)[:2]
                         caches_t.append(PagedLayerCache(
-                            Tensor(k), Tensor(v), Tensor(t), Tensor(sl), c))
+                            k, v, Tensor(t), Tensor(sl), c))
                     logits, ncs = model.forward(Tensor(ids), caches=caches_t,
                                                 pos=None)
+                    # a layer returns its arrays, then its counters if any
+                    n = [len(arrays) for arrays in pages]
                     return (logits._value,
-                            [(nc[0]._value, nc[1]._value) for nc in ncs],
-                            tuple(nc[2]._value for nc in ncs if len(nc) > 2))
+                            [tuple(x._value for x in nc[:k])
+                             for nc, k in zip(ncs, n)],
+                            tuple(nc[k]._value for nc, k in zip(ncs, n)
+                                  if len(nc) > k))
                 finally:
                     for p, (v, sg) in zip(params, saved_p):
                         p._value, p.stop_gradient = v, sg
@@ -648,15 +673,12 @@ class ServingEngine:
             n = mb * bs
 
             def serve_gather(pages, table):
-                out = []
-                for kp, vp in pages:
-                    hkv, d = kp.shape[1], kp.shape[3]
-                    k = jnp.zeros((1, padded, hkv, d), kp.dtype)
-                    v = jnp.zeros((1, padded, hkv, d), vp.dtype)
-                    k = k.at[0, :n].set(from_pages(kp[table]))
-                    v = v.at[0, :n].set(from_pages(vp[table]))
-                    out.append((k, v))
-                return out
+                def head(p):
+                    ws = jnp.zeros((1, padded, p.shape[1], p.shape[3]),
+                                   p.dtype)
+                    return ws.at[0, :n].set(from_pages(p[table]))
+
+                return [tuple(head(p) for p in arrays) for arrays in pages]
 
             return jax.jit(serve_gather)
 
@@ -674,8 +696,8 @@ class ServingEngine:
         def build():
             def serve_admit_cow(pages, toks, bt, sl, temps, src, dst, slot,
                                 table, plen, tok, temp):
-                new = [(kp.at[dst].set(kp[src]), vp.at[dst].set(vp[src]))
-                       for kp, vp in pages]
+                new = [tuple(p.at[dst].set(p[src]) for p in arrays)
+                       for arrays in pages]
                 return (new,
                         toks.at[slot].set(tok),
                         bt.at[slot].set(table),
@@ -753,14 +775,15 @@ class ServingEngine:
                 last_block: the logical block of the prompt's last token
                 (a window layer keeps the blocks that end there)."""
                 out = []
-                for (kp, vp), (k, v), (a, b) in zip(pages, caches, cols):
+                for arrays, ws, (a, b) in zip(pages, caches, cols):
                     if (a, b) == full:
                         out.append(write_prefix(
-                            kp, vp, k[0, :n], v[0, :n], row[a:a + nb],
+                            arrays, [w[0, :n] for w in ws], row[a:a + nb],
                             block_size=bs))
                     else:
-                        out.append(write_ring(kp, vp, k[0], v[0], row[a:b],
-                                              last_block, block_size=bs))
+                        out.append(write_ring(
+                            arrays, [w[0] for w in ws], row[a:b],
+                            last_block, block_size=bs))
                 return out
 
             return jax.jit(serve_scatter, donate_argnums=(0,))
@@ -838,14 +861,15 @@ class ServingEngine:
             return True
 
     # ------------------------------------------- KV-block streaming wire
-    def _no_kv_wire_over_windows(self, name):
-        if self.window_rings:
+    def _no_kv_wire(self, name):
+        if self._unserved:
             raise NotImplementedError(
                 f"ServingEngine.{name}: the KV wire carries prefix-cache "
-                f"blocks of one block table, and a model whose cache spec "
-                f"has window layers keeps those layers' keys in rings "
-                f"(windows {[r.window for r in self.window_rings]}), with "
-                f"no prefix cache over them")
+                f"blocks of one block table as (K, V) pairs of one "
+                f"geometry, and this model's cache spec has "
+                f"{' and '.join(self._unserved)}: a ring has no prefix "
+                f"cache over it, and a latent layer's one array is not "
+                f"in the wire's format")
 
     def export_kv_blocks(self, tokens: List[int]) -> List[dict]:
         """Serialize the RESIDENT full-block prefix of `tokens` for
@@ -855,7 +879,7 @@ class ServingEngine:
         bytes gathered from the device pool. Read-only; the wire format is
         what ingest_kv_blocks() (and the HTTP /kv/ingest endpoint, after
         base64) accepts."""
-        self._no_kv_wire_over_windows("export_kv_blocks")
+        self._no_kv_wire("export_kv_blocks")
         with self._lock:
             recs = self.allocator.export_prefix(tokens)
             if not recs:
@@ -883,7 +907,7 @@ class ServingEngine:
         (descendants could never be matched past the hole). Idempotent:
         already-resident digests are deduped without touching the pool.
         Returns {"imported", "dedup", "rejected", "skipped", "bytes"}."""
-        self._no_kv_wire_over_windows("ingest_kv_blocks")
+        self._no_kv_wire("ingest_kv_blocks")
         n_layers = len(self.pool.layers)
         kp0 = self.pool.layers[0][0]
         np_dtype = np.dtype(kp0.dtype)
@@ -1172,7 +1196,7 @@ class ServingEngine:
         start = req.prefill_pos
         take = min(chunk, plen - start)
         with self.obs.request_span("serving.prefill_chunk", req,
-                                   tokens=take, batched=False):
+                                   tokens=take, start=start, batched=False):
             _, _, pv, bv = self._functional()
             # chunk writes start at prefix_matched (a block multiple, not
             # necessarily a chunk multiple): the workspace must cover the LAST
@@ -1362,11 +1386,12 @@ class ServingEngine:
         return len(running) * k
 
     def _count_keys(self, running, k) -> None:
-        """serving_paged_keys_total and serving_window_keys_total for one
-        decode dispatch of k steps: the pages the kernel fetches (by its own
-        arithmetic, `live_pages`, over every slot: an idle one is handed
-        context 1 and fetches a page), in tokens, beside the running
-        contexts' keys, which a full layer has to read."""
+        """serving_paged_keys_total, serving_latent_keys_total and
+        serving_window_keys_total for one decode dispatch of k steps: the
+        pages the kernel fetches (by its own arithmetic, `live_pages`, over
+        every slot: an idle one is handed context 1 and fetches a page), in
+        tokens, beside the running contexts' keys, which a full or a latent
+        layer has to read."""
         bs = self.block_size
         ctx = self._lens.astype(np.int64)[:, None] + 1 + np.arange(k)
         live = int(ctx[running].sum())

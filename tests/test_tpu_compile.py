@@ -496,6 +496,39 @@ def test_grouped_products_at_lagunas_widths(one_chip, rows, tm, k, n):
     assert "moe_grouped_matmul" in compiled.as_text()
 
 
+# ---- GLM-4.7-Flash at the published widths: 20 heads over one latent row
+# ---- of 576 values (512 + 64), stored in whole lanes: 640; block 128; the
+# ---- longdoc cell's 24 slots, 260-wide tables and 5,633 blocks
+_G_HEADS, _G_ROW, _G_RANK = 20, 640, 512
+
+
+def test_latent_decode_at_the_published_widths(one_chip):
+    from paddle_tpu.ops.pallas.paged_attention import latent_decode
+
+    pages = ((5633, 1, 128, _G_ROW), jnp.bfloat16)
+    compiled = _compile(
+        lambda q, p, bt, cl: latent_decode(q, p, bt, cl, v_dim=_G_RANK,
+                                           scale=1 / 16),
+        one_chip, ((24, _G_HEADS, _G_ROW), jnp.bfloat16), pages,
+        ((24, 260), jnp.int32), ((24,), jnp.int32))
+    # the pool is taken as it lies in HBM: no copy, no transpose of it
+    assert not _pool_relayouts(compiled.as_text(), *pages)
+    assert "%latent_decode" in compiled.as_text()
+
+
+@pytest.mark.parametrize("chunk,cached", [(512, 8704), (1024, 33792),
+                                          (512, 33280), (2048, 34816)])
+def test_latent_prefill_at_the_published_widths(one_chip, chunk, cached):
+    from paddle_tpu.ops.pallas.flash_attention import latent_prefill
+
+    compiled = _compile(
+        lambda q, lat, off: latent_prefill(q, lat, off, v_dim=_G_RANK,
+                                           scale=1 / 16),
+        one_chip, ((1, chunk, _G_HEADS, _G_ROW), jnp.bfloat16),
+        ((1, cached, _G_ROW), jnp.bfloat16), ((), jnp.int32))
+    assert "latent_prefill" in compiled.as_text()
+
+
 @pytest.fixture(scope="module")
 def laguna_pool_programs(one_chip):
     """The engine programs that return a Laguna model's pools, both cache

@@ -21,10 +21,15 @@ One engine tick (`step()`) = admit -> prefill chunk(s) -> one decode step:
 The decode loop is device-resident: block tables are the full worst-case
 admission reservation uploaded once per request, the compiled step feeds
 its own outputs (next tokens, advanced lengths, RNG seed) straight back
-in, admission is one fused program (first-token argmax + slot scatter),
-and sampled-token fetches are deferred and batched until a token's VALUE
-can matter (eos check, length cap) — so a steady-state tick is a single
-dispatch with no host round-trip.
+in, and a greedy admission is one fused program (first-token argmax + slot
+scatter). A tick never waits for work it has just dispatched: tick n + 1's
+programs (admissions, the prefill chunk, the decode step) go to the device
+first, and only then are tick n's tokens fetched, so the fetch and all of
+the host's bookkeeping run under the device's tick n + 1 (`_fetch`). An
+eos is therefore found one tick late: the request decodes one token too
+many, which is dropped (serving_overshoot_tokens_total) and whose K/V
+lands in pages the request still holds or in the null block. A request is
+`finished` only once every token of it is in `output_tokens`.
 
 The cache is the model's to state (`model.cache_spec()`: per layer, full,
 window or latent, K/V heads, head size): layers of one kind share a block
@@ -48,7 +53,7 @@ from __future__ import annotations
 import random
 import threading
 import time
-from typing import List, Optional
+from typing import List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +66,8 @@ from ..models.generation import init_kv_cache
 from .blocks import BlockAllocator, WindowRings
 from .observability import (
     _PREFILL_TOKENS,
+    FETCHES,
+    OVERSHOOT_TOKENS,
     PROGRAMS_BUILT,
     SUBMIT_LOCK_WAIT_H,
     EngineStats,
@@ -145,6 +152,14 @@ def _holds_blocks(block_tables):
     """[slots] 1 where a slot's table row names a block, 0 for an idle
     slot (a null row)."""
     return jnp.any(block_tables != 0, axis=1).astype(jnp.int32)
+
+
+class _InFlight(NamedTuple):
+    """A dispatch whose sampled tokens no fetch has brought to the host."""
+    tokens: jax.Array       # int32 on the device
+    items: list             # [(index into tokens, slot, request)]
+    tick: int               # engine.steps at the dispatch
+    counters: tuple = ()    # the layers' counters a decode step returned
 
 
 class QueueFullError(RuntimeError):
@@ -301,9 +316,8 @@ class ServingEngine:
         # live on device in _dev and are updated incrementally (per-slot
         # scatter on admission / block-table growth) — the decode loop
         # feeds its own outputs (next tokens, advanced seq_lens, RNG seed)
-        # straight back in, and sampled-token fetches are DEFERRED and
-        # batched (one transfer per flush) so host dispatch runs ahead of
-        # device compute instead of syncing every tick
+        # straight back in, and a tick's tokens are fetched under the NEXT
+        # tick's programs (_fetch), so the device never waits for the host
         self._tables = np.zeros((self.max_slots, self._table_cols),
                                 np.int32)
         self._lens = np.zeros(self.max_slots, np.int32)
@@ -334,7 +348,14 @@ class ServingEngine:
                 "step schedule that a variable-width verify window would "
                 "miscompile. Disable one of them.")
         self._dev = None        # (toks, tables, lens, temps, seed) on device
-        self._pending = []      # [(tokens_dev, [(idx, slot, req), ...])]
+        # dispatched, not fetched, in order; no entry names a finished
+        # request (_finish)
+        self._pending: List[_InFlight] = []
+        # logits of the prefill chunks that may not have run yet, oldest
+        # first (_pace_prefill)
+        self._chunks: List[jax.Array] = []
+        self._dispatched = False    # a program went to the device this tick
+        self._counters_due = False  # a finish since the counters' last fetch
         self._jit = {}
         self._fns = None
         self._lock = threading.RLock()
@@ -516,7 +537,9 @@ class ServingEngine:
                 return nxt, new_pages, sl + _holds_blocks(bt), seed + 1, \
                     counters
 
-            return jax.jit(step, donate_argnums=(3, 5, 7, 8))
+            # the counters are not donated: the arrays a step returned are
+            # fetched beside its tokens, under the step after it (_fetch)
+            return jax.jit(step, donate_argnums=(3, 5, 7))
 
         return self._program("decode", key, build)
 
@@ -630,7 +653,7 @@ class ServingEngine:
         the difference between admission costing a tick and costing
         nothing. The slot index is traced, so one program serves every
         slot. No donation: the incoming token vector is also referenced by
-        the deferred-flush queue."""
+        the entries in flight (_pending)."""
         key = ("admit", chunk, self.max_slots, self._table_cols)
 
         def build():
@@ -647,6 +670,22 @@ class ServingEngine:
             return jax.jit(serve_admit)
 
         return self._program("admit", key, build)
+
+    def _workspace_jit(self, padded):
+        """A first-turn prompt's empty prefill workspace, every array of
+        every layer from ONE program (eagerly it is a dispatch an array: 48
+        for a 24-layer model, each a gap on the device)."""
+        key = ("workspace", padded)
+
+        def build():
+            spec, dtype = self._spec, self._dtype
+
+            def serve_workspace():
+                return init_kv_cache(1, padded, spec, dtype)
+
+            return jax.jit(serve_workspace)
+
+        return self._program("workspace", key, build)
 
     def _prefill_jit(self, chunk, padded):
         key = ("prefill", chunk, padded)
@@ -690,7 +729,7 @@ class ServingEngine:
         prompt token will write) and scatter the slot's decode state, one
         dispatch. Pages are donated (in-place pool update); the decode
         state tensors are not (the token vector may be referenced by the
-        deferred-flush queue)."""
+        entries in flight)."""
         key = ("admit_cow", self.max_slots, self._table_cols)
 
         def build():
@@ -982,6 +1021,7 @@ class ServingEngine:
             return out
 
     def _tick(self) -> dict:
+        self._dispatched = False
         with self.obs.span("serving.schedule") as sp:
             admitted = self.sched.admit()
             for req in admitted:
@@ -1017,7 +1057,15 @@ class ServingEngine:
             self._prefill_one_chunk(req)
             if self.sched.next_prefill() is req:
                 break   # long prompt mid-prefill: one chunk per tick
-        decoded = self._decode_step() if self.sched.running else 0
+        # a request whose budget the tokens in flight already fill has
+        # nothing left to decode: a batch of such alone dispatches no step
+        decoded = self._decode_step() if any(
+            len(r.output_tokens) + r._pending_n < self._token_cap(r)
+            for r in self.sched.running.values()) else 0
+        # the tokens of the tick before come to the host under this tick's
+        # programs; a tick that dispatched nothing fetches whatever is in
+        # flight at once
+        self._fetch(behind=self._dispatched)
         self.steps += 1
         return {"admitted": len(admitted), "decoded_tokens": decoded,
                 **self.sched.counts()}
@@ -1086,17 +1134,17 @@ class ServingEngine:
             int(req.prompt[-1]), req.temperature)
         self.pool.replace(new_layers)
         self._dev = (n_toks, n_bt, n_sl, n_temps, d_seed)
+        self._dispatched = True
         self._stats.inc("cow_admissions")
         self.sched.start_running(req)
-        self.obs.on_first_token(req)
 
     def _batched_prefill(self, reqs: List[Request]) -> None:
         """Admit a burst of prompts in ONE dispatch (see
         _batched_prefill_jit). Rows are the burst's unmatched suffixes,
         padded to a bucketed [n, S]; the workspace holds each row's full
         context (cached prefix + suffix) padded to P tokens. Greedy-only:
-        each row's first token is argmaxed on device and its fetch
-        deferred like any decode token."""
+        each row's first token is argmaxed on device and fetched like any
+        decode token, under the next tick's programs."""
         suffixes = [len(r.prompt) - r.prefill_pos for r in reqs]
         with self.obs.span("serving.prefill_chunk", reqs,
                            tokens=sum(suffixes), batched=True):
@@ -1145,19 +1193,20 @@ class ServingEngine:
                     d_toks, d_tables, d_lens, d_temps)
             self.pool.replace(new_layers)
             self._dev = (n_toks, n_bt, n_sl, n_temps, d_seed)
+            self._dispatched = True
             self._stats.inc("batched_prefills")
             self._stats.inc("prefill_programs")
             computed = sum(suffixes)
             self._stats.inc("prefill_tokens", computed)
             _PREFILL_TOKENS.inc(computed)
-        self._pending.append(
-            (first_dev, [(r, req.slot, req) for r, req in enumerate(reqs)]))
-        flush = False
+        self._pending.append(_InFlight(
+            first_dev, [(r, req.slot, req) for r, req in enumerate(reqs)],
+            self.steps))
         for r, req in enumerate(reqs):
             slot = req.slot
             self._tables[slot] = bt_rows[r]
             self._lens[slot] = plens[r]
-            self._toks[slot] = 0          # fetched at the next flush
+            self._toks[slot] = 0          # known at the next fetch
             self._temps[slot] = req.temperature
             req.prefill_pos = len(req.prompt)
             req._pending_n += 1
@@ -1179,22 +1228,34 @@ class ServingEngine:
                     self._stats.inc("dedup_admissions")
             if req.prefill_only:
                 # the row rode the shared dispatch for its KV only; finish
-                # instead of joining decode (the deferred first-token fetch
-                # skips finished requests at flush)
+                # instead of joining decode (its first token is dropped
+                # with the finish, unfetched)
                 self._finish(req, "prefill_complete")
                 continue
             self.sched.start_running(req)
-            self.obs.on_first_token(req)
-            if req.eos_token_id is not None or req.max_new_tokens <= 1:
-                flush = True
-        if flush:
-            self._flush_pending()
+
+    def _pace_prefill(self) -> None:
+        """Before a prefill chunk is dispatched: wait until at most one is
+        on the device. A chunk holds its logits (chunk x vocabulary: 158 MB
+        for 512 rows of 77k words) and its temporaries from its dispatch
+        until it has run, and while no request decodes nothing else paces
+        the host: a long prompt alone, or a burst of short ones in one
+        tick, had sixteen chunks in flight, all the runtime queues. Two
+        keep the device fed: the wait is for the chunk BEFORE the one in
+        flight, and is over at once where a decode step's fetch paced the
+        tick already."""
+        self._chunks = [c for c in self._chunks if not c.is_ready()]
+        while len(self._chunks) > 1:
+            with self.obs.span("serving.fetch", what="prefill_chunk",
+                               behind=1, ticks=0, tokens=0):
+                self._chunks.pop(0).block_until_ready()
 
     def _prefill_one_chunk(self, req: Request) -> None:
         plen = len(req.prompt)
         chunk = self.prefill_chunk
         start = req.prefill_pos
         take = min(chunk, plen - start)
+        self._pace_prefill()
         with self.obs.request_span("serving.prefill_chunk", req,
                                    tokens=take, start=start, batched=False):
             _, _, pv, bv = self._functional()
@@ -1213,13 +1274,13 @@ class ServingEngine:
                     req._ws_caches = self._gather_jit(padded, mb)(
                         self.pool.layers, head)
                 else:
-                    req._ws_caches = init_kv_cache(1, padded, self._spec,
-                                                   self._dtype)
+                    req._ws_caches = self._workspace_jit(padded)()
             ids = np.zeros((1, chunk), np.int32)
             ids[0, :take] = req.prompt[start:start + take]
             logits, req._ws_caches = self._prefill_jit(chunk, padded)(
-                pv, bv, jnp.asarray(ids), req._ws_caches,
-                jnp.asarray(start, jnp.int32))
+                pv, bv, ids, req._ws_caches, np.int32(start))
+            self._chunks.append(logits)
+            self._dispatched = True
             req.prefill_pos = start + take
             self._stats.inc("prefill_programs")
             self._stats.inc("prefill_tokens", take)
@@ -1256,12 +1317,11 @@ class ServingEngine:
         self._tables[slot] = self._table_row(req)
         self._lens[slot] = plen
         self._temps[slot] = req.temperature
-        # a greedy no-eos request never needs its first token's VALUE on
-        # the host this tick — sample it on device and defer the fetch, so
-        # admission doesn't block the pipeline on prefill compute
-        defer = (req.temperature <= 0.0 and req.eos_token_id is None
-                 and req.max_new_tokens > 1)
-        if defer:
+        if req.temperature <= 0.0:
+            # greedy, eos id or not: the first token is sampled on the
+            # device by the program that scatters the slot's state, and
+            # its value comes with the next fetch, so admission never
+            # waits for the prefill chunk
             if self._dev is None:
                 self._dev_init()
             d_toks, d_tables, d_lens, d_temps, d_seed = self._dev
@@ -1269,46 +1329,60 @@ class ServingEngine:
                 logits, plen - 1 - start, d_toks, d_tables, d_lens, d_temps,
                 slot, self._tables[slot], plen, req.temperature)
             self._dev = (n_toks, n_bt, n_sl, n_temps, d_seed)
-            self._pending.append((first_dev, [(0, slot, req)]))
+            self._pending.append(
+                _InFlight(first_dev, [(0, slot, req)], self.steps))
             req._pending_n += 1
-        else:
-            with self.obs.span("serving.fetch", what="first_token_logits",
-                               ticks=0, tokens=1):
-                last = np.asarray(
-                    jax.device_get(logits[0, plen - 1 - start]))
-            first = self._sample_host(last, req)
-            self._toks[slot] = first
-            if self._dev is not None:
-                # join the live decode batch by scattering this slot's
-                # state into the device copies (host-known scalars — no
-                # sync, the other slots' in-flight tokens are untouched)
-                d_toks, d_tables, d_lens, d_temps, d_seed = self._dev
-                with self.obs.span("serving.host_upload",
-                                   what="slot_state"):
-                    self._dev = (d_toks.at[slot].set(first),
-                                 d_tables.at[slot].set(
-                                     jnp.asarray(self._tables[slot])),
-                                 d_lens.at[slot].set(plen),
-                                 d_temps.at[slot].set(req.temperature),
-                                 d_seed)
-            req.output_tokens.append(first)
-            req._progress.set()
+            self.sched.start_running(req)
+            return
+        # a sampled request draws its first token on the host, from the
+        # chunk's logits: the one admission that waits for its prefill
+        with self._fetch_span("first_token_logits", behind=False, ticks=0,
+                              tokens=1):
+            last = np.asarray(jax.device_get(logits[0, plen - 1 - start]))
+        first = self._sample_host(last, req)
+        self._toks[slot] = first
+        if self._dev is not None:
+            # join the live decode batch by scattering this slot's state
+            # into the device copies (host-known scalars — no sync, the
+            # other slots' in-flight tokens are untouched)
+            d_toks, d_tables, d_lens, d_temps, d_seed = self._dev
+            with self.obs.span("serving.host_upload", what="slot_state"):
+                self._dev = (d_toks.at[slot].set(first),
+                             d_tables.at[slot].set(
+                                 jnp.asarray(self._tables[slot])),
+                             d_lens.at[slot].set(plen),
+                             d_temps.at[slot].set(req.temperature),
+                             d_seed)
         self.sched.start_running(req)
+        self._first_token(req, time.monotonic())
+        req.output_tokens.append(first)
+        req._progress.set()
+        if req.eos_token_id is not None and first == req.eos_token_id:
+            self._finish(req, "stop")
+        elif len(req.output_tokens) >= self._token_cap(req):
+            self._finish(req, "length")
+
+    def _first_token(self, req: Request, now: float) -> None:
+        """The first token's VALUE is on the host: that instant is the
+        request's first_token_time, whenever the program that sampled it
+        was dispatched."""
+        req.first_token_time = now
         self.obs.on_first_token(req)
-        if not defer:
-            if req.eos_token_id is not None and first == req.eos_token_id:
-                self._finish(req, "stop")
-            elif len(req.output_tokens) >= req.max_new_tokens:
-                self._finish(req, "length")
+
+    def _token_cap(self, req: Request) -> int:
+        """Output tokens at which `req` finishes by length: its budget, or
+        the context cap (the prompt and every token but the last take a
+        position)."""
+        return max(1, min(req.max_new_tokens,
+                          self.max_model_len + 1 - len(req.prompt)))
 
     def _sample_host(self, logits: np.ndarray, req: Request) -> int:
-        """First-token sampling for non-deferred admissions: same
+        """First-token sampling for a sampled admission (temperature > 0;
+        a greedy one argmaxes on the device, _admit_jit): same
         fold_in(PRNGKey(0), seed) threefry scheme as the compiled decode
         step, plus a per-admission nonce — two sampled requests admitted in
         the SAME tick must draw from distinct streams, and the first token
         must not replay what a decode tick at the same seed would emit."""
-        if req.temperature <= 0.0:
-            return int(logits.argmax())
         self._sample_nonce += 1
         key = jax.random.fold_in(jax.random.PRNGKey(0), self._step_seed)
         key = jax.random.fold_in(key, self._sample_nonce)
@@ -1330,14 +1404,16 @@ class ServingEngine:
                 return decoded
         running = list(self.sched.running.items())
         needs_sampling = any(req.temperature > 0.0 for _, req in running)
-        # fuse 4 decode steps into one dispatch for all-greedy batches. A
-        # slot whose budget runs out mid-chunk just overshoots: the extra
-        # tokens are dropped at flush (eos overshoot was already truncated
-        # there), and the overflow KV writes can only land in the null
-        # block or the finishing slot's own about-to-be-freed pages —
-        # never another sequence's. Prefill still gets its chunk every
-        # dispatch, so fusing costs admission at most 3 steps of latency
-        # per queued prompt.
+        # fuse 4 decode steps into one dispatch for all-greedy batches.
+        # Fused or not, a slot whose request is over (an eos the host has
+        # not seen yet, a budget that ran out mid-chunk) decodes on until
+        # the fetch that finishes it: the extra tokens are dropped there,
+        # and the overflow KV writes can only land in the null block (a
+        # position past the reservation or past the table) or the
+        # finishing slot's own about-to-be-freed pages — never another
+        # sequence's. Prefill still gets its chunk every dispatch, so
+        # fusing costs admission at most 3 steps of latency per queued
+        # prompt.
         k = 1 if needs_sampling else self.fuse_steps
         with self.obs.span("serving.decode", self.sched.running.values(),
                            batch=len(running), steps=k):
@@ -1365,24 +1441,16 @@ class ServingEngine:
                          for i in range(k) for slot, req in running]
             self.pool.replace(new_layers)
             self._dev = (nxt, d_tables, new_lens, d_temps, new_seed)
+            self._dispatched = True
             self._step_seed += k
-            # defer the token fetch: host bookkeeping below only needs
-            # COUNTS. Flush (one batched transfer) when a token value can
-            # matter — a request with an eos_token_id (checked every
-            # token), or one whose count reached its length cap this tick.
-            self._pending.append((toks, items))
+            # the values come with the next tick's fetch; the bookkeeping
+            # below needs COUNTS only
+            self._pending.append(
+                _InFlight(toks, items, self.steps, self._counters))
         self._count_keys([slot for slot, _ in running], k)
-        flush = False
         for slot, req in running:
             req._pending_n += k
             self._lens[slot] += k
-            if (req.eos_token_id is not None
-                    or len(req.output_tokens) + req._pending_n
-                    >= req.max_new_tokens
-                    or int(self._lens[slot]) >= self.max_model_len):
-                flush = True
-        if flush:
-            self._flush_pending()
         return len(running) * k
 
     def _count_keys(self, running, k) -> None:
@@ -1401,16 +1469,20 @@ class ServingEngine:
             counter.inc(live * layers, kind=kinds[1])
 
     def layer_counters(self) -> dict:
-        """{layer index: counters} fetched from the device (one transfer),
-        and what they add since the last fetch published to the registry:
-        for a sparse layer, pairs by held expert and last the pairs of
-        experts held elsewhere."""
+        """{layer index: counters} fetched from the device (one transfer,
+        which waits for the step in flight: stats() pays it, a finish does
+        not, see _fetch), and what they add since the last fetch published
+        to the registry: for a sparse layer, pairs by held expert and last
+        the pairs of experts held elsewhere."""
         if not self._counters:
             return {}
         with self.obs.span("serving.fetch", what="layer_counters", ticks=0,
-                           tokens=0):
-            vals = [np.asarray(v, np.int64)
-                    for v in jax.device_get(list(self._counters))]
+                           tokens=0, behind=0):
+            vals = jax.device_get(list(self._counters))
+        return self._publish_counters(vals)
+
+    def _publish_counters(self, vals) -> dict:
+        vals = [np.asarray(v, np.int64) for v in vals]
         for i, v, seen in zip(self._counter_layers, vals,
                               self._counters_published):
             new = v - seen
@@ -1420,20 +1492,21 @@ class ServingEngine:
             if v[:-1].sum() > 0:
                 _MOE_LOAD.set(float(v[:-1].max() / v[:-1].mean()),
                               layer=f"h{i}")
+        self._counters_due = False
         return dict(zip(self._counter_layers, vals))
 
     def _spec_step(self) -> Optional[int]:
         """One speculative tick, or None to fall through to the plain
-        deferred-fetch decode path (no request may draft right now — all
-        paused by the adaptive throttle, sampled, or out of budget).
+        decode path, whose fetch comes a tick later (no request may draft
+        right now — all paused by the adaptive throttle, sampled, or out of
+        budget).
 
         Speculation is inherently synchronous on the host side: drafting
-        needs every emitted token's VALUE, so the tick flushes the
-        deferred queue first and fetches its own (targets, accepted)
-        results eagerly. The adaptive pause keeps that cost off
-        non-repetitive traffic — when nothing drafts, the plain
-        pipelined path runs untouched."""
-        # cheap pre-check before paying the flush: is anyone allowed to
+        needs every emitted token's VALUE, so the tick fetches what is in
+        flight first and its own (targets, accepted) results eagerly. The
+        adaptive pause keeps that cost off non-repetitive traffic — when
+        nothing drafts, the plain pipelined path runs untouched."""
+        # cheap pre-check before paying the fetch: is anyone allowed to
         # draft this tick? (draft_k needs no token values)
         active = False
         for slot, req in self.sched.running.items():
@@ -1447,7 +1520,7 @@ class ServingEngine:
                 active = True
         if not active:
             return None
-        self._flush_pending()
+        self._fetch(behind=False)
         running = list(self.sched.running.items())
         if not running:
             return 0
@@ -1499,16 +1572,20 @@ class ServingEngine:
                 d_lens, jnp.asarray(dls), d_temps, d_seed)
             self.pool.replace(new_layers)
             self._dev = (nxt, d_tables, new_sl, d_temps, new_seed)
+            self._dispatched = True
             self._step_seed += 1
             self._stats.inc("spec_ticks")
         # speculation needs this tick's values before the next draft
-        with self.obs.span("serving.fetch", what="spec_verify",
-                           ticks=1) as fetch:
+        with self._fetch_span("spec_verify", behind=False,
+                              ticks=1) as fetch:
             greedy_h, acc_h, nxt_h = jax.device_get((greedy, acc, nxt))
             fetch.set(tokens=int(acc_h.sum()) + len(running))
+        now = time.monotonic()
         decoded = 0
         touched = []
         for slot, req in running:
+            if req.first_token_time is None:
+                self._first_token(req, now)
             dl = int(dls[slot])
             if req.temperature > 0.0:
                 # single-token fallback in the mixed batch: the sampled
@@ -1562,46 +1639,67 @@ class ServingEngine:
             req._progress.set()
         return decoded
 
-    def _flush_pending(self) -> None:
-        """Materialize every deferred sampled token (one host transfer for
-        all pending ticks), append them in tick order, then run the finish
-        checks. eos-bearing requests force a flush per tick, so an eos stop
-        is still detected on the exact token that emitted it."""
-        if not self._pending:
+    def _fetch_span(self, what: str, behind: bool, **args):
+        """The `serving.fetch` span around a wait for token values, counted
+        by whether a later dispatch is in flight behind what it waits for
+        (the device works on) or none is (the device idles once this is
+        done, until the host has dispatched again)."""
+        FETCHES.inc(kind="under_dispatch" if behind else "exposed")
+        return self.obs.span("serving.fetch", what=what, behind=int(behind),
+                             **args)
+
+    def _fetch(self, behind: bool) -> None:
+        """Bring sampled tokens to the host (one transfer), append them in
+        tick order, then run the finish checks. `behind`: this tick has
+        dispatched, so what EARLIER ticks dispatched is fetched and this
+        tick's programs run under the wait and under the bookkeeping after
+        it; else everything in flight is fetched, with nothing behind it.
+        An eos is found here, a tick after the step that emitted it: the
+        step dispatched since decoded one token past it, dropped below or
+        by _finish."""
+        n = sum(1 for e in self._pending if e.tick < self.steps) if behind \
+            else len(self._pending)
+        if not n:
             return
-        pending, self._pending = self._pending, []
-        # the one place a steady tick waits for the device
-        with self.obs.span("serving.fetch", what="tokens",
-                           ticks=len(pending),
-                           tokens=sum(len(it) for _, it in pending)):
-            vals = jax.device_get([arr for arr, _ in pending])
+        fetched, self._pending = self._pending[:n], self._pending[n:]
+        # a finish publishes the layers' counters: those the newest step
+        # fetched here returned ride this transfer
+        counters = next((e.counters for e in reversed(fetched)
+                         if e.counters), ()) if self._counters_due else ()
+        with self._fetch_span("tokens", behind, ticks=n,
+                              tokens=sum(len(e.items) for e in fetched)):
+            vals = jax.device_get([e.tokens for e in fetched]
+                                  + list(counters))
+        now = time.monotonic()
+        if counters:
+            self._publish_counters(vals[n:])
         touched = {}
-        for arr, (_, items) in zip(vals, pending):
+        for arr, e in zip(vals, fetched):
             a = np.asarray(arr)
-            for idx, slot, req in items:
-                # cancelled mid-flight: its slot may already belong to a
-                # NEW request — don't touch output_tokens or _toks[slot]
-                if req.state == "finished":
-                    continue
+            for idx, slot, req in e.items:
                 req._pending_n -= 1
-                # fused-step overshoot past the token budget: drop
-                if len(req.output_tokens) >= req.max_new_tokens:
-                    continue
+                if req.first_token_time is None:
+                    self._first_token(req, now)
+                touched.setdefault(req.request_id,
+                                   (req, len(req.output_tokens)))
                 t = int(a[idx])
                 req.output_tokens.append(t)
                 self._toks[slot] = t
-                touched[req.request_id] = (slot, req)
-        for slot, req in touched.values():
-            if req.eos_token_id is not None and \
-                    req.eos_token_id in req.output_tokens:
-                cut = req.output_tokens.index(req.eos_token_id) + 1
-                del req.output_tokens[cut:]
-                self._finish(req, "stop")
-            elif len(req.output_tokens) >= req.max_new_tokens:
-                self._finish(req, "length")
-            elif int(self._lens[slot]) >= self.max_model_len:
-                self._finish(req, "length")
-        for _, req in touched.values():
+        for req, seen in touched.values():
+            out = req.output_tokens
+            cap = self._token_cap(req)
+            new = out[seen:cap]
+            if req.eos_token_id is not None and req.eos_token_id in new:
+                keep, reason = seen + new.index(req.eos_token_id) + 1, "stop"
+            elif len(out) >= cap:
+                keep, reason = cap, "length"
+            else:
+                continue
+            # decoded past the finish (a fused chunk's tail): dropped
+            OVERSHOOT_TOKENS.inc(len(out) - keep)
+            del out[keep:]
+            self._finish(req, reason)
+        for req, _ in touched.values():
             # wake streaming readers AFTER the finish checks so a reader
             # never observes tokens past an eos truncation
             req._progress.set()
@@ -1610,6 +1708,15 @@ class ServingEngine:
         slot = req.slot
         self.sched.finish(req, reason)
         req._pending_n = 0
+        # what is in flight for it is never fetched. After a stop or a
+        # length that is the overshoot: the step dispatched before the
+        # host saw the finish
+        dropped = 0
+        for e in self._pending:
+            live = [it for it in e.items if it[2] is not req]
+            dropped += len(e.items) - len(live)
+            e.items[:] = live
+        self._pending = [e for e in self._pending if e.items]
         if slot is not None:
             self._tables[slot] = 0
             self._lens[slot] = 0
@@ -1623,8 +1730,10 @@ class ServingEngine:
                 d_toks, d_tables, d_lens, d_temps, d_seed = self._dev
                 self._dev = (*self._clear_slot_jit()(
                     d_toks, d_tables, d_lens, d_temps, slot), d_seed)
-        if self._counters and reason in ("stop", "length"):
-            self.layer_counters()       # the flush before it just waited
+        if reason in ("stop", "length"):
+            OVERSHOOT_TOKENS.inc(dropped)
+            # the counters ride the next token fetch (or stats())
+            self._counters_due = bool(self._counters)
         self.obs.on_finish(req, reason)
 
     # ------------------------------------------------------------ status

@@ -127,6 +127,19 @@ PROGRAMS_BUILT = _counter(
     "compile that stalls every running request.",
     labelnames=("kind",), always=True)
 
+FETCHES = _counter(
+    "serving_fetches_total",
+    "Waits for token values on the host, by whether the engine had "
+    "dispatched later work before it waited (`under_dispatch`: the device "
+    "runs on under the wait and under the host's bookkeeping after it) or "
+    "had not (`exposed`: once the values are in, the device idles until "
+    "the host dispatches again).", labelnames=("kind",), always=True)
+OVERSHOOT_TOKENS = _counter(
+    "serving_overshoot_tokens_total",
+    "Tokens decoded past a request's stop or length and dropped: the step "
+    "dispatched before the host had the token that finished it (an eos is "
+    "found a tick late), and a fused chunk's tail.", always=True)
+
 # per-tick engine gauges: FLAGS_metrics-gated (stats() is the always-on
 # view of the same numbers)
 _SLOT_OCC = _gauge("serving_slot_occupancy",
@@ -424,8 +437,9 @@ class ServingObservability:
                        prefix_matched=req.prefix_matched)
 
     def on_first_token(self, req) -> None:
-        """Prefill -> running (all three admission-completion sites): SLO
-        queue/TTFT observes + the admission span."""
+        """The first token's value reached the host (the engine has just
+        stamped `first_token_time`): SLO queue/TTFT observes + the
+        admission span."""
         q = req.queue_seconds()
         if q is not None:
             _QUEUE_H.observe(q, tier=req.tier)

@@ -57,7 +57,8 @@ class Request:
     """One generation request and its lifecycle telemetry. Timestamps are
     time.monotonic(); the engine fills them as the request moves through
     the pipeline (queue time = prefill_start - arrival, TTFT =
-    first_token - arrival)."""
+    first_token - arrival, first_token being when the first token's value
+    reached the host)."""
 
     def __init__(self, prompt: List[int], max_new_tokens: int = 16,
                  temperature: float = 0.0, eos_token_id: Optional[int] = None,
@@ -98,20 +99,20 @@ class Request:
         self.prefix_matched = 0       # prompt tokens served from the cache
         self._cow_src = None          # shared block forked at admission
         self._ws_caches = None        # contiguous prefill workspace
-        self._pending_n = 0           # sampled tokens not yet fetched
+        self._pending_n = 0           # sampled tokens in flight, not fetched
         self._reserved_blocks = 0
         # self-speculation state, attached by the engine when spec is on
         # (greedy requests only); kept after finish for telemetry
         self._drafter = None          # speculative.NgramDrafter
         self._spec = None             # speculative.SpecState
         self._done = threading.Event()  # set at finish (HTTP waiters)
-        self._progress = threading.Event()  # pulsed per output flush
+        self._progress = threading.Event()  # pulsed per token fetch
 
     def wait(self, timeout: Optional[float] = None) -> bool:
         return self._done.wait(timeout)
 
     def wait_progress(self, timeout: Optional[float] = None) -> bool:
-        """Block until more output tokens were flushed (or the request
+        """Block until more output tokens were fetched (or the request
         finished). Streaming handlers clear + re-wait in a loop."""
         return self._progress.wait(timeout)
 
@@ -233,10 +234,11 @@ class Scheduler:
         return self.prefilling[0] if self.prefilling else None
 
     def start_running(self, req: Request) -> None:
-        """Prefill done (first token sampled, prefix scattered to pages)."""
+        """Prefill done: the prefix is scattered to pages and the program
+        that samples the first token is dispatched. `first_token_time` is
+        the engine's to stamp, when the token's value is on the host."""
         self.prefilling.remove(req)
         req.state = "running"
-        req.first_token_time = time.monotonic()
         self.running[req.slot] = req
         self._publish()
 
@@ -260,7 +262,7 @@ class Scheduler:
         if req.request_id in self.allocator.sequences():
             # a window ring saw every position whose keys were written:
             # the prompt at the end of prefill, then a token a decode step
-            cached = 0 if req.first_token_time is None else \
+            cached = 0 if req.state != "running" else \
                 len(req.prompt) + max(0, len(req.output_tokens) - 1)
             self.allocator.free(req.request_id)
             for rings in self.window_rings:

@@ -12,9 +12,17 @@ CONTINUOUSLY by the single engine loop rather than serialized.
                     "telemetry": {queue_s, ttft_s, decode_tok_s, ...}}
                    With "stream": true the response is chunked
                    transfer-encoding NDJSON: one {"request_id", "tokens",
-                   "done": false} line per flushed token batch (the
-                   engine's deferred-fetch flush points), then a final
-                   {"done": true, "finish_reason", "telemetry"} line.
+                   "done": false} line per fetched token batch, then a
+                   final {"done": true, "finish_reason", "telemetry"} line.
+                   The engine fetches a tick's tokens under the NEXT
+                   tick's programs (engine._fetch), so a streamed token
+                   reaches its reader one tick after the step that
+                   sampled it, at the device's pace and not the host's;
+                   an eos is seen as late, and costs the one token the
+                   step after it decoded, which is dropped. Neither
+                   changes a result: greedy tokens and finish reasons
+                   are what a fetch in the same tick gave, and a request
+                   is `finished` only with every token in its output.
                    A client disconnect cancels the request (its slot and
                    KV reservation return to the pool immediately).
   POST /kv/export  {"tokens": [...]} -> NDJSON: one line per resident
@@ -198,11 +206,12 @@ class _Handler(BaseHTTPRequestHandler):
             self._reply(400, {"error": f"{type(e).__name__}: {e}"})
 
     def _stream(self, req, timeout: float) -> None:
-        """Chunked NDJSON: one line per engine flush with the newly
+        """Chunked NDJSON: one line per engine fetch with the newly
         materialized tokens, a final line with the finish reason and
-        telemetry. The engine pulses req's progress event at every
-        deferred-fetch flush; snapshots are taken under the engine lock so
-        a line never shows tokens past an eos truncation. A broken pipe
+        telemetry. The engine pulses req's progress event at every fetch
+        (a tick after the step that sampled the tokens); snapshots are
+        taken under the engine lock so a line never shows tokens past an
+        eos truncation. A broken pipe
         (client gone) cancels the request so it stops consuming slots."""
         import time as _time
 

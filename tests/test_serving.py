@@ -533,6 +533,87 @@ def _tiny_gpt():
     return cfg, m
 
 
+def _greedy(m, prompt, n):
+    ids = np.asarray([prompt], np.int32)
+    return [int(t) for t in m.generate(
+        paddle.to_tensor(ids), max_new_tokens=n).numpy()[0, len(prompt):]]
+
+
+def _generate_cut(m, prompt, max_new, eos, max_model_len):
+    """(tokens, finish_reason) as model.generate has them, cut where a
+    served request ends: at its eos, its budget or the context cap."""
+    toks = _greedy(m, prompt, min(max_new, max_model_len + 1 - len(prompt)))
+    if eos is not None and eos in toks:
+        return toks[:toks.index(eos) + 1], "stop"
+    return toks, "length"
+
+
+def _late_finish_cases():
+    """name -> (cfg, m, rng) -> (engine arguments, [(prompt, max_new, eos)]).
+    Three slots and blocks of 8 in every case; the request the case is
+    named for runs beside two that outlive it."""
+    def prompt(cfg, rng, n):
+        return [int(t) for t in rng.integers(0, cfg.vocab_size, n)]
+
+    def neighbours(cfg, rng):
+        return [(prompt(cfg, rng, 7), 20, None), (prompt(cfg, rng, 12), 24,
+                                                  cfg.vocab_size)]
+
+    def eos_at(m, p, i):
+        """A prompt's i-th greedy token, if no earlier one equals it."""
+        toks = _greedy(m, p, i + 1)
+        return toks[i] if toks[i] not in toks[:i] else None
+
+    def with_eos(i):
+        def case(cfg, m, rng):
+            for _ in range(32):
+                p = prompt(cfg, rng, 10)
+                eos = eos_at(m, p, i)
+                if eos is not None:
+                    return {}, [(p, 9, eos)] + neighbours(cfg, rng)
+            pytest.fail("no prompt whose greedy tokens differ")
+        return case
+
+    def eos_never(cfg, m, rng):
+        return {}, [(prompt(cfg, rng, 10), 9, cfg.vocab_size)] \
+            + neighbours(cfg, rng)
+
+    def max_new_tokens(cfg, m, rng):
+        # budgets of 1 and 2, and one whose reservation ends on a block's
+        # edge (5 + 3 = 8): the overshoot's position is the block's last,
+        # the step after it falls past the reservation, on the null block
+        return {"num_blocks": 12}, [
+            (prompt(cfg, rng, 5), 3, None), (prompt(cfg, rng, 6), 1, None),
+            (prompt(cfg, rng, 9), 2, cfg.vocab_size)] + neighbours(cfg, rng)
+
+    def max_model_len(cfg, m, rng):
+        # the context cap on a block's edge: the overshoot's position, 32,
+        # is past the table's last column
+        return {"max_model_len": 32}, [
+            (prompt(cfg, rng, 20), 64, None), (prompt(cfg, rng, 31), 8, None),
+            (prompt(cfg, rng, 27), 64, cfg.vocab_size),
+            (prompt(cfg, rng, 7), 20, None)]
+
+    def queue_and_prefix_hit(cfg, m, rng):
+        doc = prompt(cfg, rng, 16)
+        first = with_eos(0)(cfg, m, rng)[1][0]
+        mid = with_eos(3)(cfg, m, rng)[1][0]
+        return {"num_blocks": 14}, [
+            (doc + prompt(cfg, rng, 3), 6, None), first, mid,
+            (prompt(cfg, rng, 5), 1, None),
+            (doc + prompt(cfg, rng, 5), 7, cfg.vocab_size),
+            (prompt(cfg, rng, 13), 11, None),
+            (doc + prompt(cfg, rng, 2), 4, None)]
+
+    return {"eos_first_token": with_eos(0), "eos_mid_decode": with_eos(3),
+            "eos_never": eos_never, "max_new_tokens": max_new_tokens,
+            "max_model_len": max_model_len,
+            "queue_and_prefix_hit": queue_and_prefix_hit}
+
+
+_LATE_FINISH_CASES = _late_finish_cases()
+
+
 class TestServingEngine:
     @pytest.mark.slow
     def test_gpt_greedy_parity_with_static_generate(self):
@@ -661,7 +742,7 @@ class TestServingEngine:
 
         Construction: A (3 blocks, finishes by eos MID-reservation, so its
         frozen write pointer sits behind its reservation's end) and B (1
-        block, finishes by length) end in the SAME flush, A first — so C
+        block, finishes by length) end in the SAME fetch, A first — so C
         is admitted into B's slot while A's slot stays stale, and C's
         LIFO-popped table is [B's block, A's blocks...]. C's 24-token
         prompt therefore extends into A's old blocks BEHIND A's frozen
@@ -669,7 +750,7 @@ class TestServingEngine:
         sprays garbage over C's already-scattered, always-attended prompt
         tail and then trails two positions behind C's own write head —
         unless _finish cleared the device-side slot. D is a long-lived
-        deferred request: its fused admission makes the device state a
+        request: its fused admission makes the device state a
         genuine jit output (on CPU, jnp.asarray(host_mirror) can ALIAS the
         numpy buffer, so _finish's host-mirror zeroing would mask the
         stale-slot bug), and it keeps the decode loop ticking while C
@@ -691,9 +772,9 @@ class TestServingEngine:
         prompt_b = [int(t) for t in rng.integers(0, cfg.vocab_size, 5)]
         prompt_d = [int(t) for t in rng.integers(0, cfg.vocab_size, 4)]
         prompt_c = [int(t) for t in rng.integers(0, cfg.vocab_size, 24)]
-        # B gets an eos it never emits: keeps B on the non-deferred
-        # admission path, so the flush's finish order is A (slot 0) then B
-        # (slot 1) and C deterministically inherits B's slot
+        # B gets an eos it never emits. Both finishes are found by one
+        # fetch, in slot order: A (slot 0) then B (slot 1), so C
+        # deterministically inherits B's slot
         want_b = m.generate(paddle.to_tensor(np.asarray([prompt_b],
                                                         np.int32)),
                             max_new_tokens=2).numpy()[0]
@@ -721,6 +802,170 @@ class TestServingEngine:
         st = eng.stats()
         assert st["kv"]["used_blocks"] == 0 and st["running"] == 0
 
+    @pytest.mark.parametrize("case", sorted(_LATE_FINISH_CASES))
+    def test_tokens_and_reasons_equal_generate_however_late_the_finish(
+            self, case):
+        """A finish is found a tick after the step that caused it, so the
+        finishing slot decodes one step too many beside its neighbours:
+        every request's tokens and reason are model.generate's all the
+        same, no step writes a block that no live request holds (the
+        overshoot lands in the finishing request's own pages or the null
+        block, at the end of a reservation and at max_model_len too), and
+        every block and slot is free at the end."""
+        cfg, m = _tiny_gpt()
+        rng = np.random.default_rng(11)
+        engine_kw, specs = _LATE_FINISH_CASES[case](cfg, m, rng)
+        eng = ServingEngine(m, max_slots=3, block_size=8, prefill_chunk=8,
+                            **engine_kw)
+        want = [_generate_cut(m, p, n, eos, eng.max_model_len)
+                for p, n, eos in specs]
+        reqs = [eng.submit(p, max_new_tokens=n, eos_token_id=eos)
+                for p, n, eos in specs]
+
+        def held():
+            return {b for rid in eng.allocator.sequences()
+                    for b in eng.allocator.table(rid)} | {0}
+
+        waited = False
+        for _ in range(2000):
+            if not eng.sched.has_work():
+                break
+            waited |= bool(eng.sched.waiting) and not eng.sched._free_slots
+            before, pages = held(), np.asarray(eng.pool.layers[0][0])
+            eng.step()
+            untouched = sorted(set(range(eng.num_blocks)) - before - held())
+            np.testing.assert_array_equal(
+                np.asarray(eng.pool.layers[0][0])[untouched],
+                pages[untouched])
+            for r in reqs:      # finished only with every token in
+                if r.state == "finished":
+                    assert r._pending_n == 0 and r.output_tokens
+        assert not eng.sched.has_work() and not eng._pending
+        for r, (toks, reason) in zip(reqs, want):
+            assert (r.output_tokens, r.finish_reason) == (toks, reason)
+        if case == "queue_and_prefix_hit":
+            assert waited and any(r.prefix_matched for r in reqs)
+        st = eng.stats()
+        assert st["kv"]["used_blocks"] == 0 and st["reserved_blocks"] == 0
+        assert st["running"] == 0 and st["free_slots"] == 3
+        assert (eng._lens == 0).all() and (eng._tables == 0).all()
+        np.testing.assert_array_equal(np.asarray(eng._dev[1]), 0)
+
+    def test_one_dispatch_in_flight_after_a_steady_tick_none_after_an_idle(
+            self):
+        """Tick n's tokens come to the host under tick n + 1's programs:
+        after a steady decode tick exactly one dispatch is in flight (the
+        tick's own), the host has every token but that one's, and a tick
+        that dispatches nothing (the budget is already in flight) fetches
+        at once and leaves none."""
+        from paddle_tpu.observability.registry import default_registry
+
+        cfg, m = _tiny_gpt()
+        fetches = default_registry().get("serving_fetches_total")
+        before = {k: fetches.value(kind=k)
+                  for k in ("under_dispatch", "exposed")}
+        eng = ServingEngine(m, max_slots=2, block_size=16, prefill_chunk=16)
+        req = eng.submit([3, 1, 4, 1, 5], max_new_tokens=6,
+                         eos_token_id=cfg.vocab_size)   # never emitted
+        eng.step()                      # prefill, admit, the first step
+        assert [len(e.items) for e in eng._pending] == [1, 1]
+        assert req.output_tokens == [] and req.first_token_time is None
+        for n in range(2, 6):           # steady: one step a tick
+            eng.step()
+            assert len(eng._pending) == 1
+            assert eng._pending[0].tick == eng.steps - 1
+            assert len(req.output_tokens) == n and req.state == "running"
+            assert req.first_token_time is not None
+        eng.step()                      # all 6 dispatched: nothing to decode
+        assert eng._pending == [] and req.state == "finished"
+        assert len(req.output_tokens) == 6 and req.finish_reason == "length"
+        assert not eng.sched.has_work()
+        got = {k: fetches.value(kind=k) - before[k] for k in before}
+        assert got == {"under_dispatch": 4, "exposed": 1}
+
+    def test_a_prompt_alone_keeps_two_prefill_chunks_in_flight(self):
+        """While nothing decodes no fetch paces the host, and every chunk
+        in flight holds its logits on the device: before chunk k + 1 is
+        dispatched the engine waits for chunk k - 1, never for chunk k."""
+        cfg, m = _tiny_gpt()
+        eng = ServingEngine(m, max_slots=2, block_size=8, prefill_chunk=8)
+        events, real = [], eng._prefill_jit
+
+        class Unfinished:
+            """A chunk's logits that have not run until waited for."""
+
+            def __init__(self, k):
+                self.k, self.ran = k, False
+
+            def is_ready(self):
+                return self.ran
+
+            def block_until_ready(self):
+                events.append(("waited", self.k))
+                self.ran = True
+
+        def recording(chunk, padded):
+            fn = real(chunk, padded)
+
+            def call(pv, bv, ids, caches, pos):
+                k = int(pos) // chunk
+                events.append(("dispatched", k))
+                logits, caches = fn(pv, bv, ids, caches, pos)
+                # the last chunk's logits are the admission's to read
+                return (logits if k == 5 else Unfinished(k)), caches
+            return call
+
+        eng._prefill_jit = recording
+        prompt = [int(t) for t in
+                  np.random.default_rng(13).integers(0, cfg.vocab_size, 44)]
+        req = eng.submit(prompt, max_new_tokens=3)
+        eng.run_until_idle()
+        assert req.output_tokens == _greedy(m, prompt, 3)
+        assert events == [
+            ("dispatched", 0), ("dispatched", 1),
+            ("waited", 0), ("dispatched", 2), ("waited", 1),
+            ("dispatched", 3), ("waited", 2), ("dispatched", 4),
+            ("waited", 3), ("dispatched", 5)]
+
+    def test_greedy_eos_admission_on_a_warm_engine_is_one_program(self):
+        """An eos id used to send a greedy admission down the host path (a
+        wait for the chunk's logits, argmax on the host, four eager
+        scatters under serving.host_upload). It takes the fused admit
+        program like any greedy request: no slot_state upload, no wait
+        inside the admission, and nothing built on a warmed engine."""
+        from paddle_tpu.core import flags as _flags
+        from paddle_tpu.observability import spans
+
+        cfg, m = _tiny_gpt()
+        rng = np.random.default_rng(12)
+        eng = ServingEngine(m, max_slots=2, block_size=16, prefill_chunk=16)
+
+        def prompt():
+            return [int(t) for t in rng.integers(0, cfg.vocab_size, 9)]
+
+        eng.submit(prompt(), max_new_tokens=4, eos_token_id=cfg.vocab_size)
+        eng.run_until_idle()            # warm: every program of the shape
+        built = len(eng._jit)
+        old = _flags.get_flag("metrics")
+        _flags.set_flags({"metrics": "on"})
+        try:
+            mark = spans.mark()
+            req = eng.submit(prompt(), max_new_tokens=4,
+                             eos_token_id=cfg.vocab_size)
+            eng.step()
+            assert req.state == "running" and req.output_tokens == []
+            eng.run_until_idle()
+            seen = spans.since(mark)
+        finally:
+            _flags.set_flags({"metrics": old})
+        assert len(req.output_tokens) == 4 and len(eng._jit) == built
+        names = {s["name"] for s in seen}
+        assert "serving.prefill_chunk" in names and "serving.fetch" in names
+        assert "serving.program_build" not in names
+        assert "serving.host_upload" not in names
+        assert {s["args"]["what"] for s in seen
+                if s["name"] == "serving.fetch"} == {"tokens"}
+
     def test_cancel_running_request_frees_capacity(self):
         cfg, m = _tiny_gpt()
         rng = np.random.default_rng(6)
@@ -728,22 +973,27 @@ class TestServingEngine:
         p1 = [int(t) for t in rng.integers(0, cfg.vocab_size, 7)]
         eng = ServingEngine(m, max_slots=2, block_size=16, prefill_chunk=16)
         victim = eng.submit(p0, max_new_tokens=64)
-        for _ in range(4):                # running, deferred fetches queued
+        for _ in range(4):                # running, a step's tokens in flight
             eng.step()
-        assert victim.state == "running"
+        assert victim.state == "running" and eng._pending
+        got = list(victim.output_tokens)  # every tick's but the last one's
+        want0 = m.generate(paddle.to_tensor(np.asarray([p0], np.int32)),
+                           max_new_tokens=8).numpy()[0, len(p0):]
+        assert 0 < len(got) < 8 and got == [int(t) for t in want0[:len(got)]]
         assert eng.cancel(victim, reason="timeout")
+        assert not eng._pending           # dropped with the cancel, unfetched
         assert victim.state == "finished"
         assert victim.finish_reason == "timeout" and victim.wait(0)
         assert not eng.cancel(victim)     # already finished: no-op
         st = eng.stats()
         assert st["kv"]["used_blocks"] == 0 and st["running"] == 0
-        # the recycled slot + blocks still serve correctly (and the stale
-        # deferred tokens of the cancelled request are dropped at flush)
+        # the recycled slot + blocks still serve correctly (and the
+        # cancelled request's tokens in flight never reach it)
         out = eng.generate([p1], max_new_tokens=5)[0]
         want = m.generate(paddle.to_tensor(np.asarray([p1], np.int32)),
                           max_new_tokens=5).numpy()[0]
         assert out == [int(t) for t in want]
-        assert victim.output_tokens == []  # flush must not resurrect it
+        assert victim.output_tokens == got  # no fetch resurrects it
 
     def test_same_tick_sampled_admissions_draw_distinct_streams(self):
         # r11 review: two temperature>0 requests admitted in one tick must
@@ -886,6 +1136,9 @@ class TestQueueFull:
                 if st["waiting"] == 0 and st["running"] + st["prefilling"]:
                     break
                 time.sleep(0.01)
+            # the hog ends at the model's 256 positions, well within a
+            # second of ticks: hold the loop still while the queue is probed
+            eng.step = lambda: time.sleep(0.01)
             filler = eng.submit([4, 5, 6], max_new_tokens=8)  # fills queue
             body = json.dumps({"prompt": [7, 8, 9],
                                "max_new_tokens": 4}).encode()
@@ -900,6 +1153,7 @@ class TestQueueFull:
             assert payload["queue_depth"] == 1
             assert payload["queue_limit"] == 1
             assert payload["retry_after_s"] > 0
+            del eng.step
             eng.cancel(hog, reason="cancelled")
             eng.cancel(filler, reason="cancelled")
         finally:

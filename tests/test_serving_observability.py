@@ -78,7 +78,14 @@ class TestRequestTraces:
         assert "serving.admit" in names
         assert "serving.decode" in names
         assert names[-1] == "serving.finish"
-        assert names.index("serving.admit") < names.index("serving.decode")
+        # the admission (prefill start to the first token's value on the
+        # host) is recorded at the fetch, a tick after the first decode
+        # dispatch; in time it begins before it and ends after it begins
+        by_name = {}
+        for s in req.trace.spans:
+            by_name.setdefault(s["name"], s)
+        admit, decode = by_name["serving.admit"], by_name["serving.decode"]
+        assert admit["begin_ns"] < decode["begin_ns"] < admit["end_ns"]
         finish = list(req.trace.spans)[-1]
         assert finish["args"]["reason"] == "length"
         assert finish["args"]["request_id"] == req.request_id
@@ -174,6 +181,49 @@ class TestRequestTraces:
         names = req.trace.names()
         assert "serving.spec_verify" in names
         assert eng.spec_ticks > 0
+
+    def test_fetch_counters_behind_arg_and_first_token_time(self,
+                                                            metrics_on):
+        """serving_fetches_total{kind} and the `behind` arg of the
+        serving.fetch span say whether the engine had dispatched later
+        work before it waited; serving_overshoot_tokens_total counts the
+        token a late-found eos decodes too many; and first_token_time is
+        the end of the fetch that brought the first token's value, not
+        the dispatch of the program that sampled it."""
+        eng = _engine()
+        prompt = list(range(1, 9))
+        ids = paddle.to_tensor([prompt], dtype="int32")
+        toks = [int(t) for t in eng.model.generate(
+            ids, max_new_tokens=4).numpy()[0, len(prompt):]]
+        eos = next(t for t in toks[2:] if t not in toks[:toks.index(t)])
+        cut = toks.index(eos) + 1
+        fetches = registry.default_registry().get("serving_fetches_total")
+        over = registry.default_registry().get(
+            "serving_overshoot_tokens_total")
+        req = eng.submit(prompt, max_new_tokens=8, eos_token_id=eos)
+        eng.run_until_idle()
+        assert (req.output_tokens, req.finish_reason) == (toks[:cut], "stop")
+        # the eos came to the host under the step after its own: one
+        # token decoded past it, dropped unfetched
+        assert over.value() == 1
+        ring = spans.since(0)
+        ticks = {s["id"] for s in ring if s["name"] == "serving.tick"}
+        fetch = [s for s in ring if s["name"] == "serving.fetch"]
+        assert fetch and all(s["parent"] in ticks for s in fetch)
+        assert [s["args"]["behind"] for s in fetch] == [1] * len(fetch)
+        assert fetches.value(kind="under_dispatch") == len(fetch) == cut - 1
+        assert fetches.value(kind="exposed") == 0
+        # the first fetch brought the first token (and the second):
+        # stamped when it ended, a tick after the admission's dispatch
+        first = fetch[0]
+        assert first["args"]["tokens"] == 2
+        stamp = int(req.first_token_time * 1e9)
+        assert first["end_ns"] <= stamp
+        assert all(stamp <= s["begin_ns"] for s in fetch[1:])
+        decode = [s for s in ring if s["name"] == "serving.decode"]
+        assert len(decode) == cut and decode[1]["end_ns"] < stamp
+        assert req.ttft_seconds() == pytest.approx(
+            (stamp - int(req.arrival_time * 1e9)) / 1e9, abs=1e-6)
 
     def test_metrics_off_attaches_no_trace(self):
         eng = _engine()

@@ -252,7 +252,8 @@ def test_host_uploads_and_program_builds_have_spans():
     eng.generate([[1, 2, 3, 4]], max_new_tokens=3, eos_token_id=1023)
     built = _by_name("serving.program_build")
     kinds = [b["args"]["kind"] for b in built]
-    assert {"prefill", "scatter", "decode", "clear_slot"} <= set(kinds)
+    assert {"workspace", "prefill", "scatter", "admit", "decode",
+            "clear_slot"} <= set(kinds)
     assert len(kinds) == len(eng._jit)          # one span a program
     def uploads():
         return [u["args"]["what"] for u in _by_name("serving.host_upload")]
@@ -261,7 +262,11 @@ def test_host_uploads_and_program_builds_have_spans():
     n = len(built)
     eng.generate([[4, 3, 2, 1]], max_new_tokens=3, eos_token_id=1023)
     assert len(_by_name("serving.program_build")) == n   # nothing new
-    # a later admission scatters its slot into the live device state
+    # a later greedy admission, eos id or not, is the one admit program
+    assert uploads() == ["decode_state"]
+    # a sampled one draws on the host and scatters its slot into the live
+    # device state
+    eng.generate([[2, 4, 1, 3]], max_new_tokens=3, temperature=0.8)
     assert uploads() == ["decode_state", "slot_state"]
 
 
@@ -273,18 +278,21 @@ def _built(kind):
 
 def test_programs_built_rises_once_per_new_shape_and_not_on_a_repeat():
     eng = _engine(prefill_chunk=16)
-    kinds = ("prefill", "scatter", "decode", "admit")
+    kinds = ("workspace", "prefill", "scatter", "decode", "admit")
     before = {k: _built(k) for k in kinds}
     eng.generate([[1, 2, 3, 4]], max_new_tokens=3)
     first = {k: _built(k) - before[k] for k in kinds}
-    assert first == {"prefill": 1, "scatter": 1, "decode": 1, "admit": 1}
+    assert first == {"workspace": 1, "prefill": 1, "scatter": 1,
+                     "decode": 1, "admit": 1}
     eng.generate([[5, 6, 7, 8]], max_new_tokens=3)       # the same shapes
     assert {k: _built(k) - before[k] for k in kinds} == first
     eng.generate([list(range(1, 31))], max_new_tokens=3)  # a longer prompt
+    assert _built("workspace") - before["workspace"] == 2
     assert _built("prefill") - before["prefill"] == 2
     assert _built("scatter") - before["scatter"] == 2
     assert _built("decode") - before["decode"] == 1
-    total = sum(_built(k) for k in ("prefill", "scatter", "decode", "admit",
+    total = sum(_built(k) for k in ("workspace", "prefill", "scatter",
+                                    "decode", "admit",
                                     "clear_slot", "gather", "admit_cow",
                                     "batched_prefill", "spec",
                                     "decode_multi"))
@@ -325,7 +333,8 @@ def test_engine_programs_are_named_for_the_trace():
     names = {k[0]: fn.__name__ for k, fn in eng._jit.items()}
     # the decode program alone keeps `step`: decode_step_ms reads jit_step
     assert names == {
-        "decode": "step", "prefill": "serve_prefill",
+        "decode": "step", "workspace": "serve_workspace",
+        "prefill": "serve_prefill",
         "scatter": "serve_scatter", "admit": "serve_admit",
         "clear_slot": "serve_clear_slot", "gather": "serve_gather",
         "batched_prefill": "serve_batched_prefill",

@@ -10,6 +10,8 @@ from .glm_moe_lite import (  # noqa: F401
     GlmMoeLiteForCausalLM,
     GlmMoeLiteModel,
 )
+from . import xing  # noqa: F401
+from .xing import Xing4Config, Xing4ForCausalLM, Xing4Model  # noqa: F401
 from . import bert  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig,

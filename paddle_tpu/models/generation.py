@@ -40,15 +40,20 @@ class LayerCacheSpec:
     no more; "latent": ONE array of every earlier position, `head_dim` wide
     under one head (a compressed key-value latent and its rotary key, from
     which the layer's attention makes keys and values: no V is kept).
-    `counters`: int32 counters the layer adds up beside its cache
-    (a sparse layer's pairs by expert); the serving engine keeps them on the
-    device and hands them to the layer as `cache.counters`."""
+    `counters`: int32 counters the layer adds up beside its cache; the
+    serving engine keeps them on the device and hands them to the layer as
+    `cache.counters`. Their layout: a sparse layer's pairs by held expert
+    and one entry for the pairs of experts held elsewhere, then one entry
+    for each name in `extra` (additive counts of the layer's own, which the
+    engine publishes as serving_<name>_total{layer}); a layer without
+    experts keeps the `extra` ones alone."""
 
     kind: str
     kv_heads: int
     head_dim: int
     window: int = 0
     counters: int = 0
+    extra: Tuple[str, ...] = ()
 
     def __post_init__(self):
         if self.kind not in ("full", "window", "latent"):
@@ -59,6 +64,10 @@ class LayerCacheSpec:
                              "layer none")
         if self.kind == "latent" and self.kv_heads != 1:
             raise ValueError("a latent layer keeps one array under one head")
+        experts = self.counters - len(self.extra)
+        if experts < 0 or experts == 1:
+            raise ValueError("counters: the experts held and one entry for "
+                             "the others, or none, then the `extra` ones")
 
     @property
     def arrays(self) -> int:

@@ -37,8 +37,14 @@ told which they hold (`experts_held`), as Laguna's do.
 Not served: the multi-token-prediction module (`num_nextn_predict_layers`),
 a draft head for self-speculation (ROADMAP M5).
 
+A value may be narrower than a key (`v_head_dim` < nope + rope; Xing4.0,
+models/xing.py): over a cache nothing changes (the value is the latent), the
+no-cache forward pads it with zeros for its one attention op. `rope_scaling`
+(YaRN) blends the rotary part's frequencies and scales the softmax
+(`GlmMoeLiteConfig.rotary`).
+
 Assumed, where the config names a mechanism and not its formula: rotate-half
-pairing over all `qk_rope_head_dim` dimensions, no rotary scaling;
+pairing over all `qk_rope_head_dim` dimensions;
 benchmark/models/glm_moe_lite_reference.py writes the equations out and the
 tests hold this file to it.
 """
@@ -51,7 +57,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from .. import nn
 from ..core.tensor import Tensor
@@ -62,7 +67,8 @@ from ..distributed.fleet.mp_layers import (
 from ..nn import functional as F
 from ..ops import api
 from .generation import CacheSpec, LayerCacheSpec
-from .laguna import LagunaForCausalLM, LagunaModel, _linear, _normal
+from .laguna import (LagunaForCausalLM, LagunaModel, _linear, _normal,
+                     rope_inv_freq)
 from .llama import LlamaMLP
 
 
@@ -79,6 +85,7 @@ class GlmMoeLiteConfig:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 256
     rope_theta: float = 1000000.0
+    rope_scaling: Optional[dict] = None       # None, or YaRN's (see rotary())
     max_position_embeddings: int = 202752
     rms_norm_eps: float = 1e-5
     first_k_dense_replace: int = 1
@@ -108,10 +115,10 @@ class GlmMoeLiteConfig:
         self.experts_held = (lo, hi)
         if self.qk_rope_head_dim % 2:
             raise ValueError("qk_rope_head_dim must be even")
-        if self.v_head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim:
-            raise ValueError("a value of another width than a key is not "
-                             "written (the no-cache forward is one fused "
-                             "attention op)")
+        if not 0 < self.v_head_dim <= (self.qk_nope_head_dim
+                                       + self.qk_rope_head_dim):
+            raise ValueError("v_head_dim: at most a key's width (the "
+                             "no-cache forward pads a value to it)")
 
     @property
     def latent_width(self) -> int:
@@ -126,6 +133,33 @@ class GlmMoeLiteConfig:
         that anyway, and a kernel's copy of a page takes whole tiles (a
         576-wide slice of the padded array is refused by the compiler)."""
         return -(-self.latent_width // 128) * 128
+
+    def rotary(self):
+        """(inverse frequencies of the rotary part, the factor on cos and
+        sin, the softmax scale). Plain rotary, or under `rope_scaling`
+        {"type": "yarn", "factor", "original_max_position_embeddings",
+        "beta_fast", "beta_slow", "mscale", "mscale_all_dim"} YaRN's
+        blended frequencies (laguna.rope_inv_freq), cos and sin times
+        m(mscale) / m(mscale_all_dim) and the scale times m(mscale_all_dim)^2,
+        m(s) = 0.1 s ln(factor) + 1."""
+        plain = 1.0 / math.sqrt(self.qk_nope_head_dim + self.qk_rope_head_dim)
+        rs = self.rope_scaling
+        if not rs:
+            inv, _ = rope_inv_freq({"rope_theta": self.rope_theta,
+                                    "rope_type": "default"},
+                                   self.qk_rope_head_dim)
+            return inv, 1.0, plain
+        if rs["type"] != "yarn":
+            raise ValueError(f"rope_scaling type {rs['type']!r} is not "
+                             f"supported")
+        m = lambda s: 0.1 * float(s) * math.log(float(rs["factor"])) + 1.0 \
+            if float(rs["factor"]) > 1 else 1.0             # noqa: E731
+        m_all = m(rs["mscale_all_dim"])
+        inv, factor = rope_inv_freq(
+            {**rs, "rope_theta": self.rope_theta, "rope_type": "yarn",
+             "attention_factor": m(rs["mscale"]) / m_all},
+            self.qk_rope_head_dim)
+        return inv, factor, m_all * m_all * plain
 
     @staticmethod
     def tiny(**kw):
@@ -151,10 +185,7 @@ class GlmLatentAttention(nn.Layer):
             c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
         self.rank = c.kv_lora_rank
         self.row_pad = c.cache_row_width - c.latent_width
-        self.scale = 1.0 / math.sqrt(self.nope + self.rope)
-        self.inv_freq = tuple(
-            float(f) for f in 1.0 / float(c.rope_theta) ** (
-                np.arange(0, self.rope, 2, dtype=np.float64) / self.rope))
+        self.inv_freq, self.rope_factor, self.scale = c.rotary()
         std, h = c.initializer_range, self.num_heads
         self.q_a_proj = _linear(c.hidden_size, c.q_lora_rank, std)
         self.q_a_layernorm = nn.RMSNorm(c.q_lora_rank, epsilon=c.rms_norm_eps)
@@ -176,7 +207,7 @@ class GlmLatentAttention(nn.Layer):
         c = self.kv_a_layernorm(kv[:, :, :rank])                # [b, s, rank]
         q_rope, r = api.rotary_from_positions(
             q[:, :, :, nope:], api.unsqueeze(kv[:, :, rank:], 2), positions,
-            self.inv_freq)
+            self.inv_freq, factor=self.rope_factor)
         q_nope = q[:, :, :, :nope]
         # kv_b_proj by head: the key half W_uk and the value half W_uv
         w_kvb = api.reshape(self.kv_b_proj.weight, [rank, h, nope + vd])
@@ -186,10 +217,15 @@ class GlmLatentAttention(nn.Layer):
             kvx = api.reshape(self.kv_b_proj(c), [b, s, h, nope + vd])
             k = api.concat([kvx[:, :, :, :nope],
                             api.expand(r, [b, s, h, rope])], axis=-1)
+            v = kvx[:, :, :, nope:]
+            if vd < nope + rope:    # one attention op: a value as wide as
+                v = api.concat(     # a key, the rest zeros
+                    [v, api.zeros([b, s, h, nope + rope - vd], v.dtype)],
+                    axis=-1)
             out = F.scaled_dot_product_attention(
-                api.concat([q_nope, q_rope], axis=-1), k,
-                kvx[:, :, :, nope:], is_causal=True, training=False,
-                scale=self.scale)
+                api.concat([q_nope, q_rope], axis=-1), k, v,
+                is_causal=True, training=False, scale=self.scale)
+            out = out[:, :, :, :vd]
         else:
             with jax.named_scope("absorb"):
                 q_lat = api.einsum("bshn,rhn->bshr", q_nope,

@@ -349,7 +349,7 @@ class LagunaModel(nn.Layer):
             pos = Tensor(pv.astype(jnp.int32))
         positions = Tensor(start + ar)
         with jax.named_scope("embed"):
-            h = self.embed_tokens(input_ids)
+            h = self.open_streams(self.embed_tokens(input_ids))
         new_caches = []
         for i, layer in enumerate(self.layers):
             with jax.named_scope(f"h{i}"):
@@ -358,8 +358,16 @@ class LagunaModel(nn.Layer):
                               pos=pos)
             new_caches.append(nc)
         with jax.named_scope("final_norm"):
-            h = self.norm(h)
+            h = self.norm(self.close_streams(h))
         return h if caches is None else (h, new_caches)
+
+    def open_streams(self, h):
+        """What the layers pass on, made from the embedding: the one
+        residual stream itself; models/xing.py fans it out."""
+        return h
+
+    def close_streams(self, h):
+        return h
 
 
 class LagunaForCausalLM(nn.Layer, GenerationMixin):

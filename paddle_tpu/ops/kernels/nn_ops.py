@@ -951,6 +951,96 @@ def moe_experts(x, router_w, w13, w2, expert_lo=0, top_k=1, scale=1.0,
     return y.astype(x.dtype), counts
 
 
+# ------------------------------ manifold-constrained hyper-connections
+def mhc_unpack(maps, n):
+    """(H_pre [..., n], H_post [..., n], H_res [..., n, n], imbalance [...])
+    of the maps row mhc_pre returns (ops/pallas/hyper_connection.py)."""
+    res = maps[..., 2 * n:2 * n + n * n]
+    return (maps[..., :n], maps[..., n:2 * n],
+            res.reshape(res.shape[:-1] + (n, n)), maps[..., n * (n + 2)])
+
+
+def _mhc_maps_xla(x, phi, a, b, n, eps, clamp, iters):
+    xf = x.astype(jnp.float32)
+    r = lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    h = jnp.einsum("tk,mk->tm", xf, phi.astype(jnp.float32),
+                   precision=lax.Precision.HIGHEST) * r
+    a = a.astype(jnp.float32)
+    h = h * jnp.repeat(a, _np.array([n, n, n * n]),
+                       total_repeat_length=n * (n + 2)) \
+        + b.astype(jnp.float32)
+    pre = jax.nn.sigmoid(h[:, :n])
+    post = 2.0 * jax.nn.sigmoid(h[:, n:2 * n])
+    m = jnp.exp(jnp.clip(h[:, 2 * n:], clamp[0], clamp[1])).reshape(-1, n, n)
+    for _ in range(iters):      # columns (sums over the rows i), then rows
+        m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=2, keepdims=True) + eps)
+    off = jnp.max(jnp.abs(jnp.sum(m, axis=1) - 1.0), axis=-1, keepdims=True)
+    return pre, post, m, off
+
+
+def mhc_pre(x, phi, a, b, n=4, eps=1e-6, clamp=(-30.0, 30.0), iters=20):
+    """Opens a sublayer under manifold-constrained hyper-connections
+    (arXiv:2512.24880). x [..., n * C]: a token's n residual streams, stream
+    j in columns [j * C, (j + 1) * C); phi [n (n + 2), n * C] float32, its
+    rows the maps of H_pre, H_post, then H_res row-major; a [3]: the scalars
+    of the three; b [n (n + 2)]. One RMS norm (no weight) over all n * C
+    values, h = a * (phi x~) + b, H_pre = sigmoid, H_post = 2 sigmoid, H_res
+    = exp(clip(h, *clamp)) made doubly stochastic by `iters` Sinkhorn-Knopp
+    rounds (each column over its sum + eps, then each row), all in float32.
+    Returns (u [..., C] = H_pre x in x's dtype: the sublayer's input; maps
+    [..., 128] float32: [H_pre, H_post, H_res, the largest distance of a
+    column sum of H_res from 1, zeros], which mhc_post and mhc_unpack
+    read). The kernels of ops/pallas/hyper_connection.py on the TPU and in
+    interpret mode, the XLA composition elsewhere."""
+    from .. import pallas as _pallas
+    from ..pallas import hyper_connection as _hc
+
+    lead, width = x.shape[:-1], x.shape[-1]
+    x2 = x.reshape(-1, width)
+    clamp = (float(clamp[0]), float(clamp[1]))
+    if _pallas.pallas_enabled() and _hc.supports(x.shape, n, x.dtype):
+        u, maps = _hc.mhc_pre(x2, phi, a, b, n=n, eps=float(eps),
+                              clamp=clamp, iters=int(iters),
+                              interpret=_pallas.interpret_mode())
+    else:
+        pre, post, m, off = _mhc_maps_xla(x2, phi, a, b, n, eps, clamp,
+                                          iters)
+        t = x2.shape[0]
+        u = jnp.einsum("tj,tjc->tc", pre,
+                       x2.reshape(t, n, -1).astype(jnp.float32)
+                       ).astype(x.dtype)
+        maps = jnp.concatenate(
+            [pre, post, m.reshape(t, n * n), off,
+             jnp.zeros((t, _hc.MAPS_WIDTH - n * (n + 2) - 1), jnp.float32)],
+            axis=-1)
+    return u.reshape(lead + (width // n,)), \
+        maps.reshape(lead + (_hc.MAPS_WIDTH,))
+
+
+def mhc_post(x, y, maps, n=4):
+    """Closes a sublayer: x' = H_res x + H_post^T y, x [..., n * C] the
+    streams mhc_pre read, y [..., C] the sublayer's output, maps what
+    mhc_pre returned. The sum in float32, x' in x's dtype."""
+    from .. import pallas as _pallas
+    from ..pallas import hyper_connection as _hc
+
+    lead, width = x.shape[:-1], x.shape[-1]
+    x2, y2 = x.reshape(-1, width), y.reshape(-1, width // n)
+    maps2 = maps.reshape(-1, maps.shape[-1])
+    if _pallas.pallas_enabled() and _hc.supports(x.shape, n, x.dtype):
+        out = _hc.mhc_post(x2, y2, maps2, n=n,
+                           interpret=_pallas.interpret_mode())
+    else:
+        _, post, res, _ = mhc_unpack(maps2, n)
+        t = x2.shape[0]
+        out = (jnp.einsum("tij,tjc->tic", res,
+                          x2.reshape(t, n, -1).astype(jnp.float32))
+               + post[:, :, None] * y2.astype(jnp.float32)[:, None, :]
+               ).reshape(t, width).astype(x.dtype)
+    return out.reshape(lead + (width,))
+
+
 # ------------------------------------------------- cached decode attention
 def cached_multihead_attention(q, k, v, k_cache, v_cache, pos, scale=None,
                                window=None):

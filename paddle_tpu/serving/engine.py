@@ -126,6 +126,21 @@ _MOE_LOAD = _gauge(
     "serving_moe_expert_load_max_over_mean",
     "Busiest held expert's pairs over the mean held expert's, by layer, "
     "over the decode steps so far.", labelnames=("layer",), always=True)
+# LayerCacheSpec.extra: a layer's own additive counters, by name
+_LAYER_COUNTERS = {
+    "mhc_applications": _counter(
+        "serving_mhc_applications_total",
+        "Token-applications of the residual mix (manifold-constrained "
+        "hyper-connections: two a layer a token) the decode steps ran, by "
+        "layer. Added up on the device, published as the pairs are.",
+        labelnames=("layer",), always=True),
+    "mhc_unbalanced": _counter(
+        "serving_mhc_unbalanced_total",
+        "Of those, the ones whose residual map had a column sum off 1 by "
+        "more than 1e-3 after the last Sinkhorn round: 0 while the rounds "
+        "converge under the served inputs.",
+        labelnames=("layer",), always=True),
+}
 _WINDOW_KEYS = _counter(
     "serving_window_keys_total",
     "Keys of window layers a decode step's attention fetched (`read`: the "
@@ -312,6 +327,11 @@ class ServingEngine:
         self._counters_published = [np.zeros(spec.layers[i].counters,
                                              np.int64)
                                     for i in self._counter_layers]
+        unknown = {name for l in spec.layers for name in l.extra} \
+            - set(_LAYER_COUNTERS)
+        if unknown:
+            raise ValueError(f"LayerCacheSpec.extra names {sorted(unknown)}: "
+                             f"the engine publishes {sorted(_LAYER_COUNTERS)}")
         # host mirror of per-slot decode state; the authoritative copies
         # live on device in _dev and are updated incrementally (per-slot
         # scatter on admission / block-table growth) — the decode loop
@@ -1472,8 +1492,9 @@ class ServingEngine:
         """{layer index: counters} fetched from the device (one transfer,
         which waits for the step in flight: stats() pays it, a finish does
         not, see _fetch), and what they add since the last fetch published
-        to the registry: for a sparse layer, pairs by held expert and last
-        the pairs of experts held elsewhere."""
+        to the registry: for a sparse layer, pairs by held expert and the
+        pairs of experts held elsewhere, then the layer's `extra` counters
+        (LayerCacheSpec)."""
         if not self._counters:
             return {}
         with self.obs.span("serving.fetch", what="layer_counters", ticks=0,
@@ -1486,12 +1507,18 @@ class ServingEngine:
         for i, v, seen in zip(self._counter_layers, vals,
                               self._counters_published):
             new = v - seen
-            _MOE_PAIRS.inc(int(new[:-1].sum()), held="yes")
-            _MOE_PAIRS.inc(int(new[-1]), held="no")
             seen[:] = v
-            if v[:-1].sum() > 0:
-                _MOE_LOAD.set(float(v[:-1].max() / v[:-1].mean()),
-                              layer=f"h{i}")
+            extra = self._spec.layers[i].extra
+            n = len(v) - len(extra)     # the experts held and one for the others
+            for name, add in zip(extra, new[n:]):
+                _LAYER_COUNTERS[name].inc(int(add), layer=f"h{i}")
+            if not n:
+                continue
+            held = v[:n - 1]
+            _MOE_PAIRS.inc(int(new[:n - 1].sum()), held="yes")
+            _MOE_PAIRS.inc(int(new[n - 1]), held="no")
+            if held.sum() > 0:
+                _MOE_LOAD.set(float(held.max() / held.mean()), layer=f"h{i}")
         self._counters_due = False
         return dict(zip(self._counter_layers, vals))
 

@@ -593,3 +593,99 @@ def test_no_program_relayouts_either_cache_groups_pool(laguna_pool_programs,
     if name == "step":
         for kernel in ("paged_decode", "moe_grouped_matmul"):
             assert kernel in text
+
+
+# ---- Xing4.0-29B-A4B (PR 36): the residual mix's two kernels at 4 streams
+# ---- of 3,584, one block of 128 tokens and a chunk's 512; and the agent
+# ---- cell's step and prefill programs at the published widths (32 heads,
+# ---- value 128, a 640-lane latent row, 64 groups of 1,024), two layers
+_X_STREAMS, _X_HIDDEN = 4, 3584
+
+
+@pytest.mark.parametrize("tokens", [128, 512], ids=["block", "chunk"])
+def test_residual_mix_kernels_at_the_published_widths(one_chip, tokens):
+    from paddle_tpu.ops.pallas import hyper_connection as hc
+
+    width, maps = _X_STREAMS * _X_HIDDEN, _X_STREAMS * (_X_STREAMS + 2)
+    pre = _compile(
+        lambda x, phi, a, b: hc.mhc_pre(x, phi, a, b, n=_X_STREAMS, eps=1e-6,
+                                        clamp=(-30.0, 30.0), iters=20),
+        one_chip, ((tokens, width), jnp.bfloat16), ((maps, width), jnp.float32),
+        ((3,), jnp.float32), ((maps,), jnp.float32))
+    assert "%mhc_pre" in pre.as_text()
+    post = _compile(
+        lambda x, y, m: hc.mhc_post(x, y, m, n=_X_STREAMS), one_chip,
+        ((tokens, width), jnp.bfloat16), ((tokens, _X_HIDDEN), jnp.bfloat16),
+        ((tokens, hc.MAPS_WIDTH), jnp.float32))
+    assert "%mhc_post" in post.as_text()
+
+
+@pytest.fixture(scope="module")
+def xing_programs(one_chip):
+    """The decode step and a prefill chunk of a Xing4 model at the published
+    widths, a dense and a sparse layer, the agent cell's slots, block, pool
+    and longest workspace; the vocabulary and the dense width are small."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models import Xing4Config, Xing4ForCausalLM
+    from paddle_tpu.nn import initializer as I
+    from paddle_tpu.serving import ServingEngine
+
+    cfg = Xing4Config(vocab_size=1024, intermediate_size=1024, num_layers=2,
+                      first_k_dense_replace=1)
+    paddle.set_default_dtype("bfloat16")
+    I.set_global_initializer(I.Constant(0.0))
+    try:
+        model = Xing4ForCausalLM(cfg)
+    finally:
+        I.set_global_initializer(None)
+        paddle.set_default_dtype("float32")
+    # the longest workspace: a 16,384-token context and a 1,024-token turn
+    slots, blocks, block, padded = 24, 2561, 128, 17408
+    engine = ServingEngine(model, max_slots=slots, block_size=block,
+                           num_blocks=2, prefill_chunk=512,
+                           max_model_len=17664)
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32 = jnp.int32
+    pv, bv = jax.tree_util.tree_map(lambda x: sd(x.shape, x.dtype),
+                                    engine._functional()[2:])
+    pool = (blocks, 1, block, cfg.cache_row_width)
+    pages = [(sd(pool, jnp.bfloat16),)] * cfg.num_layers
+    lens = sd((slots,), i32)
+    counters = tuple(sd((engine._spec.layers[i].counters,), i32)
+                     for i in engine._counter_layers)
+    work = [(sd((1, padded, 1, cfg.cache_row_width), jnp.bfloat16),)] \
+        * cfg.num_layers
+    return pool, {
+        "step": (engine._decode_jit(False), (
+            pv, bv, lens, pages, sd((slots, engine._table_cols), i32), lens,
+            sd((slots,), jnp.float32), sd((), i32), counters)),
+        "serve_prefill": (engine._prefill_jit(512, padded), (
+            pv, bv, sd((1, 512), i32), work, sd((), i32))),
+    }
+
+
+@pytest.mark.parametrize("name", ["step", "serve_prefill"])
+def test_xing_programs_hold_the_mix_and_leave_the_pool_where_it_lies(
+        xing_programs, monkeypatch, name):
+    pool, programs = xing_programs
+    fn, args = programs[name]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule jit_{name},")
+    assert not _pool_relayouts(text, pool)
+    # a decode step's 24 rows take the mix's XLA form
+    kernels = {"step": ("latent_decode", "moe_grouped_matmul"),
+               "serve_prefill": ("mhc_pre", "mhc_post", "latent_prefill",
+                                 "moe_grouped_matmul")}[name]
+    for kernel in kernels:
+        assert f"%{kernel}" in text, kernel
+    # the two mixes of a layer, under their scopes
+    for scope in ("h0/hc.attn", "h0/hc.mlp", "h1/hc.attn", "h1/hc.mlp"):
+        assert scope in text, scope
+    if name == "step":  # both layers' pools updated where they came in
+        assert compiled.memory_analysis().alias_size_in_bytes \
+            >= 2 * 2 * pool[0] * pool[2] * pool[3]

@@ -362,26 +362,7 @@ class GPTForCausalLM(nn.Layer, GenerationMixin):
             # (labels=input_ids is the natural call, as in the reference
             # pretrain pipeline). An unshifted CE here would train the
             # copy task — causal attention sees token i at position i.
-            import jax.numpy as jnp
-
-            from ..core.tensor import Tensor
-
-            v = self.config.vocab_size
-            with jax.named_scope("loss"):
-                shift_logits = api.reshape(logits[:, :-1, :], [-1, v])
-                lab = labels._value if isinstance(labels, Tensor) else \
-                    jnp.asarray(labels)
-                shift_lab = lab[:, 1:]
-                if segments is not None:
-                    seg_v = (segments._value if isinstance(segments, Tensor)
-                             else jnp.asarray(segments))
-                    # a pair crossing a packed-document boundary is not a
-                    # next-token example; padding (-1 segment) masks too
-                    same_doc = (seg_v[:, 1:] == seg_v[:, :-1]) \
-                        & (seg_v[:, 1:] >= 0)
-                    shift_lab = jnp.where(same_doc, shift_lab, -100)
-                return F.cross_entropy(shift_logits,
-                                       api.reshape(Tensor(shift_lab), [-1]))
+            return F.causal_lm_loss(logits, labels, segments)
         return logits
 
 
@@ -441,11 +422,9 @@ def _gpt_untied_head_fwd(layer, h):
 
 
 def _gpt_pipeline_loss(out, label):
-    # shifted next-token CE, matching GPTForCausalLM.forward so
-    # pipeline-vs-sequential parity compares the same objective
-    v = out.shape[-1]
-    return F.cross_entropy(api.reshape(out[:, :-1, :], [-1, v]),
-                           api.reshape(label[:, 1:], [-1]))
+    # the objective of GPTForCausalLM.forward, so pipeline-vs-sequential
+    # parity compares the same thing
+    return F.causal_lm_loss(out, label)
 
 
 def _gpt_pipeline_descs(self):

@@ -400,10 +400,5 @@ class LagunaForCausalLM(nn.Layer, GenerationMixin):
                 return self.lm_head(h), new_caches
         logits = self.lm_head(self.model(input_ids))
         if labels is not None:
-            v = logits.shape[-1]
-            lab = labels._value if isinstance(labels, Tensor) else \
-                jnp.asarray(labels)
-            return F.cross_entropy(
-                api.reshape(logits[:, :-1, :], [-1, v]),
-                api.reshape(Tensor(lab[:, 1:]), [-1]))
+            return F.causal_lm_loss(logits, labels)
         return logits

@@ -293,20 +293,7 @@ class LlamaForCausalLM(nn.Layer, GenerationMixin):
         h = self.model(input_ids, segments=segments)
         logits = self._head(h)
         if labels is not None:
-            b, s, v = logits.shape
-            shift_logits = api.reshape(logits[:, :-1, :], [-1, v])
-            lab = labels._value if isinstance(labels, Tensor) else \
-                jnp.asarray(labels)
-            shift_lab = lab[:, 1:]
-            if segments is not None:
-                seg_v = (segments._value if isinstance(segments, Tensor)
-                         else jnp.asarray(segments))
-                same_doc = (seg_v[:, 1:] == seg_v[:, :-1]) \
-                    & (seg_v[:, 1:] >= 0)  # padding (-1) pairs are not
-                #                            next-token examples either
-                shift_lab = jnp.where(same_doc, shift_lab, -100)
-            shift_labels = api.reshape(Tensor(shift_lab), [-1])
-            return F.cross_entropy(shift_logits, shift_labels)
+            return F.causal_lm_loss(logits, labels, segments)
         return logits
 
 
@@ -376,10 +363,7 @@ def _llama_untied_head_fwd(layer, h):
 
 
 def _llama_pipeline_loss(out, label):
-    v = out.shape[-1]
-    shift_logits = api.reshape(out[:, :-1, :], [-1, v])
-    shift_labels = api.reshape(label[:, 1:], [-1])
-    return F.cross_entropy(shift_logits, shift_labels)
+    return F.causal_lm_loss(out, label)
 
 
 def _llama_pipeline_descs(self):

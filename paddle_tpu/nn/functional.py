@@ -155,6 +155,23 @@ def softmax_with_cross_entropy(logits, label, soft_label=False, ignore_index=-10
     return loss
 
 
+def causal_lm_loss(logits, labels, segments=None, ignore_index=-100):
+    """The next-token loss every causal LM here trains on (op
+    causal_lm_loss, under the named scope `loss`): logits [b, s, v] as the
+    head produced them, labels [b, s] unshifted, segments the optional
+    packed-document ids. Labels and segments may be plain arrays."""
+    import jax
+
+    from ..core.tensor import Tensor
+
+    def tensor(x):
+        return x if x is None or isinstance(x, Tensor) else Tensor(x)
+
+    with jax.named_scope("loss"):
+        return _api.causal_lm_loss(logits, tensor(labels), tensor(segments),
+                                   ignore_index)
+
+
 def sequence_mask(lengths, maxlen=None, dtype="int64"):
     if maxlen is None:
         maxlen = int(lengths.max().item())

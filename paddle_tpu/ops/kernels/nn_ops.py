@@ -638,6 +638,69 @@ def cross_entropy(
     return loss
 
 
+def causal_lm_loss(logits, labels, segments=None, ignore_index=-100):
+    """Next-token loss of a causal language model: the mean, over the rows
+    that count, of -log softmax(logits[b, i])[labels[b, i + 1]].
+
+    logits [b, s, v] in the dtype the head produced, labels [b, s] UNSHIFTED
+    (labels = input_ids is the natural call), segments optional [b, s]
+    packed-document ids (padding -1). The shift is on the labels: every one
+    of the b x s rows is read, and the last position of a sequence, a pair
+    that crosses a packed document's boundary, padding and a label equal to
+    ignore_index count for nothing. Equal to cross_entropy(logits[:, :-1]
+    .reshape(-1, v), labels[:, 1:].reshape(-1)), without the copy that slice
+    forces (s - 1 rows fill no tile) and without an array of the logits'
+    size in float32: the row statistics are float32 sums inside reductions
+    over the logits as they lie, and the gradient is one elementwise pass
+    written in the logits' dtype (see _next_token_nll)."""
+    lab = labels.astype(jnp.int32)
+    s = lab.shape[1]
+    keep = lax.broadcasted_iota(jnp.int32, lab.shape, 1) < s - 1
+    if segments is not None:
+        nxt = jnp.roll(segments, -1, axis=1)
+        keep &= (nxt == segments) & (nxt >= 0)
+    target = jnp.where(keep, jnp.roll(lab, -1, axis=1), ignore_index)
+    return _next_token_nll(logits, target, int(ignore_index))
+
+
+def _row_targets(logits, target):
+    """[.., v] bool: the class each row is scored against. A comparison with
+    an iota and not a gather: it fuses into the pass that reads the logits,
+    partitions over a sharded vocabulary, and no class equals a negative
+    ignore_index."""
+    classes = lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    return classes == target[..., None]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _next_token_nll(logits, target, ignore_index):
+    return _next_token_nll_fwd(logits, target, ignore_index)[0]
+
+
+def _next_token_nll_fwd(logits, target, ignore_index):
+    x = logits.astype(jnp.float32)  # in registers: every use is a reduction
+    m = jnp.max(x, axis=-1)
+    shifted = x - m[..., None]
+    log_z = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
+    picked = jnp.sum(jnp.where(_row_targets(logits, target), shifted, 0.0),
+                     axis=-1)
+    valid = target != ignore_index
+    count = jnp.maximum(jnp.sum(valid), 1).astype(jnp.float32)
+    loss = jnp.sum(jnp.where(valid, log_z - picked, 0.0)) / count
+    return loss, (logits, m + log_z, target, count)
+
+
+def _next_token_nll_bwd(ignore_index, residuals, g):
+    logits, lse, target, count = residuals
+    weight = jnp.where(target != ignore_index, g / count, 0.0)
+    p = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+    d = (p - _row_targets(logits, target)) * weight[..., None]
+    return d.astype(logits.dtype), None
+
+
+_next_token_nll.defvjp(_next_token_nll_fwd, _next_token_nll_bwd)
+
+
 def nll_loss(input, label, weight=None, ignore_index=-100, reduction="mean"):
     return _nll(input, label, weight, ignore_index, reduction)
 

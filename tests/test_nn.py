@@ -184,6 +184,125 @@ def test_cross_entropy_ignore_index():
     np.testing.assert_allclose(float(loss.item()), expected, atol=1e-5)
 
 
+# ---- the next-token loss (op causal_lm_loss): the shift on the labels, the
+# ---- logits read in the dtype the head produced
+def _shifted_cross_entropy(logits, labels, segments=None):
+    """What every causal LM here computed before the op: slice, reshape,
+    cross_entropy (float32 inside), packed pairs masked on the labels."""
+    from paddle_tpu.nn import functional as F
+    from paddle_tpu.ops import api
+
+    lab = np.asarray(labels)[:, 1:].copy()
+    if segments is not None:
+        seg = np.asarray(segments)
+        lab[~((seg[:, 1:] == seg[:, :-1]) & (seg[:, 1:] >= 0))] = -100
+    v = logits.shape[-1]
+    return F.cross_entropy(
+        api.reshape(api.cast(logits, "float32")[:, :-1, :], [-1, v]),
+        paddle.to_tensor(lab.reshape(-1)))
+
+
+def _lm_case(case, b=3, s=17, v=97):
+    rng = np.random.default_rng(37)
+    logits = (3 * rng.standard_normal((b, s, v))).astype(np.float32)
+    labels = rng.integers(0, v, (b, s)).astype(np.int32)
+    segments = None
+    if case == "ignored_labels":
+        labels[0, 3] = labels[2, 1:9] = labels[1, -1] = -100
+    elif case == "packed_segments":
+        segments = np.sort(rng.integers(0, 3, (b, s)), axis=1).astype(np.int32)
+        segments[1, -4:] = -1                       # padding
+    return logits, labels, segments
+
+
+@pytest.mark.parametrize("case", ["plain", "ignored_labels", "packed_segments"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_causal_lm_loss_is_the_shifted_cross_entropy(dtype, case):
+    from paddle_tpu.nn import functional as F
+
+    logits, labels, segments = _lm_case(case)
+    x = paddle.to_tensor(logits).astype(dtype)
+    x.stop_gradient = False
+    loss = F.causal_lm_loss(x, labels, segments)    # plain arrays are taken
+    loss.backward()
+    ref_x = paddle.to_tensor(x.numpy().astype(np.float32), stop_gradient=False)
+    want = _shifted_cross_entropy(ref_x, labels, segments)
+    want.backward()
+    assert str(loss.dtype).endswith("float32") and x.grad.dtype == x.dtype
+    got_g = x.grad.numpy().astype(np.float32)
+    want_g = ref_x.grad.numpy()
+    # float32: both are float32 throughout. bfloat16: the value from the
+    # same rounded logits with float32 sums; the gradient rounded once
+    value_tol, grad_tol = (1e-6, 1e-6) if dtype == "float32" else (1e-5, 2 ** -8)
+    assert abs(float(loss) - float(want)) <= value_tol * abs(float(want))
+    assert np.all(np.abs(got_g - want_g)
+                  <= grad_tol * np.maximum(np.abs(want_g), 1e-3 / labels.size))
+    # the last position of every sequence scores nothing
+    assert not got_g[:, -1, :].any()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_causal_lm_loss_with_every_label_ignored(dtype):
+    from paddle_tpu.nn import functional as F
+
+    logits, labels, _ = _lm_case("plain")
+    x = paddle.to_tensor(logits).astype(dtype)
+    x.stop_gradient = False
+    loss = F.causal_lm_loss(x, np.full_like(labels, -100))
+    loss.backward()
+    assert float(loss) == 0.0
+    assert not x.grad.numpy().astype(np.float32).any()      # zeros, no NaN
+
+
+def _lm_model(family):
+    from paddle_tpu import models
+
+    paddle.seed(37)
+    if family == "gpt":
+        return models.GPTForCausalLM(models.GPTConfig(
+            vocab_size=96, hidden_size=32, num_layers=1, num_heads=2,
+            max_position_embeddings=32, hidden_dropout_prob=0.0,
+            attention_dropout_prob=0.0))
+    if family == "llama":
+        return models.LlamaForCausalLM(models.LlamaConfig(
+            vocab_size=96, hidden_size=32, num_layers=1, num_heads=2,
+            num_key_value_heads=2, intermediate_size=64,
+            max_position_embeddings=32))
+    return models.LagunaForCausalLM(models.LagunaConfig.tiny())
+
+
+@pytest.mark.parametrize("site", ["gpt", "gpt_segments", "llama",
+                                  "llama_segments", "laguna",
+                                  "gpt_pipeline", "llama_pipeline"])
+def test_every_causal_lm_gives_the_loss_it_gave(site):
+    """The five places that held the shift-and-cross_entropy code call the
+    one op now: each still returns that loss."""
+    from paddle_tpu.models import gpt, llama
+
+    family, _, variant = site.partition("_")
+    if variant == "pipeline":
+        logits, labels, _ = _lm_case("plain")
+        out = paddle.to_tensor(logits)
+        loss_fn = {"gpt": gpt._gpt_pipeline_loss,
+                   "llama": llama._llama_pipeline_loss}[family]
+        got, want = loss_fn(out, paddle.to_tensor(labels)), \
+            _shifted_cross_entropy(out, labels)
+    else:
+        model = _lm_model(family)
+        model.eval()
+        ids = np.random.default_rng(5).integers(0, 96, (2, 16)).astype(np.int32)
+        kw = {}
+        if variant == "segments":
+            seg = np.repeat(np.array([[0, 1, 2, -1], [0, 0, 1, 1]], np.int32),
+                            4, axis=1)
+            kw["segments"] = paddle.to_tensor(seg)
+        t = paddle.to_tensor(ids)
+        got = model(t, labels=t, **kw)
+        want = _shifted_cross_entropy(model(t, **kw), ids,
+                                      seg if kw else None)
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-6)
+
+
 def test_clip_grad_by_global_norm():
     p1 = paddle.to_tensor(np.zeros(3, np.float32), stop_gradient=False)
     p2 = paddle.to_tensor(np.zeros(4, np.float32), stop_gradient=False)

@@ -370,15 +370,14 @@ def test_fused_rope(one_chip, packed):
 # ---- the names a device trace shows: XLA modules after the jitted function,
 # ---- kernel events after pl.pallas_call(name=) (the benchmark's readers
 # ---- find them by these, so a rename is a change to the yardstick)
-def _tiny_train_step():
+def _gpt_train_step(**config):
+    """TrainStep over a GPT under amp O1, as the cell and the smoke build it."""
     import paddle_tpu as paddle
     from paddle_tpu.jit import TrainStep
     from paddle_tpu.models import GPTConfig, GPTForCausalLM
 
-    cfg = GPTConfig(vocab_size=1024, hidden_size=128, num_layers=2,
-                    num_heads=2, max_position_embeddings=256,
-                    hidden_dropout_prob=0.0, attention_dropout_prob=0.0)
-    model = GPTForCausalLM(cfg)
+    model = GPTForCausalLM(GPTConfig(hidden_dropout_prob=0.0,
+                                     attention_dropout_prob=0.0, **config))
     opt = paddle.optimizer.AdamW(1e-3, parameters=model.parameters())
 
     def loss_fn(b):
@@ -388,20 +387,32 @@ def _tiny_train_step():
     return TrainStep(model, loss_fn, opt)
 
 
+def _tiny_train_step():
+    return _gpt_train_step(vocab_size=1024, hidden_size=128, num_layers=2,
+                           num_heads=2, max_position_embeddings=256)
+
+
+def _compiled_train_step_text(step, one_chip, monkeypatch, batch):
+    """The step's program for the described chip, as the chip runs it (the
+    attention op asks jax.default_backend() and is steered here)."""
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        ([p._value for p in step.params], [b._value for b in step.buffers],
+         step.opt_state, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32),
+         [jnp.zeros(batch, jnp.int32)]))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = step._jitted.lower(*args).compile().as_text()
+    assert text.startswith("HloModule jit_train_step,")
+    return text
+
+
 def test_train_step_is_jit_train_step_with_named_flash_kernels(
         one_chip, monkeypatch):
     """Through TrainStep, as the chip runs it: under the program's own
     autograd the kernels come out as %flash_fwd.N, %flash_bwd_dq.N and
     %flash_bwd_dkv.N (a plain jax.grad would call them %jvp_flash_fwd_.N)."""
-    step = _tiny_train_step()
-    args = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        ([p._value for p in step.params], [b._value for b in step.buffers],
-         step.opt_state, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32),
-         [jnp.zeros((8, 256), jnp.int32)]))
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    text = step._jitted.lower(*args).compile().as_text()
-    assert text.startswith("HloModule jit_train_step,")
+    text = _compiled_train_step_text(_tiny_train_step(), one_chip,
+                                     monkeypatch, (8, 256))
     for kernel in ("%flash_fwd.", "%flash_bwd_dq.", "%flash_bwd_dkv."):
         assert kernel in text, kernel
     kernels = [ln.split()[0] for ln in text.splitlines()
@@ -409,6 +420,55 @@ def test_train_step_is_jit_train_step_with_named_flash_kernels(
     assert kernels and all(k.startswith("%flash_") for k in kernels), kernels
     for scope in ("h0/attn", "h1/mlp", "loss", "optimizer", "lm_head"):
         assert f'op_name="jit(train_step)/{scope}/' in text, scope
+
+
+def _vocab_wide_arrays(text, vocab):
+    """(dtype, dims) of every array an instruction of the ENTRY computation
+    produces that has a `vocab`-wide dimension: what the step keeps in HBM
+    (instructions inside fused computations live in registers and VMEM)."""
+    entry = re.search(r"\nENTRY [^\n]*\{\n(.*?)\n\}", text, re.S).group(1)
+    found = []
+    for line in entry.splitlines():
+        lhs, _, rhs = line.partition(" = ")
+        if "parameter(" in rhs:
+            continue
+        result = rhs[:rhs.index("(", 1)] if rhs.startswith("(") \
+            else rhs.split(" ", 1)[0]
+        for dtype, dims in re.findall(r"\b([a-z]+[0-9]+)\[([0-9,]+)\]",
+                                      result):
+            dims = tuple(int(d) for d in dims.split(","))
+            if vocab in dims:
+                found.append((dtype, dims))
+    return found
+
+
+def test_train_step_keeps_no_float32_array_of_the_logits_size(
+        one_chip, monkeypatch):
+    """The train cell's shape (b8, s1024, v50304, amp O1; two layers, the
+    head and the loss do not depend on the depth): the next-token loss reads
+    the head's bf16 logits where they lie. No float32 array with a
+    50,304-wide dimension and 8,184 or more rows reaches HBM (the parent
+    wrote two, 1.65 GB each, and crossed them five times a step), the
+    last position is masked and not sliced (no `[8,1023,50304]` array of any
+    dtype), and the loss adds no kernel: three flash kernels a layer, which
+    is all `flash_attn_roofline` reads."""
+    import numpy as np
+
+    b, s, v, layers = 8, 1024, 50304, 2
+    step = _gpt_train_step(vocab_size=v, hidden_size=1024, num_layers=layers,
+                           num_heads=16, max_position_embeddings=s)
+    text = _compiled_train_step_text(step, one_chip, monkeypatch, (b, s))
+    wide = _vocab_wide_arrays(text, v)
+    assert ("bf16", (b, s, v)) in wide, wide          # the logits themselves
+    for dtype, dims in wide:
+        rows = int(np.prod(dims)) // v
+        assert not (dtype == "f32" and rows >= b * (s - 1)), (dtype, dims)
+        assert s - 1 not in dims, (dtype, dims)
+    kernels = [ln.split()[0] for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(kernels) == 3 * layers, kernels
+    assert all(k.startswith("%flash_") for k in kernels), kernels
+    assert 'op_name="jit(train_step)/loss/' in text
 
 
 def test_decode_program_is_jit_step_with_a_named_kernel(pool_programs,

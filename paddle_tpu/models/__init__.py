@@ -12,6 +12,12 @@ from .glm_moe_lite import (  # noqa: F401
 )
 from . import xing  # noqa: F401
 from .xing import Xing4Config, Xing4ForCausalLM, Xing4Model  # noqa: F401
+from . import glm5_flash  # noqa: F401
+from .glm5_flash import (  # noqa: F401
+    Glm5FlashConfig,
+    Glm5FlashForCausalLM,
+    Glm5FlashModel,
+)
 from . import bert  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig,

@@ -39,7 +39,19 @@ class LayerCacheSpec:
     "window": of the last `window` positions only, so a holder may keep
     no more; "latent": ONE array of every earlier position, `head_dim` wide
     under one head (a compressed key-value latent and its rotary key, from
-    which the layer's attention makes keys and values: no V is kept).
+    which the layer's attention makes keys and values: no V is kept); a
+    latent layer with an `index_dim` keeps a SECOND array, an indexer key
+    pooled over every `pool` positions (learned sparse attention), and both
+    its arrays by groups of `pool` positions, a group a row: the latent
+    [.., positions / pool, pool * head_dim], the pooled key [.., positions /
+    pool, index_dim]; a query reads `index_topk` of the pooled blocks and
+    its own; "state": arrays of a fixed size a sequence, whatever
+    its length (a linear-attention layer's recurrent state and its
+    convolution's tail), of the per-sequence shapes `state` (the first in
+    float32, the rest in the cache's dtype), which a holder keeps a slot
+    and not a page. A prefill workspace of a layer that has to know where
+    a padded prompt ends (state, or a pooled key) carries, last, the
+    sequence's length as an int32 array [batch] (`limit`).
     `counters`: int32 counters the layer adds up beside its cache; the
     serving engine keeps them on the device and hands them to the layer as
     `cache.counters`. Their layout: a sparse layer's pairs by held expert
@@ -54,11 +66,21 @@ class LayerCacheSpec:
     window: int = 0
     counters: int = 0
     extra: Tuple[str, ...] = ()
+    index_dim: int = 0
+    pool: int = 1
+    index_topk: int = 0
+    state: Tuple[Tuple[int, ...], ...] = ()
 
     def __post_init__(self):
-        if self.kind not in ("full", "window", "latent"):
-            raise ValueError(f"cache kind {self.kind!r}: full, window or "
-                             f"latent")
+        if self.kind not in ("full", "window", "latent", "state"):
+            raise ValueError(f"cache kind {self.kind!r}: full, window, "
+                             f"latent or state")
+        if (self.kind == "state") != bool(self.state):
+            raise ValueError("a state layer states the shapes of its arrays, "
+                             "another layer none")
+        if self.index_dim and (self.kind != "latent" or self.pool < 2):
+            raise ValueError("a pooled index key belongs to a latent layer "
+                             "that pools 2 or more positions")
         if (self.kind == "window") != (self.window > 0):
             raise ValueError("a window layer states its window, a full "
                              "layer none")
@@ -71,8 +93,45 @@ class LayerCacheSpec:
 
     @property
     def arrays(self) -> int:
-        """Arrays a holder keeps for the layer: K and V, or the one latent."""
-        return 1 if self.kind == "latent" else 2
+        """Arrays a holder keeps for the layer: K and V, the one latent (and
+        its pooled index key), or the state's."""
+        if self.kind == "state":
+            return len(self.state)
+        if self.kind == "latent":
+            return 2 if self.index_dim else 1
+        return 2
+
+    @property
+    def has_limit(self) -> bool:
+        """Whether a prefill workspace carries the sequence's length."""
+        return self.kind == "state" or bool(self.index_dim)
+
+    def workspace_shapes(self, batch: int, length: int, dtype):
+        """[(shape, dtype)] of a contiguous cache of `length` positions for
+        `batch` sequences (generate(), the engine's prefill workspace),
+        without the limit."""
+        if self.kind == "state":
+            return [((batch, *shape), jnp.float32 if i == 0 else dtype)
+                    for i, shape in enumerate(self.state)]
+        if self.index_dim:              # by groups of `pool` positions
+            n = -(-length // self.pool)
+            return [((batch, n, self.pool * self.head_dim), dtype),
+                    ((batch, n, self.index_dim), dtype)]
+        return [((batch, length, self.kv_heads, self.head_dim), dtype)] \
+            * self.arrays
+
+    def page_shapes(self, blocks: int, block_size: int, slots: int, dtype):
+        """[(shape, dtype)] of the layer's arrays in a paged pool: pages of
+        `block_size` positions head-major ([blocks, heads, block_size, d]),
+        a pooled-key layer's by groups, a state layer's a slot."""
+        if self.kind == "state":
+            return self.workspace_shapes(slots, 0, dtype)
+        if self.index_dim:
+            n = block_size // self.pool
+            return [((blocks, n, self.pool * self.head_dim), dtype),
+                    ((blocks, n, self.index_dim), dtype)]
+        return [((blocks, self.kv_heads, block_size, self.head_dim), dtype)] \
+            * self.arrays
 
 
 @dataclass(frozen=True)
@@ -89,16 +148,23 @@ def uniform_cache_spec(num_layers: int, num_kv_heads: int, head_dim: int,
 
 
 def init_kv_cache(batch: int, max_len: int, spec: CacheSpec,
-                  dtype=jnp.float32):
+                  dtype=jnp.float32, limit=None):
     """Allocate the per-layer static buffers [batch, max_len, kv_heads,
-    head_dim]: a list of (k, v) arrays, or (latent,) for a latent layer. A
-    window layer's is as long as the others: its attention masks what lies
-    before the window."""
-    return [
-        tuple(jnp.zeros((batch, max_len, l.kv_heads, l.head_dim), dtype)
-              for _ in range(l.arrays))
-        for l in spec.layers
-    ]
+    head_dim]: a list of (k, v) arrays, or (latent,) for a latent layer
+    (LayerCacheSpec.workspace_shapes for the others). A window layer's is as
+    long as the others: its attention masks what lies before the window. A
+    layer that has to know where a sequence ends gets, last, its length:
+    `limit` (a scalar or [batch]), else max_len."""
+    out = []
+    for l in spec.layers:
+        arrays = [jnp.zeros(shape, dt)
+                  for shape, dt in l.workspace_shapes(batch, max_len, dtype)]
+        if l.has_limit:
+            arrays.append(jnp.broadcast_to(
+                jnp.asarray(max_len if limit is None else limit, jnp.int32),
+                (batch,)))
+        out.append(tuple(arrays))
+    return out
 
 
 def _sample_inside_jit(logits, do_sample, temperature, top_k, top_p, seed):

@@ -935,7 +935,8 @@ def rotary_from_positions(q, k, positions, inv_freq, factor=1.0):
 
 # ------------------------------------------------ sparse experts (dropless)
 def moe_experts(x, router_w, w13, w2, expert_lo=0, top_k=1, scale=1.0,
-                norm_topk=True, scoring="softmax", select_bias=None):
+                norm_topk=True, scoring="softmax", select_bias=None,
+                swiglu_limit=None):
     """The routed experts of a dropless top-k mixture-of-experts layer, for
     the experts whose weights are HERE: a chip's share under expert
     parallelism, or all of them.
@@ -949,7 +950,9 @@ def moe_experts(x, router_w, w13, w2, expert_lo=0, top_k=1, scale=1.0,
     normalised to sum 1 (norm_topk) and times `scale`. `select_bias`
     [experts published] is added to the scores for the CHOICE alone: the
     weights are the chosen experts' scores without it (the selection bias of
-    auxiliary-loss-free balancing). Every (token, expert) pair whose
+    auxiliary-loss-free balancing). `swiglu_limit` (None: off) clamps an
+    expert's activation before its SwiGLU: the gate to at most the limit,
+    the up projection into [-limit, limit]. Every (token, expert) pair whose
     expert is held is computed, none is dropped; a pair whose expert lives
     elsewhere adds nothing here (on one chip there is no exchange, and no
     code stands in for one).
@@ -1002,8 +1005,12 @@ def moe_experts(x, router_w, w13, w2, expert_lo=0, top_k=1, scale=1.0,
         else:
             gmm = grouped_matmul_xla
         h = gmm(rows, w13, counts[:held])
-        act = (jax.nn.silu(h[:, :width].astype(jnp.float32))
-               * h[:, width:].astype(jnp.float32)).astype(x.dtype)
+        gate = h[:, :width].astype(jnp.float32)
+        up = h[:, width:].astype(jnp.float32)
+        if swiglu_limit is not None:
+            gate = jnp.minimum(gate, swiglu_limit)
+            up = jnp.clip(up, -swiglu_limit, swiglu_limit)
+        act = (jax.nn.silu(gate) * up).astype(x.dtype)
         out = gmm(act, w2, counts[:held])[:pairs]
         # back to (token, choice) order; a pair of another chip's adds 0
         back = jnp.zeros((pairs,), jnp.int32).at[order].set(

@@ -42,7 +42,12 @@ context. What the rings do not serve yet (prefix-cache hits, speculation,
 fused steps, the KV wire) refuses by name for a model with window layers,
 and what is not written over latent pages (speculation, fused steps, the KV
 wire, batched prefill) for a model with latent layers; the prefix cache
-serves latent pages as it serves K/V pages.
+serves latent pages as it serves K/V pages. A latent layer with a pooled
+index key keeps two arrays by groups of positions (`write_groups`); a state
+layer keeps fixed-size arrays a SLOT (a recurrent state, a convolution's
+tail), which its prefill workspace carries from chunk to chunk from zero and
+the scatter sets at the slot's row when the prompt is in
+(serving_state_resets_total); over either the prefix cache refuses too.
 
 Compiled-program keys are shape-stable: one decode program per engine, one
 prefill/admit program per chunk bucket, one scatter per (workspace, block
@@ -79,7 +84,8 @@ from ..ops.pallas.paged_attention import (
     live_pages,
     to_pages,
 )
-from .paged import PagedKVPool, PagedLayerCache, write_prefix, write_ring
+from .paged import (PagedKVPool, PagedLayerCache, write_groups, write_prefix,
+                    write_ring)
 from .scheduler import Request, Scheduler
 from .speculative import NgramDrafter, SpecState
 
@@ -155,6 +161,11 @@ _LATENT_KEYS = _counter(
     "the pages the kernel visits in every slot, idle ones too, in tokens) "
     "beside the keys of the running contexts (`live`), a layer a slot.",
     labelnames=("kind",), always=True)
+_STATE_RESETS = _counter(
+    "serving_state_resets_total",
+    "Slots whose fixed-size state (linear-attention layers' recurrent state "
+    "and convolution tail) was set from a prompt's prefill, which starts "
+    "from zero: one a request admitted to decode.", always=True)
 _PAGED_KEYS = _counter(
     "serving_paged_keys_total",
     "Keys of full layers a decode step's attention fetched (`fetched`: the "
@@ -272,6 +283,8 @@ class ServingEngine:
             cols[rings.window] = (at, at + rings.ring_blocks)
             at += rings.ring_blocks
         self._table_cols = at
+        # a state layer keeps a slot's row and no table; its columns are
+        # the full group's, which it never reads
         self._layer_cols = [cols[l.window] for l in spec.layers]
         # (rings, its columns, how many layers read them), for the tick
         self._ring_cols = [
@@ -279,7 +292,8 @@ class ServingEngine:
              sum(1 for l in spec.layers if l.window == r.window))
             for r in self.window_rings]
         # (counter, its kinds, window, layers) by cache group, for the tick
-        kinds = [l.kind for l in spec.layers]
+        kinds = ["dsa" if l.index_dim else l.kind for l in spec.layers]
+        self._state_layers = "state" in kinds
         self._key_counters = [
             (counter, ("fetched", "live"), None, kinds.count(kind))
             for counter, kind in ((_PAGED_KEYS, "full"),
@@ -291,12 +305,19 @@ class ServingEngine:
         # lack refuses below, by name
         self._unserved = \
             [f"window layers (windows {windows})"] * bool(windows) \
-            + ["latent layers"] * ("latent" in kinds)
+            + ["latent layers"] * bool({"latent", "dsa"} & set(kinds)) \
+            + ["state layers"] * self._state_layers
         if windows:
             prefix_cache = self._refuse_over(
                 "prefix_cache", prefix_cache, False,
                 "a prefix hit would have to find the window layers' keys, "
                 "which a ring keeps for the last window only")
+        if self._state_layers or "dsa" in kinds:
+            prefix_cache = self._refuse_over(
+                "prefix_cache", prefix_cache, False,
+                "a prefix hit would need a state layer's recurrent state at "
+                "the hit's end (a snapshot, which no page keeps) and a "
+                "pooled index key's partial block")
         if self._unserved:
             prefill_bucket = self._refuse_over(
                 "prefill_bucket", prefill_bucket, 0,
@@ -310,8 +331,9 @@ class ServingEngine:
                                   else prefill_bucket)
         by_window = {r.window: r.num_blocks for r in self.window_rings}
         self.pool = PagedKVPool(
-            [(by_window.get(l.window, self.num_blocks), l.kv_heads,
-              l.head_dim, l.arrays) for l in spec.layers],
+            [l.page_shapes(by_window.get(l.window, self.num_blocks),
+                           self.block_size, self.max_slots, self._dtype)
+             for l in spec.layers],
             self.block_size, self._dtype)
         self.allocator = BlockAllocator(self.num_blocks, self.block_size,
                                         prefix_cache=self.prefix_cache)
@@ -694,14 +716,17 @@ class ServingEngine:
     def _workspace_jit(self, padded):
         """A first-turn prompt's empty prefill workspace, every array of
         every layer from ONE program (eagerly it is a dispatch an array: 48
-        for a 24-layer model, each a gap on the device)."""
+        for a 24-layer model, each a gap on the device). A state layer's
+        starts from zero and, like a pooled-key layer's, carries the
+        prompt's length (`plen`), past which a padded chunk's rows are not
+        tokens."""
         key = ("workspace", padded)
 
         def build():
             spec, dtype = self._spec, self._dtype
 
-            def serve_workspace():
-                return init_kv_cache(1, padded, spec, dtype)
+            def serve_workspace(plen):
+                return init_kv_cache(1, padded, spec, dtype, limit=plen)
 
             return jax.jit(serve_workspace)
 
@@ -829,13 +854,23 @@ class ServingEngine:
             cols = self._layer_cols
             full = (0, self.max_blocks_per_seq)
 
-            def serve_scatter(pages, caches, row, last_block):
+            def serve_scatter(pages, caches, row, last_block, slot):
                 """row: the slot's table row, every group's columns;
                 last_block: the logical block of the prompt's last token
-                (a window layer keeps the blocks that end there)."""
+                (a window layer keeps the blocks that end there); slot: the
+                row of a state layer's arrays."""
                 out = []
-                for arrays, ws, (a, b) in zip(pages, caches, cols):
-                    if (a, b) == full:
+                for arrays, ws, (a, b), l in zip(pages, caches, cols,
+                                                 self._spec.layers):
+                    if l.kind == "state":
+                        out.append(tuple(p.at[slot].set(w[0])
+                                         for p, w in zip(arrays, ws)))
+                    elif l.index_dim:
+                        g = nb * (bs // l.pool)
+                        out.append(write_groups(
+                            arrays, [w[0, :g] for w in ws[:len(arrays)]],
+                            row[a:a + nb]))
+                    elif (a, b) == full:
                         out.append(write_prefix(
                             arrays, [w[0, :n] for w in ws], row[a:a + nb],
                             block_size=bs))
@@ -1296,7 +1331,8 @@ class ServingEngine:
                     req._ws_caches = self._gather_jit(padded, mb)(
                         self.pool.layers, head)
                 else:
-                    req._ws_caches = self._workspace_jit(padded)()
+                    req._ws_caches = self._workspace_jit(padded)(
+                        np.int32(plen))
             ids = np.zeros((1, chunk), np.int32)
             ids[0, :take] = req.prompt[start:start + take]
             logits, req._ws_caches = self._prefill_jit(chunk, padded)(
@@ -1318,9 +1354,12 @@ class ServingEngine:
                 nb = -(-plen // self.block_size)
                 new_layers = self._scatter_jit(padded, nb)(
                     self.pool.layers, req._ws_caches, self._table_row(req),
-                    np.int32((plen - 1) // self.block_size))
+                    np.int32((plen - 1) // self.block_size),
+                    np.int32(req.slot))
                 self.pool.replace(new_layers)
                 req._ws_caches = None
+                if self._state_layers:
+                    _STATE_RESETS.inc()
                 if self.prefix_cache:
                     # the prompt's full blocks are now resident in the pool:
                     # index them so later prompts sharing the prefix skip

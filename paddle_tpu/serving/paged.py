@@ -10,6 +10,17 @@ and ONE for a latent layer,
 
     latent_pages     : [num_blocks, 1, block_size, head_dim]
 
+two for a latent layer with a pooled index key, by groups of `pool`
+positions (a group a row: `write_groups`),
+
+    latent_pages     : [num_blocks, block_size / pool, pool * head_dim]
+    index_pages      : [num_blocks, block_size / pool, index_dim]
+
+and, for a state layer, arrays of a fixed size a SLOT and not a page
+([slots, ...]: a recurrent state and a convolution's tail), which the decode
+step reads and writes at the slot's row and the engine sets at admission
+from the prefill workspace,
+
 with the layer's own heads and head size, and as many blocks as its CACHE
 GROUP has: full and latent layers share the engine's block table and its
 num_blocks, the layers of one window a ring of blocks a slot
@@ -67,18 +78,14 @@ class PagedKVPool:
     """Owns the per-layer page arrays. Holds plain jax arrays (not Tensors):
     the compiled decode step takes and returns them as donated buffers."""
 
-    def __init__(self, layer_blocks, block_size: int, dtype=jnp.float32):
-        """layer_blocks: for each layer in the model's order, (blocks of its
-        cache group, kv_heads, head_dim, arrays: 2 for K and V, 1 for a
-        latent layer)."""
+    def __init__(self, layer_arrays, block_size: int, dtype=jnp.float32):
+        """layer_arrays: for each layer in the model's order, the (shape,
+        dtype) of each of its arrays (LayerCacheSpec.page_shapes)."""
         self.block_size = int(block_size)
         self.dtype = dtype
-        self.layers: List[Tuple[jax.Array, ...]] = []
-        for blocks, kv_heads, head_dim, arrays in layer_blocks:
-            shape = (int(blocks), int(kv_heads), self.block_size,
-                     int(head_dim))
-            self.layers.append(tuple(jnp.zeros(shape, dtype)
-                                     for _ in range(arrays)))
+        self.layers: List[Tuple[jax.Array, ...]] = [
+            tuple(jnp.zeros(shape, dt) for shape, dt in arrays)
+            for arrays in layer_arrays]
 
     def nbytes(self) -> int:
         """Bytes of every array of every layer as the shapes give them (the
@@ -104,6 +111,18 @@ def write_prefix(pages, rows, table, *, block_size):
     runs in a contiguous workspace); jit-compiled per padded length."""
     return tuple(
         p.at[table].set(to_pages(r, block_size).astype(p.dtype))
+        for p, r in zip(pages, rows))
+
+
+def write_groups(pages, rows, table):
+    """Scatter a contiguous prefix of a grouped layer (a latent layer with a
+    pooled index key: rows of groups of positions, LayerCacheSpec.index_dim)
+    into its pages: rows, for each array, [groups, width] with the groups a
+    whole number of pages; table [pages] int32 block ids. Indexes leading
+    dimensions only, like write_prefix."""
+    return tuple(
+        p.at[table].set(r.reshape(table.shape[0], p.shape[1],
+                                  p.shape[2]).astype(p.dtype))
         for p, r in zip(pages, rows))
 
 

@@ -313,7 +313,7 @@ def pool_programs(one_chip):
             pv, bv, sd((_SLOTS, W), i32), pages, tables, lens, lens, temps,
             seed)),
         "serve_scatter": (engine._scatter_jit(P, P // _BLOCK), (
-            pages, work, row, one)),
+            pages, work, row, one, one)),
         "serve_batched_prefill": (engine._batched_prefill_jit(S, P), (
             pv, bv, pages, sd((_SLOTS, S), i32), lens,
             sd((_SLOTS, P // _BLOCK), i32), lens, lens, tables, lens, temps,
@@ -631,7 +631,8 @@ def laguna_pool_programs(one_chip):
             pv, bv, lens, pages, tables, lens, sd((_L_SLOTS,), jnp.float32),
             sd((), i32), counters)),
         "serve_scatter": (engine._scatter_jit(P, P // _BLOCK), (
-            pages, work, sd((engine._table_cols,), i32), sd((), i32))),
+            pages, work, sd((engine._table_cols,), i32), sd((), i32),
+            sd((), i32))),
     }
 
 
@@ -749,3 +750,39 @@ def test_xing_programs_hold_the_mix_and_leave_the_pool_where_it_lies(
     if name == "step":  # both layers' pools updated where they came in
         assert compiled.memory_analysis().alias_size_in_bytes \
             >= 2 * 2 * pool[0] * pool[2] * pool[3]
+
+
+# ---- GLM-5.3-Flash: the delta-rule kernels and the indexer, published widths
+def test_kda_chunk_at_the_published_widths(one_chip):
+    """A prefill chunk of 2,048 tokens, 64 heads of 128, the state in and
+    out in float32; the kernel keeps its name."""
+    from paddle_tpu.ops.pallas.delta_rule import kda_chunk
+
+    tok = ((1, 2048, 64, 128), jnp.float32)
+    compiled = _compile(lambda q, k, v, g, b, s: kda_chunk(
+        q, k, v, g, b, s, scale=128 ** -0.5), one_chip, tok, tok, tok, tok,
+        ((1, 2048, 64), jnp.float32), ((1, 64, 128, 128), jnp.float32))
+    assert re.search(r"kda_chunk", compiled.as_text())
+
+
+def test_kda_decode_at_the_published_widths(one_chip):
+    from paddle_tpu.ops.pallas.delta_rule import kda_decode
+
+    vec = ((32, 64, 128), jnp.float32)
+    compiled = _compile(lambda q, k, v, g, b, s: kda_decode(
+        q, k, v, g, b, s, scale=128 ** -0.5), one_chip, vec, vec, vec, vec,
+        ((32, 64), jnp.float32), ((32, 64, 128, 128), jnp.float32))
+    assert re.search(r"kda_decode", compiled.as_text())
+
+
+@pytest.mark.parametrize("blocks", [2048, 16384])
+def test_dsa_index_at_the_published_widths(one_chip, blocks):
+    """A chunk of 2,048 queries of 32 heads of 128 over the pooled keys of
+    an 8k and of a 64k workspace."""
+    from paddle_tpu.ops.pallas.sparse_latent import dsa_index
+
+    compiled = _compile(lambda q, w, k, c: dsa_index(q, w, k, c), one_chip,
+                        ((2048, 32, 128), jnp.float32),
+                        ((2048, 32), jnp.float32),
+                        ((blocks, 128), jnp.bfloat16), ((2048,), jnp.int32))
+    assert re.search(r"dsa_index", compiled.as_text())

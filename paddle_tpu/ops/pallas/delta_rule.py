@@ -24,12 +24,27 @@ cumulative sum of g from the sub-chunk's start,
     S_16 = diag(exp G_16) S_0 + (k exp(G_16 - G))^T U
 
 A and B are products of two factors, each taken against the sub-chunk's
-middle row (exp(G_t - G_r) and exp(G_r - G_j)): with g above the lower bound
-of -5 no factor of a 16-token sub-chunk leaves float32's range (e^{+-40};
-masked entries at most e^75). T is the product (I - N)(I + N^2)(I + N^4)
-(I + N^8) of N = diag(beta) A, which is nilpotent of order 16: four products,
-no division. Only the last three lines touch the state, so the sub-chunks
-run one after the other and the rest is in the products.
+middle row (exp(G_t - G_r) and exp(G_r - G_j)), and T is the product
+(I - N)(I + N^2)(I + N^4)(I + N^8) of N = diag(beta) A, nilpotent of order
+16: no division. Only U's second term, O's first and the state's update read
+the state; the rest does not.
+
+`kda_chunk` takes a grid step of BT = 128 tokens (8 sub-chunks) a head in
+two phases.
+1. What does not read the state, the whole block at once, as full-width
+   products: A and B one product of (beta k; q) exp(G - G_r), stacked
+   [256, K], against k exp(G_r - G), each sub-chunk factored at its own
+   middle row and the result masked to the 16 x 16 diagonal blocks; T from
+   the block-diagonal N, whose powers stay block-diagonal (six [128, 128]
+   products); W = T (beta k exp G) and U_0 = T beta v one product each;
+   each sub-chunk's exp(G_16) as a column [K, 1]. The blocks the mask drops
+   pair one sub-chunk's factor with another's. With g above the gate's
+   lower bound of -5 a factor is at most e^40 (8 tokens of -5), so an entry
+   is at most 128 e^75 and never leaves float32's range.
+2. The state's own products, one sub-chunk after the other: [W_c;
+   (q exp G)_c] S as one [32, K] x S product, u_c = U_0,c - W_c S, and
+   S <- diag(exp G_16) S + (k exp(G_16 - G))_c^T u_c. After the last,
+   O = (q exp G) S + B U is one product of the block-diagonal B.
 
 Decode (`kda_decode`, `delta_rule_step`): one token a slot, the state read
 and written once (4 MiB a slot a layer at 64 heads of 128 x 128).
@@ -41,9 +56,10 @@ blocks, read at the first and written at the last. `kda_decode`: a grid over
 (slots, head blocks of 8); a block's q, k, beta k and g are turned once so
 that a head's values lie down the sublanes, and the update is vector work
 on [128, 128] tiles; the state's block is updated in place
-(input_output_aliases). Both take the products in float32 at precision
-highest: the state is the sum of up to a whole context of rank-one writes,
-and a bf16 product's rounding would compound through it.
+(input_output_aliases). Every product of both is float32 at precision
+highest, and the state is float32: the state is the sum of up to a whole
+context of rank-one writes, and a bf16 product's rounding would compound
+through it.
 """
 from __future__ import annotations
 
@@ -64,13 +80,14 @@ F32 = jnp.float32
 
 
 def _unit_lower_inverse(n, eye):
-    """(I + N)^-1 for N strictly lower triangular of order SUB (a power of
-    two): (I - N)(I + N^2)(I + N^4)..., ending where a power of N is zero."""
+    """(I + N)^-1 for N nilpotent of order SUB (a power of two): strictly
+    lower triangular of size SUB, or block-diagonal in such blocks.
+    (I - N)(I + N^2)(I + N^4)..., ending where a power of N is zero."""
     dot = functools.partial(jnp.matmul, precision=HP)
     p = eye - n
     m = dot(n, n)
     size = 2
-    while size < n.shape[-1]:
+    while size < SUB:
         p = p + dot(p, m)
         m = dot(m, m)
         size *= 2
@@ -139,15 +156,18 @@ def delta_rule_chunked_xla(q, k, v, g, beta, state, scale):
 
 # ---------------------------------------------------------------- kernels
 def _chunk_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, s0_ref,
-                  o_ref, s_out_ref, s_ref, *, heads, scale):
+                  o_ref, s_out_ref, s_ref, wq_ref, u_ref, kd_ref, b_ref,
+                  gl_ref, *, heads, scale):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _load():
         s_ref[...] = s0_ref[0]
 
-    row = lax.broadcasted_iota(jnp.int32, (SUB, SUB), 0)
-    col = lax.broadcasted_iota(jnp.int32, (SUB, SUB), 1)
+    n = BT // SUB
+    row = lax.broadcasted_iota(jnp.int32, (BT, BT), 0)
+    col = lax.broadcasted_iota(jnp.int32, (BT, BT), 1)
+    same = row // SUB == col // SUB              # the diagonal blocks
     eye = (row == col).astype(F32)
     dot = functools.partial(jnp.dot, precision=HP, preferred_element_type=F32)
     nt = functools.partial(lax.dot_general,
@@ -156,26 +176,45 @@ def _chunk_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, s0_ref,
     tn = functools.partial(lax.dot_general,
                            dimension_numbers=(((0,), (0,)), ((), ())),
                            precision=HP, preferred_element_type=F32)
-    for c in range(BT // SUB):
+
+    def each(h, i):      # row i of every sub-chunk of head h's G: [n, K]
+        return g_ref[0, h, pl.ds(i, n, stride=SUB), :]
+
+    def down(x):         # [n, K] -> [BT, K]: each row down its sub-chunk
+        return jnp.broadcast_to(x[:, None], (n, SUB, x.shape[-1])
+                                ).reshape(BT, -1)
+
+    # 1. what does not read the state, the whole block at once
+    for h in range(heads):
+        q, k, kb, G = q_ref[0, h], k_ref[0, h], kb_ref[0, h], g_ref[0, h]
+        mid, last = down(each(h, SUB // 2)), each(h, SUB - 1)
+        eq = jnp.exp(G - mid)
+        ab = nt(jnp.concatenate([kb * eq, q * eq]), k * jnp.exp(mid - G))
+        a = jnp.where(same & (row > col), ab[:BT], 0.0)
+        b_ref[h] = jnp.where(same & (row >= col), ab[BT:], 0.0)
+        tinv = _unit_lower_inverse(a, eye)
+        eg = jnp.exp(G)
+        u_ref[h] = dot(tinv, vb_ref[0, h])                          # U_0
+        wq_ref[h] = jnp.concatenate(
+            [dot(tinv, kb * eg).reshape(n, SUB, -1),                # W
+             (q * eg).reshape(n, SUB, -1)], axis=1)
+        kd_ref[h] = k * jnp.exp(down(last) - G)
+        gl_ref[h] = jnp.exp(last).T
+
+    # 2. the state's own products, one sub-chunk after the other
+    for c in range(n):
         r = pl.ds(c * SUB, SUB)
         for h in range(heads):       # independent chains, interleaved
-            q = q_ref[0, h, r, :]
-            k = k_ref[0, h, r, :]
-            kb = kb_ref[0, h, r, :]
-            G = g_ref[0, h, r, :]
-            mid = G[SUB // 2:SUB // 2 + 1, :]
-            last = G[SUB - 1:SUB, :]
-            kk = k * jnp.exp(mid - G)
-            eq = jnp.exp(G - mid)
-            eg = jnp.exp(G)
-            a = jnp.where(row > col, nt(kb * eq, kk), 0.0)
-            bm = jnp.where(row >= col, nt(q * eq, kk), 0.0)
-            tinv = _unit_lower_inverse(a, eye)
             s = s_ref[h]
-            u = dot(tinv, vb_ref[0, h, r, :]) - dot(dot(tinv, kb * eg), s)
-            o_ref[0, h, r, :] = (dot(q * eg, s) + dot(bm, u)) * scale
-            decay = jnp.broadcast_to(jnp.exp(last), s.shape).T   # row k: e^G_k
-            s_ref[h] = s * decay + tn(k * jnp.exp(last - G), u)
+            x = dot(wq_ref[h, c], s)              # W_c S over (q e^G)_c S
+            u = u_ref[h, r] - x[:SUB]
+            u_ref[h, r] = u
+            o_ref[0, h, r, :] = x[SUB:]
+            s_ref[h] = s * gl_ref[h, :, c:c + 1] + tn(kd_ref[h, r], u)
+
+    # 3. the outputs' own part: O = (q e^G) S + B U, B block-diagonal
+    for h in range(heads):
+        o_ref[0, h] = (o_ref[0, h] + dot(b_ref[h], u_ref[h])) * scale
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _store():
@@ -211,7 +250,13 @@ def kda_chunk(q, k, v, g, beta, state, *, scale, interpret=False):
         out_specs=[tok(dv), st],
         out_shape=[jax.ShapeDtypeStruct((b, h, t, dv), F32),
                    jax.ShapeDtypeStruct((b, h, dk, dv), F32)],
-        scratch_shapes=[pltpu.VMEM((HB_CHUNK, dk, dv), F32)],
+        scratch_shapes=[
+            pltpu.VMEM((HB_CHUNK, dk, dv), F32),                    # S
+            pltpu.VMEM((HB_CHUNK, BT // SUB, 2 * SUB, dk), F32),    # W; q e^G
+            pltpu.VMEM((HB_CHUNK, BT, dv), F32),                    # U_0, U
+            pltpu.VMEM((HB_CHUNK, BT, dk), F32),                    # kd
+            pltpu.VMEM((HB_CHUNK, BT, BT), F32),                    # B
+            pltpu.VMEM((HB_CHUNK, dk, BT // SUB), F32)],            # e^G_16
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         name="kda_chunk",

@@ -165,23 +165,44 @@ def _delta_inputs(t, h=8, d=128, seed=0):
             f(rng.uniform(size=(1, t, h))))
 
 
-@pytest.mark.parametrize("form", ["xla", "kernel"])
-def test_the_chunked_forms_carry_the_state_over_three_chunks(form):
-    """Three prefill chunks of 128, each from the state the last left,
-    against the token recurrence over all 384 tokens; a chunk's edge falls
-    on a sub-chunk's. float32 both sides, another order of summation:
-    round-off (reads 3e-8 on outputs of size 1; a state rounded to bf16 a
-    chunk reads 1e-3)."""
-    q, k, v, g, beta = _delta_inputs(384)
-    s0 = jnp.zeros((1, 8, 128, 128), jnp.float32)
+def _extreme_decays(decays):
+    """4 heads over two chunks of 128: `floor`, the gate's lower bound -5 in
+    every channel over the whole second block (a sub-chunk's factors reach
+    e^{+-40}, the masked blocks between sub-chunks e^75); `padded`, rows of
+    g 0 and beta 0 inside the first block and at the second block's end,
+    as a padded prefill chunk's."""
+    q, k, v, g, beta = _delta_inputs(256, h=4)
+    if decays == "floor":
+        g = g.at[:, 128:].set(-5.0)
+    else:
+        for rows in (slice(40, 72), slice(216, 256)):
+            g, beta = g.at[:, rows].set(0.0), beta.at[:, rows].set(0.0)
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("form,decays", [
+    ("xla", "mixed"), ("kernel", "mixed"), ("xla", "floor"),
+    ("kernel", "floor"), ("xla", "padded"), ("kernel", "padded")],
+    ids=["xla", "kernel", "xla-floor", "kernel-floor", "xla-padded",
+         "kernel-padded"])
+def test_the_chunked_forms_carry_the_state_over_three_chunks(form, decays):
+    """Three prefill chunks of 128 (two at the decay's extremes), each from
+    the state the last left, against the token recurrence over all their
+    tokens; a chunk's edge falls on a sub-chunk's. float32 both sides,
+    another order of summation: round-off (reads 3e-8 on outputs of size
+    1; a state rounded to bf16 a chunk reads 1e-3)."""
+    q, k, v, g, beta = (_delta_inputs(384) if decays == "mixed"
+                        else _extreme_decays(decays))
+    s0 = jnp.zeros((1, q.shape[2], 128, 128), jnp.float32)
     want_o, want_s = _recurrence(q, k, v, g, beta, s0, 0.1)
-    run = dr.delta_rule_chunked_xla if form == "xla" else (
-        lambda *a: dr.kda_chunk(*a[:-1], scale=a[-1], interpret=True))
+    run = jax.jit(
+        (lambda *a: dr.delta_rule_chunked_xla(*a, 0.1)) if form == "xla"
+        else (lambda *a: dr.kda_chunk(*a, scale=0.1, interpret=True)))
     s, outs = s0, []
-    for c in range(3):
+    for c in range(q.shape[1] // 128):
         sl_ = slice(128 * c, 128 * (c + 1))
         o, s = run(q[:, sl_], k[:, sl_], v[:, sl_], g[:, sl_], beta[:, sl_],
-                   s, 0.1)
+                   s)
         outs.append(o)
     assert float(jnp.max(jnp.abs(jnp.concatenate(outs, 1) - want_o))) < 2e-5
     assert float(jnp.max(jnp.abs(s - want_s))) < 2e-5

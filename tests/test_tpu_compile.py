@@ -753,15 +753,17 @@ def test_xing_programs_hold_the_mix_and_leave_the_pool_where_it_lies(
 
 
 # ---- GLM-5.3-Flash: the delta-rule kernels and the indexer, published widths
-def test_kda_chunk_at_the_published_widths(one_chip):
-    """A prefill chunk of 2,048 tokens, 64 heads of 128, the state in and
-    out in float32; the kernel keeps its name."""
+@pytest.mark.parametrize("tokens", [2048, 1024])
+def test_kda_chunk_at_the_published_widths(one_chip, tokens):
+    """A prefill chunk of 2,048 tokens, and the long-context cell's 1,024,
+    64 heads of 128, the state in and out in float32; the kernel keeps its
+    name."""
     from paddle_tpu.ops.pallas.delta_rule import kda_chunk
 
-    tok = ((1, 2048, 64, 128), jnp.float32)
+    tok = ((1, tokens, 64, 128), jnp.float32)
     compiled = _compile(lambda q, k, v, g, b, s: kda_chunk(
         q, k, v, g, b, s, scale=128 ** -0.5), one_chip, tok, tok, tok, tok,
-        ((1, 2048, 64), jnp.float32), ((1, 64, 128, 128), jnp.float32))
+        ((1, tokens, 64), jnp.float32), ((1, 64, 128, 128), jnp.float32))
     assert re.search(r"kda_chunk", compiled.as_text())
 
 
